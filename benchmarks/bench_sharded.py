@@ -155,8 +155,12 @@ def run():
     print("Sharded serving: 2-shard data-parallel lane vs single worker")
     print(f"(chain of {CHAIN} dependent {SIZE}x{SIZE} GeMM stages, "
           f"micro-batch {BATCH}, subprocess with 2 forced host devices)")
+    print("  the subprocess runs with JAX_PLATFORMS=cpu: it rehearses the "
+          "sharding on two virtual CPU devices and never takes the "
+          "accelerator this process may hold")
     print("=" * 76)
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     flags = [f for f in env.get("XLA_FLAGS", "").split()
              if "xla_force_host_platform_device_count" not in f]
     flags.append("--xla_force_host_platform_device_count=2")

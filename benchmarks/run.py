@@ -13,6 +13,8 @@ import argparse
 import pathlib
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import (bench_decode, bench_dispatch, bench_gemm_overhead,
                bench_multiqueue, bench_overload, bench_power, bench_roofline,
                bench_serve, bench_sharded, bench_static, bench_tinybio,
@@ -52,6 +54,7 @@ def main():
                     help="export Chrome trace JSON from the traced serve "
                          "benches (serve, overload)")
     args = ap.parse_args()
+    enable_compile_cache()
     names = [args.only] if args.only else list(BENCHES)
     n_traced = sum(1 for n in names if n in TRACED_BENCHES)
     t0 = time.time()

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params, model_spec
 from repro.serve import DecodeEngine, Server
 from repro.train.serve import greedy_generate
@@ -39,6 +40,7 @@ MAX_LEN = PROMPT + MAX_NEW + 1
 
 
 def main():
+    enable_compile_cache()
     cfg = ARCHS[ARCH].reduced()
     params = init_params(model_spec(cfg), jax.random.PRNGKey(0))
     prompts = jnp.asarray(

@@ -13,6 +13,7 @@ import argparse
 import dataclasses
 
 from repro.configs import ARCHS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import train_loop
 from repro.train.step import TrainConfig
 
@@ -23,6 +24,7 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=64)
     args = ap.parse_args()
+    enable_compile_cache()
 
     # ~100M-class: a 12-layer width-768 qwen-family model (~86M params;
     # ~2.5 s/step on one CPU core — a few hundred steps is a coffee break)

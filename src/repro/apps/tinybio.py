@@ -11,8 +11,11 @@ vectors x 32 features.  With this workload the analytic machine model
 reproduces the paper's Fig-4 bands within ±15 % on every stage
 (tests/test_paper_validation.py pins them).
 
-Every stage runs functionally (Pallas kernels on TPU, interpret/XLA on CPU)
+Every stage runs functionally (Pallas kernels on TPU, interpret mode on CPU)
 AND is costed by the machine model — the APU report carries both.
+``tinybio_stages(use_pallas=False)`` builds the same pipeline from the
+kernels' ``ref.py`` oracles, the reference the Pallas pipeline is checked
+against.
 """
 
 from __future__ import annotations
@@ -25,9 +28,9 @@ import numpy as np
 
 from ..core import (APU, EGPUConfig, EGPU_16T, Kernel, Program, Stage,
                     kernel_family)
-from ..kernels.delineate import ops as delineate_ops
 from ..kernels.stockham_fft import ops as fft_ops
 from ..kernels.stockham_fft.ref import counts as fft_counts
+from ..kernels.stockham_fft.ref import stockham_fft_ref
 
 TINYBIO_WORKLOAD = dict(n=65_536, taps=128, win=512, n_windows=128,
                         n_sv=256, n_features=36)   # 32 bands + 4 stats
@@ -42,11 +45,19 @@ def synth_signal(n: int, seed: int = 0) -> np.ndarray:
     return np.asarray(sig, np.float32)
 
 
-def _feature_kernel(win: int, n_windows: int):
+def _power_spectrum_ref(w: jax.Array) -> jax.Array:
+    """|FFT|^2 of each row via the pure-jnp Stockham oracle."""
+    re, im = jax.vmap(stockham_fft_ref)(w, jnp.zeros_like(w))
+    return re * re + im * im
+
+
+def _feature_kernel(win: int, n_windows: int, use_pallas: bool = True):
     """Stage 3: windowed power-spectrum features + time-domain stats."""
+    spectrum = fft_ops.power_spectrum if use_pallas else _power_spectrum_ref
+
     def features(x: jax.Array, flags: jax.Array) -> jax.Array:
         w = x[: win * n_windows].reshape(n_windows, win)
-        spec = jax.vmap(fft_ops.power_spectrum)(w)          # (NW, win)
+        spec = spectrum(w)                                  # (NW, win)
         nf = TINYBIO_WORKLOAD["n_features"]
         bands = spec[:, :win // 2].reshape(n_windows, nf - 4, -1).mean(-1)
         mean = w.mean(axis=1, keepdims=True)
@@ -69,26 +80,31 @@ def _feature_kernel(win: int, n_windows: int):
 # program without touching repro.kernels.
 
 @kernel_family("tinybio.delineate_keep")
-def _build_delineate_keep(config: EGPUConfig = EGPU_16T) -> Kernel:
+def _build_delineate_keep(config: EGPUConfig = EGPU_16T, *,
+                          use_pallas: bool = True) -> Kernel:
     """Delineation that also passes the filtered signal through:
     x -> (x, flags)."""
-    del_k = Program.build(config).create_kernel("delineate")
+    del_k = Program.build(config).create_kernel("delineate",
+                                                use_pallas=use_pallas)
     return Kernel("delineate_keep",
-                  executor=lambda x: (x, delineate_ops.delineate(x, 0)),
+                  executor=lambda x: (x, del_k.executor(x)),
                   counts=del_k.counts)
 
 
 @kernel_family("tinybio.fft_features")
 def _build_fft_features(config: EGPUConfig = EGPU_16T, *, win: int = 512,
-                        n_windows: int = 128) -> Kernel:
+                        n_windows: int = 128,
+                        use_pallas: bool = True) -> Kernel:
     """Stage-3 spectral+time features at a fixed windowing."""
     return Kernel(name="fft_features",
-                  executor=_feature_kernel(win, n_windows),
+                  executor=_feature_kernel(win, n_windows, use_pallas),
                   counts=lambda **kw: fft_counts(n=win).scaled(n_windows))
 
 
-def tinybio_stages(config: EGPUConfig = EGPU_16T, seed: int = 0):
-    """(stages, inputs) for :meth:`repro.core.APU.offload`."""
+def tinybio_stages(config: EGPUConfig = EGPU_16T, seed: int = 0,
+                   use_pallas: bool = True):
+    """(stages, inputs) for :meth:`repro.core.APU.offload`; with
+    ``use_pallas=False`` every stage runs its ``ref.py`` oracle."""
     wl = TINYBIO_WORKLOAD
     n, taps, win, nw = wl["n"], wl["taps"], wl["win"], wl["n_windows"]
     rng = np.random.default_rng(seed + 1)
@@ -107,15 +123,17 @@ def tinybio_stages(config: EGPUConfig = EGPU_16T, seed: int = 0):
     # serve GraphCache a stable registry identity to key on.
     program = Program.build(config)
     stages = [
-        Stage(program.create_kernel("fir"), consts=(jnp.asarray(h),),
+        Stage(program.create_kernel("fir", use_pallas=use_pallas),
+              consts=(jnp.asarray(h),),
               counts_params={"n": n, "taps": taps, "itemsize": 2}),
         # delineate consumes the filtered signal; passes (signal, flags) on
-        Stage(program.create_kernel("tinybio.delineate_keep"),
+        Stage(program.create_kernel("tinybio.delineate_keep",
+                                    use_pallas=use_pallas),
               counts_params={"n": n}),
         Stage(program.create_kernel("tinybio.fft_features", win=win,
-                                    n_windows=nw),
+                                    n_windows=nw, use_pallas=use_pallas),
               counts_params={}),
-        Stage(program.create_kernel("svm"),
+        Stage(program.create_kernel("svm", use_pallas=use_pallas),
               consts=(jnp.asarray(sv), jnp.asarray(alpha),
                       jnp.float32(0.1)),
               params={"gamma": 0.5},

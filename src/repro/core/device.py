@@ -39,7 +39,10 @@ KIB = 1024
 MIB = 1024 * KIB
 
 # TPU v5e-ish magnitudes used when projecting e-GPU knobs onto Pallas tiling.
-TPU_VMEM_BYTES = 16 * MIB  # usable VMEM per core (conservative)
+# The Mosaic compiler's default scoped-VMEM limit on v5e: a Pallas call whose
+# buffers need 16 MiB compiles for a described v5e, one that needs 20 MiB is
+# refused (RESOURCE_EXHAUSTED in memory space vmem).
+TPU_VMEM_BYTES = 16 * MIB
 TPU_LANES = 128            # VPU/MXU minor dimension
 TPU_SUBLANES = 8           # VPU second-minor dimension (float32)
 
@@ -238,7 +241,11 @@ class KernelKnobs:
 
 def check_vmem_budget(knobs: KernelKnobs, *block_bytes: int) -> None:
     """Raise if the sum of per-buffer VMEM block footprints (times the
-    pipeline depth, since Pallas multi-buffers blocks) exceeds the budget."""
+    pipeline depth, since Pallas multi-buffers blocks) exceeds the budget.
+
+    Pass every input and output block and every scratch buffer: Pallas
+    double-buffers the blocks, and a depth of at least 2 then bounds what
+    the kernel really allocates by the budget."""
     total = sum(block_bytes) * knobs.pipeline_depth
     if total > knobs.vmem_budget_bytes:
         raise ValueError(

@@ -1485,12 +1485,13 @@ class CommandGraph:
 
     def _fused(self, donate: Tuple[int, ...],
                in_shardings: Optional[Tuple[Any, ...]] = None,
-               out_shardings: Optional[Tuple[Any, ...]] = None) -> Callable:
+               out_shardings: Optional[Tuple[Any, ...]] = None,
+               per_device: bool = False) -> Callable:
         # One compiled executable per (donation, mesh binding): the same
         # captured graph serves single-device and sharded launches side by
         # side — shardings are a launch-time property, never part of the
         # capture (NamedShardings hash by mesh + spec, so the key is cheap).
-        key = (donate, in_shardings, out_shardings)
+        key = (donate, in_shardings, out_shardings, per_device)
         fn = self._jit_cache.get(key)
         if fn is not None:
             return fn
@@ -1515,7 +1516,14 @@ class CommandGraph:
             jit_kwargs["in_shardings"] = in_shardings
         if out_shardings is not None:
             jit_kwargs["out_shardings"] = out_shardings
-        fn = jax.jit(run, donate_argnums=donate, **jit_kwargs)
+        body = run
+        if per_device:
+            body = jax.shard_map(
+                run, mesh=in_shardings[0].mesh,
+                in_specs=tuple(sh.spec for sh in in_shardings),
+                out_specs=tuple(sh.spec for sh in out_shardings),
+                check_vma=False)
+        fn = jax.jit(body, donate_argnums=donate, **jit_kwargs)
         self._jit_cache[key] = fn
         return fn
 
@@ -1523,7 +1531,8 @@ class CommandGraph:
                queue_events: bool = True,
                queue: Optional[CommandQueue] = None,
                in_shardings: Optional[Sequence[Any]] = None,
-               out_shardings: Optional[Sequence[Any]] = None
+               out_shardings: Optional[Sequence[Any]] = None,
+               per_device: bool = False
                ) -> Tuple[Buffer, ...]:
         """Execute the captured chain as one fused dispatch (non-blocking).
 
@@ -1545,7 +1554,12 @@ class CommandGraph:
         the graph's jit cache, so one entry serves single-device workers
         and :class:`~repro.serve.sharded.ShardedWorker`\\ s side by side.
         Kernels are pure and the batch rows independent, so a data-parallel
-        binding can never change functional results.
+        binding can never change functional results.  ``per_device=True``
+        (NamedShardings on one mesh required) runs the chain on each
+        device's shards under ``jax.shard_map`` instead of letting GSPMD
+        partition it: the same result wherever every sharded dimension is
+        independent rows, and the only way to run Pallas (Mosaic) kernels,
+        which GSPMD cannot partition.
 
         **Launch-time queue binding**: per-node modeled events are appended
         to ``queue`` — the *caller's* queue — defaulting to the capture
@@ -1611,7 +1625,10 @@ class CommandGraph:
             if findings:
                 from ..analyze.graph import GraphVerifyError
                 raise GraphVerifyError(findings)
-        fn = self._fused(donate_key, in_sh, out_sh)
+        if per_device and (in_sh is None or out_sh is None):
+            raise ValueError("per_device launches need in_shardings and "
+                             "out_shardings on one mesh")
+        fn = self._fused(donate_key, in_sh, out_sh, per_device)
         t0 = time.perf_counter()
         with warnings.catch_warnings():
             # CPU backends warn that donated buffers were unused; donation

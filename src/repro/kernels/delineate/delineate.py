@@ -41,7 +41,9 @@ def _delineate_kernel(xp_ref, xc_ref, xn_ref, o_ref, *, block: int, n: int,
     t = jnp.asarray(thr, xc.dtype)
     is_peak = (xc > prev) & (xc >= nxt) & (xc > t) & interior
     is_trough = (xc < prev) & (xc <= nxt) & (xc < -t) & interior
-    o_ref[...] = is_peak.astype(jnp.int8) - is_trough.astype(jnp.int8)
+    # Mosaic has no int8 subtract: subtract in int32, narrow on the store
+    o_ref[...] = (is_peak.astype(jnp.int32)
+                  - is_trough.astype(jnp.int32)).astype(jnp.int8)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "thr", "true_n"))

@@ -29,9 +29,19 @@ def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...]
-    b = b_ref[...]
-    acc_ref[...] += jnp.dot(a, b, preferred_element_type=acc_ref.dtype)
+    if jnp.issubdtype(acc_ref.dtype, jnp.integer):
+        # The v5e MXU takes no int32 operands, so integer GeMM is an exact
+        # VPU multiply-accumulate: one rank-1 update per k of the tile
+        # (wrapping int32 arithmetic, as numpy's int32 matmul).
+        acc = acc_ref[...]
+        for kk in range(a_ref.shape[1]):
+            col = a_ref[:, pl.ds(kk, 1)].astype(acc.dtype)
+            row = b_ref[pl.ds(kk, 1), :].astype(acc.dtype)
+            acc = acc + col * row
+        acc_ref[...] = acc
+    else:
+        acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
+                                preferred_element_type=acc_ref.dtype)
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _store():
@@ -73,9 +83,11 @@ def tiles_from_knobs(knobs: KernelKnobs, m: int, n: int, k: int,
     bn = min(knobs.lane_tile, max(128, n))
     bm = min(max(knobs.sublane_tile * 16, 128), max(128, m))
     bk = 128
-    # shrink bm until A+B+acc blocks (x pipeline depth) fit the budget
+    # shrink bm until the A, B, output and accumulator blocks (x pipeline
+    # depth) fit the budget
     while True:
-        blocks = (bm * bk * itemsize, bk * bn * itemsize, bm * bn * 4)
+        blocks = (bm * bk * itemsize, bk * bn * itemsize, bm * bn * 4,
+                  bm * bn * 4)
         try:
             check_vmem_budget(knobs, *blocks)
             break

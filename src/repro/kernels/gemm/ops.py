@@ -6,6 +6,7 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.numpy as jnp
 
 from ...core.device import EGPU_16T, EGPUConfig, KernelKnobs
 from ...core.program import kernel_family
@@ -23,6 +24,10 @@ def gemm(a: jax.Array, b: jax.Array, knobs: KernelKnobs | None = None) -> jax.Ar
     m, k = a.shape
     _, n = b.shape
     bm, bn, bk = tiles_from_knobs(knobs, m, n, k, a.dtype.itemsize)
+    if jnp.issubdtype(a.dtype, jnp.integer):
+        # the integer kernel unrolls one VPU update of the whole output tile
+        # per k: 128x128 tiles keep that code small
+        bm, bn = min(bm, 128), min(bn, 128)
     bm, bn, bk = min(bm, round_up(m, 8)), min(bn, round_up(n, 128)), min(bk, round_up(k, 128))
     ap = pad_dim(pad_dim(a, 0, bm), 1, bk)
     bp = pad_dim(pad_dim(b, 0, bk), 1, bn)

@@ -31,6 +31,18 @@ from jax.experimental.pallas import tpu as pltpu
 from ..common import use_interpret
 
 
+def _cumsum_rows(x):
+    """Inclusive prefix sum over axis 0 by log-step shifts (Mosaic has no
+    cumsum): after the step with shift ``s`` each row holds the sum of the
+    ``2s`` rows ending at it."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    s = 1
+    while s < x.shape[0]:
+        x = x + jnp.where(rows >= s, pltpu.roll(x, s, 0), 0.0)
+        s *= 2
+    return x
+
+
 def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, sout_ref, s_ref,
                   *, chunk: int, steps: int):
     t_idx = pl.program_id(2)
@@ -46,26 +58,30 @@ def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, sout_ref, s_ref,
     w = w_ref[0, 0].astype(f32)
     u = u_ref[0].astype(f32)             # (1, D)
 
-    lw = jnp.cumsum(jnp.log(w), axis=0)              # (C, D), <= 0
+    lw = _cumsum_rows(jnp.log(w))                    # (C, D), <= 0
     lw_prev = lw - jnp.log(w)                        # exclusive cumsum
     # pairwise decay e^{Lw[t-1] - Lw[s]} for s < t, strictly causal
     diff = lw_prev[:, None, :] - lw[None, :, :]      # (C, C, D)
-    ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    strict = (ti > si)[:, :, None]
+    ti = jax.lax.broadcasted_iota(jnp.int32, diff.shape, 0)
+    si = jax.lax.broadcasted_iota(jnp.int32, diff.shape, 1)
+    strict = ti > si
     decay = jnp.where(strict, jnp.exp(jnp.where(strict, diff, 0.0)), 0.0)
-    a = jnp.einsum("ti,tsi,si->ts", r, decay, k)     # strictly-lower triangle
-    a_diag = jnp.sum(r * u * k, axis=1)              # (C,)
-    a = a + a_diag[:, None] * (ti == si).astype(f32)
+    # strictly-lower triangle of sum_i r[t,i] decay[t,s,i] k[s,i]
+    a = jnp.sum(r[:, None, :] * decay * k[None, :, :], axis=-1)
+    a_diag = jnp.sum(r * u * k, axis=1, keepdims=True)   # (C, 1)
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1))
+    a = a + a_diag * eye.astype(f32)
     y_intra = jnp.dot(a, v, preferred_element_type=f32)
 
     s0 = s_ref[...]                                  # (D, D)
     y_state = jnp.dot(r * jnp.exp(lw_prev), s0, preferred_element_type=f32)
     y_ref[0, 0] = (y_intra + y_state).astype(y_ref.dtype)
 
-    w_total = jnp.exp(lw[-1])                        # (D,)
-    k_scaled = k * jnp.exp(lw[-1][None, :] - lw)     # (C, D), <= k
-    s_ref[...] = w_total[:, None] * s0 + jnp.dot(
+    lw_last = lw[chunk - 1:chunk]                    # (1, D)
+    w_total = jnp.exp(lw_last).reshape(-1, 1)        # (D, 1)
+    k_scaled = k * jnp.exp(lw_last - lw)             # (C, D), <= k
+    s_ref[...] = w_total * s0 + jnp.dot(
         k_scaled.T, v, preferred_element_type=f32)
 
     @pl.when(t_idx == steps - 1)
@@ -92,7 +108,8 @@ def rwkv6_scan_pallas(r: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, 1, chunk, d), lambda b_, h_, i: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, chunk, d), lambda b_, h_, i: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, chunk, d), lambda b_, h_, i: (b_, h_, i, 0)),
-            pl.BlockSpec((1, d), lambda b_, h_, i: (h_, 0)),
+            # (1, D) of each head: the last two block dims span the array
+            pl.BlockSpec((1, 1, d), lambda b_, h_, i: (h_, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, chunk, d), lambda b_, h_, i: (b_, h_, i, 0)),
@@ -104,5 +121,5 @@ def rwkv6_scan_pallas(r: jax.Array, k: jax.Array, v: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
         interpret=use_interpret(),
-    )(r, k, v, w, u)
+    )(r, k, v, w, u.reshape(h, 1, d))
     return y, s

@@ -20,7 +20,6 @@ import json
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..distributed.compression import compressed_psum
@@ -41,18 +40,18 @@ def main():
     def plain(gg, ee):
         def body(x):
             return jax.lax.pmean(x, "pod")
-        fn = shard_map(body, mesh=mesh, in_specs=P("data", "model"),
-                       out_specs=P("data", "model"), check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=P("data", "model"),
+                           out_specs=P("data", "model"), check_vma=False)
         return fn(gg), ee
 
     def compressed(gg, ee):
         def body(x, err):
             out, new_err = compressed_psum({"g": x}, {"g": err}, "pod")
             return out["g"], new_err["g"]
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(P("data", "model"), P("data", "model")),
-                       out_specs=(P("data", "model"), P("data", "model")),
-                       check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P("data", "model"), P("data", "model")),
+                           out_specs=(P("data", "model"), P("data", "model")),
+                           check_vma=False)
         return fn(gg, ee)
 
     rec = {}

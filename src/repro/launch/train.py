@@ -29,6 +29,7 @@ from ..models.params import init_params
 from ..models.transformer import model_spec
 from ..optim import adamw_init, wsd_schedule
 from ..train.step import TrainConfig, make_train_step
+from .compile_cache import enable_compile_cache
 
 
 def build_host_trainer(cfg, tcfg: TrainConfig, seed: int = 0):
@@ -107,6 +108,7 @@ def main():
     ap.add_argument("--simulate-failure", type=int, default=0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = ARCHS[args.arch]
     if args.smoke:

@@ -52,7 +52,6 @@ def _context_parallel_attention(q, k, v, cfg: ModelConfig) -> jax.Array:
     imbalance; documented as future work in DESIGN.md.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh = active_mesh()
     rules = active_rules()
@@ -67,8 +66,8 @@ def _context_parallel_attention(q, k, v, cfg: ModelConfig) -> jax.Array:
         offset = jax.lax.axis_index("model") * ql.shape[2]
         return _xla_full(ql, kf, vf, scale, True, bk=512, q_offset=offset)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
-                   out_specs=q_spec, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
+                       out_specs=q_spec, check_vma=False)
     return fn(q, k, v)
 
 

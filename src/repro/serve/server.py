@@ -64,7 +64,7 @@ from .batching import BucketBatcher, MicroBatch, batched_stages
 from .cache import GraphCache, stages_signature
 from .dispatch import (DispatchError, LaunchTicket, MultiQueueDispatcher,
                        PowerBudgetError, QueueStats, QueueWorker)
-from .faults import FaultPlan
+from .faults import FaultPlan, InjectedFault
 from .power import PowerBudget
 
 PERCENTILES = (50, 90, 99)
@@ -871,7 +871,7 @@ class Server:
             slot = self._estate.free_slots()[0]
             try:
                 prefix = eng.prefill(None, prompt, rid=rid)
-            except Exception as e:                   # injected fault etc.
+            except (InjectedFault, DispatchError) as e:
                 self._eng_streams.pop(rid, None)
                 self._record_shed(rid, f"engine prefill failed: {e}")
                 continue
@@ -896,10 +896,11 @@ class Server:
             return False
         try:
             self._estate, toks = eng.generate(None, self._estate)
-        except Exception as e:
-            # the persistent decode state is poisoned mid-flight (injected
-            # fault or a donated-buffer launch failure): shed every active
-            # rid LOUDLY and reset the state — no request is silently lost
+        except (InjectedFault, DispatchError) as e:
+            # the persistent decode state is poisoned mid-flight by a failed
+            # launch: shed every active rid LOUDLY and reset the state — no
+            # request is silently lost.  Any other error is a real failure
+            # (compiler, out of memory, bug) and propagates to the caller.
             for rid, rec in list(self._eng_active.items()):
                 self._eng_streams.pop(rid, None)
                 self._record_shed(rid, f"engine generate failed: {e}")

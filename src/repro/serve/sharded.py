@@ -251,8 +251,14 @@ class ShardedWorker(QueueWorker):
         # sharded work, exactly like the plain-lane path
         spike_s = self._fault_gate()
         in_sh, out_sh, shards, axis_factor = self.shardings_for(graph)
+        # a pure data-parallel launch (every constant replicated) runs each
+        # device's batch slice under shard_map, which Pallas kernels need;
+        # model-parallel constants leave the partitioning to GSPMD
+        n_req = getattr(graph, "n_request_inputs", len(in_sh))
+        per_device = all(sh.spec == P() for sh in in_sh[n_req:])
         outs = graph.launch_prefix(batch.inputs, queue=self.queue,
-                                   in_shardings=in_sh, out_shardings=out_sh)
+                                   in_shardings=in_sh, out_shardings=out_sh,
+                                   per_device=per_device)
         fused, energy = graph.fused_modeled()
         if fused is not None:
             # transfer + compute split across the mesh slices; startup +

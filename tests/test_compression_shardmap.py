@@ -4,7 +4,6 @@ and int8-wire verification on the lowered multipod HLO."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.compression import compressed_psum
@@ -21,9 +20,9 @@ def test_compressed_psum_single_participant_exact():
     def body(gg, ee):
         return compressed_psum(gg, ee, "data")
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(), P()), out_specs=(P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(), P()), out_specs=(P(), P()),
+                       check_vma=False)
     out, err = fn(g, e)
     np.testing.assert_allclose(np.asarray(out["w"]) + np.asarray(err["w"]),
                                np.asarray(g["w"]), rtol=1e-5, atol=1e-5)
@@ -39,8 +38,8 @@ def test_compressed_wire_is_int8_in_jaxpr():
     def body(gg, ee):
         return compressed_psum({"w": gg}, {"w": ee}, "data")
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                   out_specs=(P(), P()), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                       out_specs=(P(), P()), check_vma=False)
     jaxpr = str(jax.make_jaxpr(fn)(g, e))
     assert "all_gather" in jaxpr
     # the big gathered operand is int8; only the (1,)-scale gathers are f32

@@ -29,6 +29,8 @@ from repro.configs import ARCHS
 from repro.models import init_params, model_spec
 from repro.obs import Tracer
 from repro.serve import DecodeEngine, EngineHTTPServer, Server
+from repro.serve.faults import InjectedFault
+from repro.serve.server import AdmissionError
 from repro.train.serve import greedy_generate
 
 BATCH, PROMPT, NEW = 2, 12, 4
@@ -212,3 +214,45 @@ def test_http_ingress_smoke():
         assert status == 200
         assert toks == [int(t) for t in ref[i]]
     assert bad[0] == 400
+
+
+def _fail_engine(monkeypatch, eng, phase, exc):
+    def boom(*args, **kwargs):
+        raise exc
+    monkeypatch.setattr(eng, phase, boom)
+
+
+@pytest.mark.parametrize("phase", ["prefill", "generate"])
+def test_engine_injected_fault_sheds_loudly(phase, monkeypatch):
+    """An injected launch fault sheds the request: result() names why."""
+    cfg, params, prompts = _setup("qwen2.5-3b", 1, PROMPT)
+    eng = DecodeEngine(cfg, params, num_slots=1, max_len=PROMPT + NEW + 1)
+    srv = Server((), workers=(), engine=eng)
+    if phase == "prefill":
+        _fail_engine(monkeypatch, eng, phase, InjectedFault("lane down"))
+    rid = srv.submit_decode(prompts[0], max_new=NEW)
+    if phase == "generate":
+        _fail_engine(monkeypatch, eng, phase, InjectedFault("lane down"))
+    srv.flush()
+    with pytest.raises(AdmissionError, match=f"engine {phase} failed"):
+        srv.result(rid)
+
+
+@pytest.mark.parametrize("phase", ["prefill", "generate"])
+def test_engine_real_error_propagates(phase, monkeypatch):
+    """A failure that is no injected fault (a compiler or memory error, a
+    bug) reaches the caller instead of ending as a shed request."""
+    cfg, params, prompts = _setup("qwen2.5-3b", 1, PROMPT)
+    eng = DecodeEngine(cfg, params, num_slots=1, max_len=PROMPT + NEW + 1)
+    srv = Server((), workers=(), engine=eng)
+    err = RuntimeError("RESOURCE_EXHAUSTED: out of memory")
+    if phase == "prefill":
+        _fail_engine(monkeypatch, eng, phase, err)
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            srv.submit_decode(prompts[0], max_new=NEW)
+    else:
+        rid = srv.submit_decode(prompts[0], max_new=NEW)
+        _fail_engine(monkeypatch, eng, phase, err)
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            list(srv.stream(rid))
+    assert srv.n_shed == 0

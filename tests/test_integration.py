@@ -8,9 +8,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.apps.tinybio import TINYBIO_WORKLOAD, run_tinybio, synth_signal
+from repro.apps.tinybio import (TINYBIO_WORKLOAD, run_tinybio, synth_signal,
+                                tinybio_stages)
 from repro.configs import ARCHS
-from repro.core import EGPU_4T, EGPU_16T
+from repro.core import APU, EGPU_4T, EGPU_16T
 from repro.train.step import TrainConfig
 from repro.launch.train import train_loop
 
@@ -26,6 +27,17 @@ def test_tinybio_pipeline_functional():
     assert len(report.stages) == 4
     assert report.overall_speedup > 3.0
     assert report.overall_energy_reduction > 1.4
+
+
+def test_tinybio_pallas_matches_ref_pipeline():
+    """The Pallas pipeline agrees with the same stages built from the
+    kernels' ref.py oracles (stage by stage through the APU)."""
+    apu = APU(EGPU_16T)
+    (got,), _ = apu.offload(*tinybio_stages(EGPU_16T, seed=2))
+    (ref,), _ = apu.offload(*tinybio_stages(EGPU_16T, seed=2,
+                                            use_pallas=False))
+    np.testing.assert_allclose(np.asarray(got.data), np.asarray(ref.data),
+                               rtol=1e-4, atol=1e-5)
 
 
 def test_tinybio_speedup_grows_with_config():

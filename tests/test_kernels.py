@@ -204,6 +204,20 @@ def test_rwkv6_scan(b, h, t, d):
     np.testing.assert_allclose(s, sr, rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("b,h,t,d", [(1, 2, 64, 16), (2, 3, 40, 8)])
+def test_rwkv6_scan_pallas_interpret(b, h, t, d):
+    """The kernel the chip runs (chunked, state in VMEM scratch), in
+    interpret mode, against the sequential oracle."""
+    r, k, v = rand(b, h, t, d, scale=0.3), rand(b, h, t, d, scale=0.3), \
+        rand(b, h, t, d, scale=0.3)
+    w = jnp.asarray(RNG.random((b, h, t, d)).astype(np.float32) * 0.5 + 0.3)
+    u = rand(h, d, scale=0.3)
+    y, s = rwkv6_scan(r, k, v, w, u, impl="pallas")
+    yr, sr = rwkv6_scan_ref(r, k, v, w, u)
+    np.testing.assert_allclose(y, yr, rtol=2e-3, atol=2e-3)
+    np.testing.assert_allclose(s, sr, rtol=2e-3, atol=2e-3)
+
+
 def test_rwkv6_chunked_equals_sequential():
     """State chaining across chunks is exact."""
     b, h, t, d = 1, 2, 64, 16

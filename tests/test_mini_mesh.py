@@ -25,6 +25,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCHS
 from repro.data import DataConfig, SyntheticLMData
+from repro.launch.mesh import auto_mesh
 from repro.distributed.sharding import (SERVE_RULES, TRAIN_FSDP_RULES,
                                         activate, param_shardings, spec_for)
 from repro.models import init_params, model_spec
@@ -33,7 +34,7 @@ from repro.optim import adamw_init, constant_schedule
 from repro.train.step import TrainConfig, make_train_step
 
 assert len(jax.devices()) == 8, jax.devices()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = auto_mesh((2, 4), ("data", "model"))
 
 def reduced(arch):
     cfg = ARCHS[arch].reduced()

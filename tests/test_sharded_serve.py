@@ -29,6 +29,7 @@ import pytest
 from repro.core import EGPU_16T, Kernel, Stage
 from repro.kernels.gemm.ref import counts as gemm_counts
 from repro.kernels.gemm.ref import gemm_ref
+from repro.launch.mesh import auto_mesh
 from repro.serve import (GraphCache, QueueWorker, Server, ShardedWorker,
                          data_mesh, shard_breakdown)
 
@@ -211,7 +212,7 @@ def test_const_axes_shard_model_parallel_stage_args():
     stages = [Stage(Kernel("mlp", executor=mlp,
                            counts=lambda **kw: gemm_counts(m=d, n=d, k=d)),
                     consts=(w,), n_inputs=1)]
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = auto_mesh((1, 2), ("data", "model"))
     worker = ShardedWorker(EGPU_16T, mesh, name="mp",
                            const_axes=((None, "mlp"),))
     srv = Server(stages, workers=(worker,), bucket_sizes=(8,), max_batch=2)
