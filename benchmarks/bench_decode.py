@@ -15,10 +15,6 @@ The roofline readout comes straight off the captured schedule
 (:class:`~repro.serve.EngineRoofline`): bytes/step, the bandwidth-floor
 step time, and how memory-bound the step is.
 
-A traced arm replays the engine workload under a :class:`Tracer` on a
-virtual clock and asserts ZERO modeled perturbation against an untraced
-twin — per-step ``engine.generate`` spans are free.
-
 Results append to ``BENCH_serve.json`` tagged ``bench="decode"``.
 """
 
@@ -31,7 +27,6 @@ import numpy as np
 
 from repro.configs import ARCHS
 from repro.models import init_params, model_spec
-from repro.obs import Tracer
 from repro.serve import DecodeEngine
 from repro.train.serve import greedy_generate
 
@@ -73,11 +68,9 @@ def _workload(eng, prompts):
     return outs
 
 
-def _arm(cfg, params, prompts, *, resident, tracer=None, clock=None):
+def _arm(cfg, params, prompts, *, resident):
     eng = DecodeEngine(cfg, params, num_slots=SLOTS,
-                       max_len=MAX_LEN, resident=resident,
-                       tracer=tracer,
-                       clock=clock if clock is not None else time.perf_counter)
+                       max_len=MAX_LEN, resident=resident)
     outs = _workload(eng, prompts)             # warm: captures both graphs
     t0 = time.perf_counter()
     outs2 = _workload(eng, prompts)            # steady state: replay only
@@ -124,8 +117,6 @@ def run():
           f"{roof.min_step_s * 1e6:.1f} us bandwidth floor, "
           f"{roof.mem_bound_fraction:.0%} memory-bound")
 
-    traced = _traced_arm(cfg, params, prompts)
-
     result = {
         "bench": "decode",
         "arch": ARCH,
@@ -144,34 +135,10 @@ def run():
         },
         "bit_identical_to_greedy": True,
         "cache_stats": engine.cache.stats(),
-        "traced": traced,
     }
     history = append_entry(OUT_PATH, result)
     print(f"  appended to {OUT_PATH.name} (run #{len(history)})")
     return result
-
-
-def _traced_arm(cfg, params, prompts):
-    """Tracing must not perturb the modeled totals by one bit."""
-    t = [0.0]
-    tracer = Tracer()
-    eng_t, outs_t, _ = _arm(cfg, params, prompts, resident=True,
-                            tracer=tracer, clock=lambda: t[0])
-    eng_u, outs_u, _ = _arm(cfg, params, prompts, resident=True,
-                            clock=lambda: t[0])
-    assert outs_t == outs_u, "tracing perturbed the decoded tokens"
-    totals_t = (eng_t.n_steps, eng_t.n_tokens, eng_t.n_prefills,
-                eng_t.prefill_modeled_s, eng_t.decode_modeled_s,
-                eng_t.energy_j, eng_t.occupancy)
-    totals_u = (eng_u.n_steps, eng_u.n_tokens, eng_u.n_prefills,
-                eng_u.prefill_modeled_s, eng_u.decode_modeled_s,
-                eng_u.energy_j, eng_u.occupancy)
-    assert totals_t == totals_u, "tracing perturbed the modeled totals"
-    n_gen = len([s for s in tracer.spans if s.name == "engine.generate"])
-    assert n_gen == eng_t.n_steps, (n_gen, eng_t.n_steps)
-    print(f"  traced arm: {n_gen} engine.generate spans, modeled totals "
-          f"identical to untraced twin")
-    return {"n_generate_spans": n_gen, "modeled_totals_equal": True}
 
 
 if __name__ == "__main__":

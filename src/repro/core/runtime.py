@@ -109,6 +109,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..obs.profiler import span
 from .device import EGPUConfig, EGPU_16T, HOST
 from .machine import (PhaseBreakdown, WorkCounts, egpu_time, fuse_breakdowns,
                       host_time, transfer_time)
@@ -1100,10 +1101,15 @@ class CommandGraph:
     while the wall-clock path is fused; :meth:`fused_modeled` walks the
     dependency DAG's critical path, so concurrent branches overlap instead
     of summing.
+
+    A sealed graph is named after its kernel nodes (``engine.generate``;
+    a pipeline's stages joined by ``+``): the name labels its profiler
+    spans, and its compiled program is ``jit_<name>`` on the device.
     """
 
     def __init__(self, queue: CommandQueue):
         self.queue = queue                     # home queue: default binding
+        self.name = "graph"                    # its kernels', once sealed
         self.queues: List[CommandQueue] = [queue]
         self.nodes: List[GraphNode] = []
         self._n_slots = 0
@@ -1142,6 +1148,8 @@ class CommandGraph:
         # Only a capture body that completed cleanly yields a launchable
         # graph; an exception mid-capture leaves a truncated chain.
         self._sealed = exc_type is None
+        self.name = "+".join(n.kernel.name for n in self.nodes
+                             if n.kind == "kernel") or "graph"
         # REPRO_VERIFY=1 (repro.analyze): sanitize every capture at seal
         # time, so a whole test/bench run doubles as a sanitizer sweep.
         if (self._sealed and self.nodes
@@ -1511,6 +1519,8 @@ class CommandGraph:
                     vals[slot] = o
             return tuple(vals[s] for s in out_slots)
 
+        # the compiled program's name on the device (``jit_<name>``)
+        run.__name__ = run.__qualname__ = self.name
         jit_kwargs: Dict[str, Any] = {}
         if in_shardings is not None:
             jit_kwargs["in_shardings"] = in_shardings
@@ -1571,92 +1581,101 @@ class CommandGraph:
         booked there too — per-queue totals are per *launching* queue, not
         per device; read :meth:`modeled_breakdowns` for the per-node /
         per-device split.
+
+        Each launch is a ``graph.launch`` profiler span
+        (:func:`repro.obs.span`) carrying the graph's ``name`` and
+        ``first``: 1 where this launch compiled its binding.
         """
-        if any(q._capture is self for q in self.queues):
-            raise RuntimeError("cannot launch while still capturing")
-        if not self._sealed:
-            raise RuntimeError(
-                "capture did not complete cleanly; re-capture the chain "
-                "before launching")
-        if not any(n.out_slots for n in self.nodes):
-            raise RuntimeError(
-                "cannot launch an empty CommandGraph (no kernel nodes)")
-        if donate and not inputs:
-            # Donating the graph's own captured arrays would poison every
-            # later zero-argument launch on backends that honor donation.
-            raise ValueError(
-                "donate requires explicit launch inputs: the captured "
-                "external arrays must stay valid for later launches")
-        ext = list(inputs) if inputs else list(self._ext_values)
-        if len(ext) != len(self._ext_slots):
-            raise ValueError(
-                f"graph takes {len(self._ext_slots)} external inputs, "
-                f"got {len(ext)}")
-        ext = [jnp.asarray(x) for x in ext]
-        # Shape/dtype must match the capture: a silent retrace would attach
-        # capture-time modeled costs to a differently-sized computation.
-        for i, (x, aval) in enumerate(zip(ext, self._ext_avals)):
-            if x.shape != aval.shape or x.dtype != aval.dtype:
+        with span("graph.launch", graph=self.name) as sp:
+            if any(q._capture is self for q in self.queues):
+                raise RuntimeError("cannot launch while still capturing")
+            if not self._sealed:
+                raise RuntimeError(
+                    "capture did not complete cleanly; re-capture the chain "
+                    "before launching")
+            if not any(n.out_slots for n in self.nodes):
+                raise RuntimeError(
+                    "cannot launch an empty CommandGraph (no kernel nodes)")
+            if donate and not inputs:
+                # Donating the graph's own captured arrays would poison every
+                # later zero-argument launch on backends that honor donation.
                 raise ValueError(
-                    f"launch input {i} is {x.shape}/{x.dtype}, but the graph "
-                    f"was captured with {aval.shape}/{aval.dtype}; re-capture "
-                    "for a different problem size")
-        in_sh = None
-        if in_shardings is not None:
-            in_sh = tuple(in_shardings)
-            if len(in_sh) != len(self._ext_slots):
+                    "donate requires explicit launch inputs: the captured "
+                    "external arrays must stay valid for later launches")
+            ext = list(inputs) if inputs else list(self._ext_values)
+            if len(ext) != len(self._ext_slots):
                 raise ValueError(
-                    f"in_shardings must cover all {len(self._ext_slots)} "
-                    f"external inputs (None for unconstrained), got "
-                    f"{len(in_sh)}")
-        out_sh = None
-        if out_shardings is not None:
-            out_sh = tuple(out_shardings)
-            n_out = len(self._output_slots())
-            if len(out_sh) != n_out:
-                raise ValueError(
-                    f"out_shardings must cover all {n_out} graph outputs "
-                    f"(None for unconstrained), got {len(out_sh)}")
-        donate_key = tuple(sorted(int(i) for i in donate))
-        if donate_key and os.environ.get("REPRO_VERIFY") == "1":
-            # donation-aware sweep (memoized): a reader of a donated slot
-            # off the ordered path would observe reused storage
-            findings = self.verify(donate=donate_key)
-            if findings:
-                from ..analyze.graph import GraphVerifyError
-                raise GraphVerifyError(findings)
-        if per_device and (in_sh is None or out_sh is None):
-            raise ValueError("per_device launches need in_shardings and "
-                             "out_shardings on one mesh")
-        fn = self._fused(donate_key, in_sh, out_sh, per_device)
-        t0 = time.perf_counter()
-        with warnings.catch_warnings():
-            # CPU backends warn that donated buffers were unused; donation
-            # is best-effort there by design.
-            warnings.filterwarnings(
-                "ignore", message=".*donated.*", category=UserWarning)
-            raw = fn(*ext)
-        dispatch = time.perf_counter() - t0
-        outs = tuple(Buffer(r) for r in raw)
-        if queue_events:
-            target = queue if queue is not None else self.queue
-            # Outputs belong to the node that produced them (mirrors
-            # _output_slots): the last out_slot-bearing node, or — when the
-            # capture ends in explicit reads — each trailing read node gets
-            # its own read-back buffer.
-            slot_buf = dict(zip(self._output_slots(), outs))
-            for i, node in enumerate(self.nodes):
-                node_outs = tuple(slot_buf[s] for s in node.out_slots
-                                  if s in slot_buf)
-                per_node = dispatch if i == 0 else 0.0
-                ev = Event(node.kernel, node_outs, node.modeled,
-                           node.energy_j, per_node)
-                target._events.append(ev)
-                if target._tracer is not None:
-                    target._trace_event(ev)
-                for b in node_outs:      # dataflow edge for later eager
-                    b._event = ev        # consumers, same as enqueue
-        return outs
+                    f"graph takes {len(self._ext_slots)} external inputs, "
+                    f"got {len(ext)}")
+            ext = [jnp.asarray(x) for x in ext]
+            # Shape/dtype must match the capture: a silent retrace would attach
+            # capture-time modeled costs to a differently-sized computation.
+            for i, (x, aval) in enumerate(zip(ext, self._ext_avals)):
+                if x.shape != aval.shape or x.dtype != aval.dtype:
+                    raise ValueError(
+                        f"launch input {i} is {x.shape}/{x.dtype}, but the "
+                        f"graph was captured with {aval.shape}/{aval.dtype}; "
+                        "re-capture for a different problem size")
+            in_sh = None
+            if in_shardings is not None:
+                in_sh = tuple(in_shardings)
+                if len(in_sh) != len(self._ext_slots):
+                    raise ValueError(
+                        f"in_shardings must cover all {len(self._ext_slots)} "
+                        f"external inputs (None for unconstrained), got "
+                        f"{len(in_sh)}")
+            out_sh = None
+            if out_shardings is not None:
+                out_sh = tuple(out_shardings)
+                n_out = len(self._output_slots())
+                if len(out_sh) != n_out:
+                    raise ValueError(
+                        f"out_shardings must cover all {n_out} graph outputs "
+                        f"(None for unconstrained), got {len(out_sh)}")
+            donate_key = tuple(sorted(int(i) for i in donate))
+            if donate_key and os.environ.get("REPRO_VERIFY") == "1":
+                # donation-aware sweep (memoized): a reader of a donated slot
+                # off the ordered path would observe reused storage
+                findings = self.verify(donate=donate_key)
+                if findings:
+                    from ..analyze.graph import GraphVerifyError
+                    raise GraphVerifyError(findings)
+            if per_device and (in_sh is None or out_sh is None):
+                raise ValueError("per_device launches need in_shardings and "
+                                 "out_shardings on one mesh")
+            n_compiled = len(self._jit_cache)
+            fn = self._fused(donate_key, in_sh, out_sh, per_device)
+            # 1 where this binding was compiled (or loaded from the
+            # persistent cache) by this launch
+            sp.set_metadata(first=int(len(self._jit_cache) > n_compiled))
+            t0 = time.perf_counter()
+            with warnings.catch_warnings():
+                # CPU backends warn that donated buffers were unused; donation
+                # is best-effort there by design.
+                warnings.filterwarnings(
+                    "ignore", message=".*donated.*", category=UserWarning)
+                raw = fn(*ext)
+            dispatch = time.perf_counter() - t0
+            outs = tuple(Buffer(r) for r in raw)
+            if queue_events:
+                target = queue if queue is not None else self.queue
+                # Outputs belong to the node that produced them (mirrors
+                # _output_slots): the last out_slot-bearing node, or — when
+                # the capture ends in explicit reads — each trailing read
+                # node gets its own read-back buffer.
+                slot_buf = dict(zip(self._output_slots(), outs))
+                for i, node in enumerate(self.nodes):
+                    node_outs = tuple(slot_buf[s] for s in node.out_slots
+                                      if s in slot_buf)
+                    per_node = dispatch if i == 0 else 0.0
+                    ev = Event(node.kernel, node_outs, node.modeled,
+                               node.energy_j, per_node)
+                    target._events.append(ev)
+                    if target._tracer is not None:
+                        target._trace_event(ev)
+                    for b in node_outs:      # dataflow edge for later eager
+                        b._event = ev        # consumers, same as enqueue
+            return outs
 
     def launch_prefix(self, inputs: Sequence[Any],
                       **launch_kwargs: Any) -> Tuple[Buffer, ...]:

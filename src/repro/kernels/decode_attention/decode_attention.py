@@ -111,5 +111,6 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((g, 1), jnp.float32),
         ],
         interpret=use_interpret(),
+        name="decode_attention_pallas",
     )(qg, k, v)
     return (out.reshape(b, h, dv), m.reshape(b, h, 1), l.reshape(b, h, 1))

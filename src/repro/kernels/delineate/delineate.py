@@ -70,4 +70,5 @@ def delineate_pallas(x: jax.Array, thr, *, block: int = 512,
         out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.int8),
         interpret=use_interpret(),
+        name="delineate_pallas",
     )(x2, x2, x2)[0]

@@ -66,5 +66,6 @@ def fir_pallas(x: jax.Array, h: jax.Array, *, block: int = 512,
         out_shape=jax.ShapeDtypeStruct((1, n), x.dtype if fxp_shift is not None
                                        else jnp.float32),
         interpret=use_interpret(),
+        name="fir_pallas",
     )(x2, x2, h2)
     return out[0]

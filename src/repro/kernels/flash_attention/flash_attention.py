@@ -108,4 +108,5 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=use_interpret(),
+        name="flash_attention_pallas",
     )(q, k, v)

@@ -73,6 +73,7 @@ def gemm_pallas(a: jax.Array, b: jax.Array, *, bm: int = 128, bn: int = 128,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         interpret=use_interpret(),
+        name="gemm_pallas",
     )(a, b)
 
 

@@ -90,5 +90,6 @@ def mamba_scan_pallas(x: jax.Array, delta: jax.Array, a: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((bd, n), jnp.float32)],
         interpret=use_interpret(),
+        name="mamba_scan_pallas",
     )(x, delta, a, b, c)
     return y, h

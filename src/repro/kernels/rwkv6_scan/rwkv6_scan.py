@@ -121,5 +121,6 @@ def rwkv6_scan_pallas(r: jax.Array, k: jax.Array, v: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
         interpret=use_interpret(),
+        name="rwkv6_scan_pallas",
     )(r, k, v, w, u.reshape(h, 1, d))
     return y, s

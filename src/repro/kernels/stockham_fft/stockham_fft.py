@@ -73,5 +73,6 @@ def fft_pallas(re: jax.Array, im: jax.Array) -> tuple[jax.Array, jax.Array]:
         out_shape=[jax.ShapeDtypeStruct((bp, n), jnp.float32),
                    jax.ShapeDtypeStruct((bp, n), jnp.float32)],
         interpret=use_interpret(),
+        name="fft_pallas",
     )(re, im)
     return ore[:b], oim[:b]

@@ -67,6 +67,7 @@ def svm_pallas(x: jax.Array, sv: jax.Array, alpha: jax.Array,
         out_shape=jax.ShapeDtypeStruct((q, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32)],
         interpret=use_interpret(),
+        name="svm_pallas",
     )(x.astype(jnp.float32), xsq, sv.astype(jnp.float32), svsq,
       alpha.reshape(m, 1).astype(jnp.float32))
     return out[:, 0]

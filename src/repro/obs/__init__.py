@@ -1,13 +1,25 @@
 """repro.obs — end-to-end observability: spans, traces, metrics.
 
-The machine model already knows where every modeled nanosecond of a
-request goes; this package makes that knowledge inspectable.  Three
-pieces:
+Spans run on two clocks:
 
-* :class:`Tracer` / :class:`Span` — per-request span trees with explicit
-  parent links, recorded on the *modeled virtual clock* (the same injected
-  clock + per-lane ``modeled_busy_until`` discipline as the goodput
-  gates), so traces are deterministic and assertable;
+* the **device clock** — :func:`span` writes a profiler span
+  (``jax.profiler.TraceAnnotation``) at each layer boundary of the served
+  path (``server.``, ``engine.``, ``graph.``, ``batch.``, ``dispatch.``).
+  A profiler trace holds them beside the chip's own operations, on the
+  same clock, so it shows what the host was doing while the device idled;
+  with no profiler session they record nothing (:mod:`.profiler`);
+* the **modeled clock** — the e-GPU machine model knows where every
+  modeled nanosecond of a pipeline request goes, and :class:`Tracer`
+  makes that inspectable.
+
+Four pieces:
+
+* :func:`span` — the profiler spans above;
+* :class:`Tracer` / :class:`Span` — per-request span trees of the
+  pipeline path, with explicit parent links, recorded on the *modeled
+  virtual clock* (the same injected clock + per-lane
+  ``modeled_busy_until`` discipline as the goodput gates), so traces are
+  deterministic and assertable;
 * :meth:`Tracer.to_chrome_json` — a Perfetto/Chrome-trace exporter:
   request trees and per-lane launch slices (sized by each node's captured
   :class:`~repro.core.machine.PhaseBreakdown`, laid out along the DAG
@@ -17,7 +29,7 @@ pieces:
   counters publish into, dumping as :meth:`MetricsRegistry.snapshot` or
   Prometheus text.
 
-Tracing is opt-in and zero-overhead-when-off: ``Server(tracer=...)`` and
+Modeled tracing is opt-in and zero-overhead-when-off: ``Server(tracer=...)`` and
 ``CommandQueue(tracer=...)`` take a tracer explicitly, every hook guards
 on ``tracer is not None``, and telemetry never perturbs modeled totals,
 goodput, or outputs (the traced benchmark arms assert bit-identity).
@@ -78,6 +90,7 @@ per-lane ``repro_lane_idle_power_watts`` /
 
 from .metrics import (DEFAULT_BUCKETS, Counter, Gauge, Histogram,
                       MetricsRegistry)
+from .profiler import span
 from .trace import (TERMINAL_SPANS, Span, Tracer, validate_chrome_trace)
 
 __all__ = [
@@ -89,5 +102,6 @@ __all__ = [
     "Span",
     "TERMINAL_SPANS",
     "Tracer",
+    "span",
     "validate_chrome_trace",
 ]

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from ..core.apu import Stage
 from ..core.machine import WorkCounts
 from ..core.runtime import Kernel
+from ..obs.profiler import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -350,33 +351,34 @@ class BucketBatcher:
     # -- collation ----------------------------------------------------------
     def _collate(self, key: Tuple[Any, ...],
                  reqs: Sequence[ServeRequest]) -> MicroBatch:
-        self.n_batches += 1
-        n_arrays = len(key)
-        stacked = []
-        for j in range(n_arrays):
-            shape, _dtype = key[j]
-            rows = []
-            for r in reqs:
-                a = r.arrays[j]
-                if a.ndim > self.pad_axis:
-                    padded = pad_to(a, shape[self.pad_axis], self.pad_axis,
-                                    self.fill)
-                    self.padded_elements += int(padded.size - a.size)
-                    rows.append(padded)
-                else:
-                    rows.append(a)
-            batch = jnp.stack(rows)
-            if len(reqs) < self.max_batch:          # pad the batch dim too:
-                extra = self.max_batch - len(reqs)  # one shape per bucket
-                batch = jnp.concatenate(
-                    [batch, jnp.full((extra,) + batch.shape[1:], self.fill,
-                                     batch.dtype)])
-                self.padded_elements += extra * math.prod(batch.shape[1:])
-            stacked.append(batch)
-        return MicroBatch(bucket_key=key, inputs=tuple(stacked),
-                          requests=tuple(reqs), capacity=self.max_batch,
-                          pad_axis=self.pad_axis,
-                          crop_outputs=self.crop_outputs)
+        with span("batch.form", n=len(reqs), capacity=self.max_batch):
+            self.n_batches += 1
+            n_arrays = len(key)
+            stacked = []
+            for j in range(n_arrays):
+                shape, _dtype = key[j]
+                rows = []
+                for r in reqs:
+                    a = r.arrays[j]
+                    if a.ndim > self.pad_axis:
+                        padded = pad_to(a, shape[self.pad_axis], self.pad_axis,
+                                        self.fill)
+                        self.padded_elements += int(padded.size - a.size)
+                        rows.append(padded)
+                    else:
+                        rows.append(a)
+                batch = jnp.stack(rows)
+                if len(reqs) < self.max_batch:      # pad the batch dim too:
+                    extra = self.max_batch - len(reqs)  # one shape per bucket
+                    batch = jnp.concatenate(
+                        [batch, jnp.full((extra,) + batch.shape[1:], self.fill,
+                                         batch.dtype)])
+                    self.padded_elements += extra * math.prod(batch.shape[1:])
+                stacked.append(batch)
+            return MicroBatch(bucket_key=key, inputs=tuple(stacked),
+                              requests=tuple(reqs), capacity=self.max_batch,
+                              pad_axis=self.pad_axis,
+                              crop_outputs=self.crop_outputs)
 
 
 # ---------------------------------------------------------------------------

@@ -44,6 +44,7 @@ from ..analyze.graph import GraphVerifyError
 from ..core.apu import APU, Stage
 from ..core.ndrange import NDRange
 from ..core.runtime import CommandGraph
+from ..obs.profiler import span
 
 _SIG_MEMO_CAPACITY = 64
 
@@ -246,8 +247,8 @@ class GraphCache:
                        key_prefix: Optional[Hashable] = None,
                        ) -> Tuple[CommandGraph, bool]:
         """Return ``(graph, hit)`` — capturing (and thereby compiling on
-        first launch) only on a miss.  The entry is promoted to
-        most-recently-used either way."""
+        first launch) only on a miss, inside a ``graph.capture`` profiler
+        span.  The entry is promoted to most-recently-used either way."""
         key = self.key_for(apu, stages, inputs, ndranges, key_prefix)
         graph = self._graphs.get(key)
         if graph is not None:
@@ -255,8 +256,10 @@ class GraphCache:
             self._graphs.move_to_end(key)
             return graph, True
         self.misses += 1
-        graph = apu.capture_pipeline(stages, inputs, ndranges)
-        findings = graph.verify()
+        with span("graph.capture") as sp:
+            graph = apu.capture_pipeline(stages, inputs, ndranges)
+            sp.set_metadata(graph=graph.name)
+            findings = graph.verify()
         self.verified += 1
         self.findings += len(findings)
         if findings and os.environ.get("REPRO_VERIFY") == "1":

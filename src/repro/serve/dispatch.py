@@ -53,6 +53,7 @@ from ..core.machine import PhaseBreakdown
 from ..core.power import egpu_idle_power_mw
 from ..core.runtime import Buffer, CommandGraph
 from ..obs import Tracer
+from ..obs.profiler import span
 from .batching import MicroBatch
 from .faults import FaultPlan, InjectedFault, apply_spike
 from .power import LanePrice, PowerBudget
@@ -711,99 +712,104 @@ class MultiQueueDispatcher:
         every ticket retired for backpressure along the way — including
         by failed attempts.  Raises :class:`DispatchError` (carrying
         those retired tickets) when the attempt budget is exhausted.
+        The whole call is one ``dispatch.launch`` profiler span.
         """
-        self._tick += 1
-        cap = (self.max_attempts if self.max_attempts is not None
-               else 2 * len(self.workers))
-        retired_all: List[LaunchTicket] = []
-        tried: Set[str] = set()
-        last: Optional[InjectedFault] = None
-        for attempt in range(cap):
-            if self.budget is None:
-                worker = self.pick(exclude=tried)
-            else:
-                t_ref = (t_now if t_now is not None
-                         else self.workers[0].clock())
-                est = (estimate_for if estimate_for is not None
-                       else lambda w: w.estimate(graph_for(w)))
-                picked = self._pick_powered(batch, est, t_ref, tried)
-                if picked is None:
-                    self.power_sheds += 1
-                    fleet_mw = self.fleet_power_w(t_ref) * 1e3
-                    if self.tracer is not None:
-                        for req in batch.requests:
-                            self.tracer.request_event(
-                                req.rid, t_ref, "power-shed",
-                                fleet_power_mw=fleet_mw)
-                    raise PowerBudgetError(
-                        f"power budget (lane {self.budget.lane_mw} mW, "
-                        f"fleet {self.budget.fleet_mw} mW) leaves no lane "
-                        f"for a micro-batch of {batch.n_requests} "
-                        f"request(s): modeled fleet draw {fleet_mw:.2f} mW",
-                        retired=retired_all)
-                worker = picked
-            breaker = self.breakers[worker.name]
-            breaker.on_attempt()
-            if self.tracer is not None:
-                t_evt = t_now if t_now is not None else worker.clock()
-                for req in batch.requests:
-                    self.tracer.request_event(
-                        req.rid, t_evt, "dispatch-pick", lane=worker.name,
-                        attempt=attempt)
-            try:
-                ticket, retired = worker.launch(graph_for(worker), batch,
-                                                t_now=t_now)
-            except InjectedFault as e:
-                retired_all.extend(e.retired)
-                trips_before = breaker.trips
-                breaker.record_failure(self._tick)
-                tried.add(worker.name)
-                if len(tried) >= len(self.workers):
-                    tried.clear()        # second pass over the fleet
-                last = e
-                will_retry = attempt + 1 < cap
-                if self.tracer is not None:
-                    t_evt = t_now if t_now is not None else worker.clock()
-                    if breaker.trips > trips_before:
-                        self.tracer.instant(f"lane:{worker.name}", t_evt,
-                                            "breaker-trip",
-                                            cooldown=breaker.cooldown)
-                    for req in batch.requests:
-                        self.tracer.request_event(
-                            req.rid, t_evt, "fault", lane=worker.name,
-                            launch_idx=e.launch_idx, reason=e.reason)
-                        if breaker.trips > trips_before:
-                            self.tracer.request_event(
-                                req.rid, t_evt, "breaker-trip",
-                                lane=worker.name)
-                        if will_retry:
-                            self.tracer.request_event(
-                                req.rid, t_evt, "retry", attempt=attempt)
-                if will_retry:
-                    self.retries += 1
-                    if self.backoff_base_s > 0.0:
-                        backoff_s = min(self.backoff_cap_s,
-                                        self.backoff_base_s * (2 ** attempt))
+        with span("dispatch.launch", n=batch.n_requests) as sp:
+            self._tick += 1
+            cap = (self.max_attempts if self.max_attempts is not None
+                   else 2 * len(self.workers))
+            retired_all: List[LaunchTicket] = []
+            tried: Set[str] = set()
+            last: Optional[InjectedFault] = None
+            for attempt in range(cap):
+                if self.budget is None:
+                    worker = self.pick(exclude=tried)
+                else:
+                    t_ref = (t_now if t_now is not None
+                             else self.workers[0].clock())
+                    est = (estimate_for if estimate_for is not None
+                           else lambda w: w.estimate(graph_for(w)))
+                    picked = self._pick_powered(batch, est, t_ref, tried)
+                    if picked is None:
+                        self.power_sheds += 1
+                        fleet_mw = self.fleet_power_w(t_ref) * 1e3
                         if self.tracer is not None:
                             for req in batch.requests:
                                 self.tracer.request_event(
-                                    req.rid, t_evt, "backoff",
-                                    backoff_s=backoff_s)
-                        time.sleep(backoff_s)
-                continue
-            breaker.record_success()
-            retired_all.extend(retired)
-            if self.budget is not None:
-                # sample the modeled fleet draw with the new launch booked
-                t_ref = t_now if t_now is not None else worker.clock()
-                self.peak_fleet_power_w = max(self.peak_fleet_power_w,
-                                              self.fleet_power_w(t_ref))
-            return ticket, retired_all
-        self.dispatch_failures += 1
-        raise DispatchError(
-            f"micro-batch of {batch.n_requests} request(s) failed all "
-            f"{cap} dispatch attempts (last: {last})",
-            retired=retired_all) from last
+                                    req.rid, t_ref, "power-shed",
+                                    fleet_power_mw=fleet_mw)
+                        raise PowerBudgetError(
+                            f"power budget (lane {self.budget.lane_mw} mW, "
+                            f"fleet {self.budget.fleet_mw} mW) leaves no lane "
+                            f"for a micro-batch of {batch.n_requests} "
+                            f"request(s): modeled fleet draw "
+                            f"{fleet_mw:.2f} mW",
+                            retired=retired_all)
+                    worker = picked
+                breaker = self.breakers[worker.name]
+                breaker.on_attempt()
+                sp.set_metadata(lane=worker.name)
+                if self.tracer is not None:
+                    t_evt = t_now if t_now is not None else worker.clock()
+                    for req in batch.requests:
+                        self.tracer.request_event(
+                            req.rid, t_evt, "dispatch-pick", lane=worker.name,
+                            attempt=attempt)
+                try:
+                    ticket, retired = worker.launch(graph_for(worker), batch,
+                                                    t_now=t_now)
+                except InjectedFault as e:
+                    retired_all.extend(e.retired)
+                    trips_before = breaker.trips
+                    breaker.record_failure(self._tick)
+                    tried.add(worker.name)
+                    if len(tried) >= len(self.workers):
+                        tried.clear()        # second pass over the fleet
+                    last = e
+                    will_retry = attempt + 1 < cap
+                    if self.tracer is not None:
+                        t_evt = t_now if t_now is not None else worker.clock()
+                        if breaker.trips > trips_before:
+                            self.tracer.instant(f"lane:{worker.name}", t_evt,
+                                                "breaker-trip",
+                                                cooldown=breaker.cooldown)
+                        for req in batch.requests:
+                            self.tracer.request_event(
+                                req.rid, t_evt, "fault", lane=worker.name,
+                                launch_idx=e.launch_idx, reason=e.reason)
+                            if breaker.trips > trips_before:
+                                self.tracer.request_event(
+                                    req.rid, t_evt, "breaker-trip",
+                                    lane=worker.name)
+                            if will_retry:
+                                self.tracer.request_event(
+                                    req.rid, t_evt, "retry", attempt=attempt)
+                    if will_retry:
+                        self.retries += 1
+                        if self.backoff_base_s > 0.0:
+                            backoff_s = min(
+                                self.backoff_cap_s,
+                                self.backoff_base_s * (2 ** attempt))
+                            if self.tracer is not None:
+                                for req in batch.requests:
+                                    self.tracer.request_event(
+                                        req.rid, t_evt, "backoff",
+                                        backoff_s=backoff_s)
+                            time.sleep(backoff_s)
+                    continue
+                breaker.record_success()
+                retired_all.extend(retired)
+                if self.budget is not None:
+                    # sample the modeled fleet draw with the new launch booked
+                    t_ref = t_now if t_now is not None else worker.clock()
+                    self.peak_fleet_power_w = max(self.peak_fleet_power_w,
+                                                  self.fleet_power_w(t_ref))
+                return ticket, retired_all
+            self.dispatch_failures += 1
+            raise DispatchError(
+                f"micro-batch of {batch.n_requests} request(s) failed all "
+                f"{cap} dispatch attempts (last: {last})",
+                retired=retired_all) from last
 
     def quarantines(self) -> int:
         """Total circuit-breaker trips across the fleet."""

@@ -56,6 +56,7 @@ from ..core.scheduler import optimal_ndrange
 from ..models.config import ModelConfig
 from ..models.transformer import cache_axes, cache_struct
 from ..obs import Tracer
+from ..obs.profiler import span
 from ..train.serve import make_decode_step, make_prefill_step
 from .batching import MicroBatch
 from .cache import GraphCache
@@ -134,7 +135,7 @@ def build_prefill_kernel(config: EGPUConfig = EGPU_16T, *,
                   counts=_engine_counts)
 
 
-@kernel_family("engine.decode_step", registry=ENGINE_REGISTRY)
+@kernel_family("engine.generate", registry=ENGINE_REGISTRY)
 def build_decode_kernel(config: EGPUConfig = EGPU_16T, *,
                         cfg: ModelConfig, num_slots: int,
                         cache_dtype: str = "bfloat16") -> Kernel:
@@ -170,7 +171,7 @@ def build_decode_kernel(config: EGPUConfig = EGPU_16T, *,
         toks, new_cache = vstep(params, cache, tokens, positions)
         return (toks, *jax.tree_util.tree_leaves(new_cache))
 
-    return Kernel(name="engine.decode_step", executor=engine_decode,
+    return Kernel(name="engine.generate", executor=engine_decode,
                   counts=_engine_counts)
 
 
@@ -321,7 +322,6 @@ class DecodeEngine:
                 "round-tripped through transfer nodes every step")
         self.config = self.worker.apu.egpu.config
         self.cache = cache if cache is not None else GraphCache(capacity=16)
-        self.tracer = tracer
         self.clock = clock
         self._program = Program.build(self.config, registry=ENGINE_REGISTRY)
         self._bidx = batch_axes(cfg)
@@ -362,7 +362,7 @@ class DecodeEngine:
     # -- state construction -------------------------------------------------
     def _decode_kernel(self) -> Kernel:
         kern = self._program.create_kernel(
-            "engine.decode_step", cfg=self.cfg, num_slots=self.num_slots,
+            "engine.generate", cfg=self.cfg, num_slots=self.num_slots,
             cache_dtype=str(self.cache_dtype))
         kern.executor._params_def = jax.tree_util.tree_structure(self.params)
         return kern
@@ -490,67 +490,72 @@ class DecodeEngine:
 
     # -- the JetStream-style API -------------------------------------------
     def prefill(self, params: Optional[Any], prompt: Any,
-                rid: Optional[int] = None) -> Prefix:
+                rid: Optional[int] = None,
+                wait_us: Optional[float] = None) -> Prefix:
         """Run one request's prompt; returns its :class:`Prefix`.
 
         ``params`` may be ``None`` to use the engine's bound params (they
-        are launch inputs either way — the captured graph is pure).
+        are launch inputs either way — the captured graph is pure).  The
+        call is one ``engine.prefill`` profiler span carrying ``rid``, the
+        prompt's length and ``wait_us``, the time the request waited for a
+        slot (the server passes it).
         """
         if params is not None and params is not self.params:
             raise ValueError(
                 "prefill params must be the engine's bound params: the "
                 "captured graphs pin their avals (pass None to reuse)")
-        prompt = jnp.asarray(prompt, jnp.int32)
-        if prompt.ndim == 1:
-            prompt = prompt[None, :]
-        if prompt.ndim != 2 or prompt.shape[0] != 1:
-            raise ValueError(
-                f"prefill takes ONE request's prompt (S,) or (1, S); got "
-                f"shape {tuple(prompt.shape)}")
-        s = int(prompt.shape[1])
-        if s < 1 or s >= self.max_len:
-            raise ValueError(
-                f"prompt length {s} must be in [1, max_len={self.max_len})")
-        graph = self._prefill_graph(prompt)
-        batch = MicroBatch(bucket_key=("engine.prefill", s),
-                           inputs=(prompt, *self._param_leaves),
-                           requests=(), capacity=1, crop_outputs=False)
-        t_now = self.clock()
-        ticket, _ = self.worker.launch(graph, batch, t_now=t_now)
-        outs = ticket.outputs
-        tok = outs[0].data
-        cache = jax.tree_util.tree_unflatten(
-            jax.tree_util.tree_structure(self._bidx),
-            [b.data for b in outs[1:]])
-        jax.block_until_ready(tok)
-        self.worker.drain()
-        modeled = ticket.modeled_latency_s or 0.0
-        self.n_prefills += 1
-        self.prefill_modeled_s += modeled
-        self.energy_j += ticket.energy_j
-        if self.tracer is not None and rid is not None:
-            self.tracer.child(rid, "engine.prefill", t_now,
-                              ticket.t_done_modeled or t_now,
-                              prompt_len=s)
-        return Prefix(token=tok, cache=cache, pos=s, prompt_len=s, rid=rid,
-                      modeled_s=modeled, energy_j=ticket.energy_j)
+        attrs = {k: v for k, v in (("rid", rid), ("wait_us", wait_us))
+                 if v is not None}
+        with span("engine.prefill", **attrs) as sp:
+            prompt = jnp.asarray(prompt, jnp.int32)
+            if prompt.ndim == 1:
+                prompt = prompt[None, :]
+            if prompt.ndim != 2 or prompt.shape[0] != 1:
+                raise ValueError(
+                    f"prefill takes ONE request's prompt (S,) or (1, S); got "
+                    f"shape {tuple(prompt.shape)}")
+            s = int(prompt.shape[1])
+            sp.set_metadata(prompt_len=s)
+            if s < 1 or s >= self.max_len:
+                raise ValueError(f"prompt length {s} must be in "
+                                 f"[1, max_len={self.max_len})")
+            graph = self._prefill_graph(prompt)
+            batch = MicroBatch(bucket_key=("engine.prefill", s),
+                               inputs=(prompt, *self._param_leaves),
+                               requests=(), capacity=1, crop_outputs=False)
+            ticket, _ = self.worker.launch(graph, batch, t_now=self.clock())
+            outs = ticket.outputs
+            tok = outs[0].data
+            cache = jax.tree_util.tree_unflatten(
+                jax.tree_util.tree_structure(self._bidx),
+                [b.data for b in outs[1:]])
+            jax.block_until_ready(tok)
+            self.worker.drain()
+            modeled = ticket.modeled_latency_s or 0.0
+            self.n_prefills += 1
+            self.prefill_modeled_s += modeled
+            self.energy_j += ticket.energy_j
+            return Prefix(token=tok, cache=cache, pos=s, prompt_len=s, rid=rid,
+                          modeled_s=modeled, energy_j=ticket.energy_j)
 
     def insert(self, prefix: Prefix, state: DecodeState,
                slot: int) -> DecodeState:
         """Splice ``prefix`` into ``slot`` — a launch-time buffer update on
-        the persistent state, never a re-capture."""
+        the persistent state, never a re-capture; one ``engine.insert``
+        profiler span."""
         if not 0 <= slot < state.num_slots:
             raise ValueError(f"slot {slot} out of range "
                              f"[0, {state.num_slots})")
         if state.occupied[slot]:
             raise ValueError(f"slot {slot} is occupied (rid="
                              f"{state.rids[slot]}); release it first")
-        state.tokens = state.tokens.at[slot].set(prefix.token[0])
-        state.positions = state.positions.at[slot].set(prefix.pos)
-        state.cache = jax.tree_util.tree_map(
-            lambda dst, src, i: jax.lax.dynamic_update_index_in_dim(
-                dst, jnp.squeeze(src, axis=i).astype(dst.dtype), slot, i),
-            state.cache, prefix.cache, self._bidx)
+        with span("engine.insert", slot=slot):
+            state.tokens = state.tokens.at[slot].set(prefix.token[0])
+            state.positions = state.positions.at[slot].set(prefix.pos)
+            state.cache = jax.tree_util.tree_map(
+                lambda dst, src, i: jax.lax.dynamic_update_index_in_dim(
+                    dst, jnp.squeeze(src, axis=i).astype(dst.dtype), slot, i),
+                state.cache, prefix.cache, self._bidx)
         state.occupied[slot] = True
         state.rids[slot] = prefix.rid
         self.n_inserts += 1
@@ -569,59 +574,48 @@ class DecodeEngine:
 
         Returns ``(state, tokens)`` where ``tokens`` is the realized (B,)
         int32 next-token vector (occupied slots' entries are live; free
-        slots' entries are stale lanes to ignore).
+        slots' entries are stale lanes to ignore).  The call is one
+        ``engine.generate`` profiler span; reading the tokens back and
+        waiting for the new cache is its ``engine.readback`` child.
         """
         if params is not None and params is not self.params:
             raise ValueError(
                 "generate params must be the engine's bound params: the "
                 "captured graph pins their avals (pass None to reuse)")
-        graph = self._generate_graph(state)
-        cache_leaves = jax.tree_util.tree_leaves(state.cache)
-        inputs = (state.tokens, state.positions, *cache_leaves,
-                  *self._param_leaves)
-        # donate exactly the persistent cache leaves (input slots 2..) so
-        # XLA reuses them for the step's outputs instead of allocating a
-        # fresh cache per token
-        donate = tuple(range(2, 2 + len(cache_leaves)))
-        batch = MicroBatch(bucket_key=("engine.generate", self.num_slots),
-                           inputs=inputs, requests=(),
-                           capacity=self.num_slots, crop_outputs=False,
-                           donate=donate)
-        t_now = self.clock()
-        ticket, _ = self.worker.launch(graph, batch, t_now=t_now)
-        outs = ticket.outputs
-        toks = outs[0].data
-        new_leaves = [b.data for b in outs[1:]]
-        # realize BEFORE retiring: the next launch donates these buffers
-        tokens_np = np.asarray(jax.device_get(toks))
-        jax.block_until_ready(new_leaves)
-        self.worker.drain()
-        state.tokens = toks
-        state.positions = state.positions + 1
-        state.cache = jax.tree_util.tree_unflatten(
-            jax.tree_util.tree_structure(self._bidx), new_leaves)
         occ = state.n_occupied
-        modeled = ticket.modeled_latency_s or 0.0
-        self.n_steps += 1
-        self.n_tokens += occ
-        self.decode_modeled_s += modeled
-        self.energy_j += ticket.energy_j
-        self._occupancy_sum += occ / self.num_slots
-        if self.tracer is not None:
-            start = (ticket.t_done_modeled - modeled
-                     if ticket.t_done_modeled is not None else t_now)
-            self.tracer.span(
-                "engine.generate", start,
-                ticket.t_done_modeled if ticket.t_done_modeled is not None
-                else t_now,
-                track=f"engine/{self.name}", step=self.n_steps,
-                occupied=occ, slots=self.num_slots)
-            for slot, rid in enumerate(state.rids):
-                if rid is not None and state.occupied[slot]:
-                    self.tracer.request_event(
-                        rid, ticket.t_done_modeled or t_now, "token",
-                        slot=slot, step=self.n_steps)
-        return state, tokens_np
+        with span("engine.generate", occupied=occ):
+            graph = self._generate_graph(state)
+            cache_leaves = jax.tree_util.tree_leaves(state.cache)
+            inputs = (state.tokens, state.positions, *cache_leaves,
+                      *self._param_leaves)
+            # donate exactly the persistent cache leaves (input slots 2..) so
+            # XLA reuses them for the step's outputs instead of allocating a
+            # fresh cache per token
+            donate = tuple(range(2, 2 + len(cache_leaves)))
+            batch = MicroBatch(bucket_key=("engine.generate", self.num_slots),
+                               inputs=inputs, requests=(),
+                               capacity=self.num_slots, crop_outputs=False,
+                               donate=donate)
+            ticket, _ = self.worker.launch(graph, batch, t_now=self.clock())
+            outs = ticket.outputs
+            toks = outs[0].data
+            new_leaves = [b.data for b in outs[1:]]
+            # realize BEFORE retiring: the next launch donates these buffers
+            with span("engine.readback"):
+                tokens_np = np.asarray(jax.device_get(toks))
+                jax.block_until_ready(new_leaves)
+            self.worker.drain()
+            state.tokens = toks
+            state.positions = state.positions + 1
+            state.cache = jax.tree_util.tree_unflatten(
+                jax.tree_util.tree_structure(self._bidx), new_leaves)
+            modeled = ticket.modeled_latency_s or 0.0
+            self.n_steps += 1
+            self.n_tokens += occ
+            self.decode_modeled_s += modeled
+            self.energy_j += ticket.energy_j
+            self._occupancy_sum += occ / self.num_slots
+            return state, tokens_np
 
     # -- reporting ----------------------------------------------------------
     @property

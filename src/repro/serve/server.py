@@ -60,6 +60,7 @@ import numpy as np
 from ..core.apu import Stage
 from ..core.device import EGPUConfig, EGPU_16T, OP_ANCHOR, env_op_point
 from ..obs import MetricsRegistry, Tracer
+from ..obs.profiler import span
 from .batching import BucketBatcher, MicroBatch, batched_stages
 from .cache import GraphCache, stages_signature
 from .dispatch import (DispatchError, LaunchTicket, MultiQueueDispatcher,
@@ -536,21 +537,19 @@ class Server:
         # -- continuous-batching decode engine (ISSUE 9) --------------------
         #: slot-based decode engine behind :meth:`submit_decode` /
         #: :meth:`stream`; ``None`` keeps the server pipeline-only.  The
-        #: engine adopts the server's clock and tracer so both fronts share
-        #: one timeline and one trace.
+        #: engine adopts the server's clock and its lane the server's tracer,
+        #: so both fronts share one timeline and one trace.
         self.engine = engine
         if engine is not None:
             if clock is not time.perf_counter:
                 engine.clock = clock
                 engine.worker.clock = clock
-            if tracer is not None:
-                if engine.tracer is None:
-                    engine.tracer = tracer
-                if engine.worker.tracer is None:
-                    engine.worker.tracer = tracer
+            if tracer is not None and engine.worker.tracer is None:
+                engine.worker.tracer = tracer
         self._estate = None                  # DecodeState, built on demand
-        #: accepted but not yet slotted: rid -> (prompt, max_new, deadline_s)
-        self._eng_waiting: "OrderedDict[int, Tuple[Any, int, Optional[float]]]" = OrderedDict()
+        #: accepted but not yet slotted:
+        #: rid -> (prompt, max_new, deadline_s, accepted at)
+        self._eng_waiting: "OrderedDict[int, Tuple[Any, int, Optional[float], float]]" = OrderedDict()
         #: slotted and generating: rid -> record dict (slot, remaining, ...)
         self._eng_active: Dict[int, Dict[str, Any]] = {}
         #: per-rid token queues not yet consumed by :meth:`stream` (LRU-
@@ -598,44 +597,48 @@ class Server:
         capacity cannot meet the deadline), without consuming a request
         id.  Returns the request id; fetch its outputs with
         :meth:`result` after a :meth:`flush` (or once enough same-bucket
-        traffic flushed it naturally)."""
-        now = self.clock()
-        if deadline is not None:
-            deadline = float(deadline)
-            if deadline <= 0.0:
-                raise ValueError(
-                    f"deadline must be a positive budget in seconds, "
-                    f"got {deadline}")
-        try:
-            self._admit(now, deadline, priority)
-        except AdmissionError as e:
-            # door rejects never consumed a rid, so they carry no span
-            # tree — the shed decision lands as a track-level instant
+        traffic flushed it naturally).  The call is one ``server.submit``
+        profiler span."""
+        with span("server.submit") as sp:
+            now = self.clock()
+            if deadline is not None:
+                deadline = float(deadline)
+                if deadline <= 0.0:
+                    raise ValueError(
+                        f"deadline must be a positive budget in seconds, "
+                        f"got {deadline}")
+            try:
+                self._admit(now, deadline, priority)
+            except AdmissionError as e:
+                # door rejects never consumed a rid, so they carry no span
+                # tree — the shed decision lands as a track-level instant
+                if self.tracer is not None:
+                    self.tracer.instant("server", now, "shed-at-door",
+                                        reason=str(e), priority=priority)
+                raise
+            req = self.batcher.submit(
+                *arrays, t_submit=now,
+                deadline_s=None if deadline is None else now + deadline,
+                priority=priority)
+            sp.set_metadata(rid=req.rid)
             if self.tracer is not None:
-                self.tracer.instant("server", now, "shed-at-door",
-                                    reason=str(e), priority=priority)
-            raise
-        req = self.batcher.submit(
-            *arrays, t_submit=now,
-            deadline_s=None if deadline is None else now + deadline,
-            priority=priority)
-        if self.tracer is not None:
-            self.tracer.begin_request(
-                req.rid, now, priority=priority,
-                deadline_s=None if deadline is None else now + deadline)
-            self.tracer.request_event(req.rid, now, "submit",
-                                      n_pending=self.batcher.n_pending)
-        # Start the wall clock only once a request is actually ACCEPTED
-        # (regression, ISSUE 6): stamping before batcher.submit charged
-        # servers whose first submit was rejected (oversize, shed) for
-        # idle time they never served, skewing requests/s.
-        if self._t0 is None:
-            self._t0 = now
-        self._launch(self.batcher.pop_full())
-        if self.deadline_flush:
-            self._launch(self.batcher.tick(now, slack_s=self._flush_slack()),
-                         deadline_flushed=True)
-        return req.rid
+                self.tracer.begin_request(
+                    req.rid, now, priority=priority,
+                    deadline_s=None if deadline is None else now + deadline)
+                self.tracer.request_event(req.rid, now, "submit",
+                                          n_pending=self.batcher.n_pending)
+            # Start the wall clock only once a request is actually ACCEPTED
+            # (a regression once): stamping before batcher.submit charged
+            # servers whose first submit was rejected (oversize, shed) for
+            # idle time they never served, skewing requests/s.
+            if self._t0 is None:
+                self._t0 = now
+            self._launch(self.batcher.pop_full())
+            if self.deadline_flush:
+                self._launch(
+                    self.batcher.tick(now, slack_s=self._flush_slack()),
+                    deadline_flushed=True)
+            return req.rid
 
     def tick(self, now: Optional[float] = None) -> None:
         """Deadline pump for idle periods: launch any partial bucket whose
@@ -787,42 +790,45 @@ class Server:
         re-capture) and then rides the per-step ``generate`` launches with
         every other occupied slot.  Read its tokens incrementally with
         :meth:`stream` (which never blocks on neighbors) or all at once
-        via :meth:`result` after :meth:`flush`.
+        via :meth:`result` after :meth:`flush`.  The call is one
+        ``server.submit_decode`` profiler span.
         """
-        eng = self._require_engine()
-        prompt = jnp.asarray(prompt, jnp.int32).reshape(-1)
-        if max_new < 1:
-            raise ValueError(f"max_new must be >= 1, got {max_new}")
-        s = int(prompt.shape[0])
-        if s < 1 or s + max_new > eng.max_len:
-            raise ValueError(
-                f"prompt ({s} tokens) + max_new ({max_new}) must fit the "
-                f"engine's max_len={eng.max_len}")
-        now = self.clock()
-        if (self.admission and self.max_pending is not None
-                and len(self._eng_waiting) >= self.max_pending):
-            self.n_shed += 1
+        with span("server.submit_decode") as sp:
+            eng = self._require_engine()
+            prompt = jnp.asarray(prompt, jnp.int32).reshape(-1)
+            if max_new < 1:
+                raise ValueError(f"max_new must be >= 1, got {max_new}")
+            s = int(prompt.shape[0])
+            if s < 1 or s + max_new > eng.max_len:
+                raise ValueError(
+                    f"prompt ({s} tokens) + max_new ({max_new}) must fit the "
+                    f"engine's max_len={eng.max_len}")
+            now = self.clock()
+            if (self.admission and self.max_pending is not None
+                    and len(self._eng_waiting) >= self.max_pending):
+                self.n_shed += 1
+                if self.tracer is not None:
+                    self.tracer.instant("server", now, "shed-at-door",
+                                        reason="engine queue full",
+                                        priority=priority)
+                raise AdmissionError(
+                    f"admission control shed decode request: "
+                    f"{len(self._eng_waiting)} waiting >= "
+                    f"max_pending={self.max_pending}")
+            rid = self.batcher.mint_rid()
+            sp.set_metadata(rid=rid, prompt_len=s)
             if self.tracer is not None:
-                self.tracer.instant("server", now, "shed-at-door",
-                                    reason="engine queue full",
-                                    priority=priority)
-            raise AdmissionError(
-                f"admission control shed decode request: "
-                f"{len(self._eng_waiting)} waiting >= "
-                f"max_pending={self.max_pending}")
-        rid = self.batcher.mint_rid()
-        if self.tracer is not None:
-            self.tracer.begin_request(
-                rid, now, priority=priority, prompt_len=s, max_new=max_new,
-                deadline_s=None if deadline is None else now + deadline)
-        if self._t0 is None:
-            self._t0 = now
-        self._eng_waiting[rid] = (
-            prompt, int(max_new),
-            None if deadline is None else now + float(deadline))
-        self._eng_streams[rid] = deque()
-        self._eng_pump()
-        return rid
+                self.tracer.begin_request(
+                    rid, now, priority=priority, prompt_len=s, max_new=max_new,
+                    deadline_s=None if deadline is None else now + deadline)
+            if self._t0 is None:
+                self._t0 = now
+            self._eng_waiting[rid] = (
+                prompt, int(max_new),
+                None if deadline is None else now + float(deadline), now)
+            self._eng_streams[rid] = deque()
+            self._eng_pump()
+            return rid
 
     def stream(self, rid: int) -> Iterator[int]:
         """Per-request token iterator: yields ``rid``'s tokens as generate
@@ -866,11 +872,13 @@ class Server:
             self._estate = eng.init_state()
         admitted = 0
         while self._eng_waiting and self._estate.free_slots():
-            rid, (prompt, max_new, deadline_s) = \
+            rid, (prompt, max_new, deadline_s, accepted) = \
                 self._eng_waiting.popitem(last=False)
             slot = self._estate.free_slots()[0]
             try:
-                prefix = eng.prefill(None, prompt, rid=rid)
+                prefix = eng.prefill(
+                    None, prompt, rid=rid,
+                    wait_us=(self.clock() - accepted) * 1e6)
             except (InjectedFault, DispatchError) as e:
                 self._eng_streams.pop(rid, None)
                 self._record_shed(rid, f"engine prefill failed: {e}")
@@ -890,39 +898,42 @@ class Server:
 
     def _eng_step(self) -> bool:
         """ONE generate launch advancing every occupied slot one token;
-        finished requests free their slots and the pump refills them."""
+        finished requests free their slots and the pump refills them.  One
+        ``server.step`` profiler span."""
         eng = self.engine
         if not self._eng_active:
             return False
-        try:
-            self._estate, toks = eng.generate(None, self._estate)
-        except (InjectedFault, DispatchError) as e:
-            # the persistent decode state is poisoned mid-flight by a failed
-            # launch: shed every active rid LOUDLY and reset the state — no
-            # request is silently lost.  Any other error is a real failure
-            # (compiler, out of memory, bug) and propagates to the caller.
-            for rid, rec in list(self._eng_active.items()):
-                self._eng_streams.pop(rid, None)
-                self._record_shed(rid, f"engine generate failed: {e}")
-            self._eng_active.clear()
-            self._estate = eng.init_state()
-            self._eng_pump()
+        with span("server.step", occupied=len(self._eng_active)):
+            try:
+                self._estate, toks = eng.generate(None, self._estate)
+            except (InjectedFault, DispatchError) as e:
+                # the persistent decode state is poisoned mid-flight by a
+                # failed launch: shed every active rid LOUDLY and reset the
+                # state — no request is silently lost.  Any other error is a
+                # real failure (compiler, out of memory, bug) and propagates
+                # to the caller.
+                for rid, rec in list(self._eng_active.items()):
+                    self._eng_streams.pop(rid, None)
+                    self._record_shed(rid, f"engine generate failed: {e}")
+                self._eng_active.clear()
+                self._estate = eng.init_state()
+                self._eng_pump()
+                return True
+            finished = []
+            for rid, rec in self._eng_active.items():
+                tok = int(toks[rec["slot"]])
+                rec["tokens"].append(tok)
+                rec["remaining"] -= 1
+                self._eng_streams[rid].append(tok)
+                if rec["remaining"] <= 0:
+                    finished.append(rid)
+            for rid in finished:
+                rec = self._eng_active.pop(rid)
+                eng.release(self._estate, rec["slot"])
+                self._eng_finish(rid, rec)
+            if finished:
+                self._eng_pump()
             return True
-        finished = []
-        for rid, rec in self._eng_active.items():
-            tok = int(toks[rec["slot"]])
-            rec["tokens"].append(tok)
-            rec["remaining"] -= 1
-            self._eng_streams[rid].append(tok)
-            if rec["remaining"] <= 0:
-                finished.append(rid)
-        for rid in finished:
-            rec = self._eng_active.pop(rid)
-            eng.release(self._estate, rec["slot"])
-            self._eng_finish(rid, rec)
-        if finished:
-            self._eng_pump()
-        return True
 
     def _eng_finish(self, rid: int, rec: Dict[str, Any]) -> None:
         """Book one completed decode request (results store, SLO counters,
@@ -1034,62 +1045,70 @@ class Server:
         tr.finish_request(rid, t_end, "result")
 
     def _finalize(self, tickets: Sequence[LaunchTicket]) -> None:
-        for t in tickets:
-            per_request = t.batch.crop(t.outputs)
-            n = max(1, t.batch.n_requests)
-            # modeled start of the batch's service window on its lane
-            # (t_done_modeled already includes any queueing behind the
-            # lane's busy timeline)
-            fused_s = t.fused.total_s if t.fused is not None else 0.0
-            # clamped: an idle lane starts at t_launch exactly, and the
-            # subtraction may land an ulp before it
-            exec_start = (max(t.t_launch, t.t_done_modeled - fused_s)
-                          if t.t_done_modeled is not None else t.t_launch)
-            for req, outs in zip(t.batch.requests, per_request):
-                self._results[req.rid] = outs
-                while len(self._results) > self._results_window:
-                    old_rid, _ = self._results.popitem(last=False)
-                    self._results_evicted += 1
-                    self._evicted_upto = max(self._evicted_upto, old_rid)
-                if t.fused is not None:
-                    # each request *experiences* the whole batch's fused
-                    # latency; its amortized cost share (the throughput
-                    # view) and energy split across the live requests
-                    self._modeled_latency.append(t.fused.total_s)
-                    self._modeled_cost.append(t.fused.scaled(1.0 / n).total_s)
-                    self._modeled_energy.append(t.energy_j / n)
-                    # flame attribution: the request's end-to-end modeled
-                    # latency (submit -> t_done_modeled) split by phase —
-                    # the five deques always sum to it (see DECOMP_PHASES)
-                    freq = t.fused.freq_hz
-                    self._decomp["admission"].append(0.0)
-                    self._decomp["queueing"].append(
-                        t.t_launch - req.t_submit)
-                    self._decomp["dispatch"].append(
-                        (exec_start - t.t_launch)
-                        + (t.fused.startup + t.fused.scheduling) / freq)
-                    self._decomp["compute"].append(t.fused.compute / freq)
-                    self._decomp["transfer"].append(t.fused.transfer / freq)
-                # deadline accounting against the deterministic modeled
-                # completion time (requests without a deadline are always
-                # "in deadline" for goodput purposes)
-                violated = (req.deadline_s is not None
-                            and t.t_done_modeled is not None
-                            and t.t_done_modeled > req.deadline_s)
-                if violated:
-                    self._n_deadline_violations += 1
-                else:
-                    self._n_in_deadline += 1
-                self._n_done += 1
-                if self.tracer is not None:
-                    self._trace_completion(t, req, exec_start, violated)
-            if t.t_done is not None:
-                self._t_last = (t.t_done if self._t_last is None
-                                else max(self._t_last, t.t_done))
-            if t.t_done_modeled is not None:
-                self._t_last_modeled = (
-                    t.t_done_modeled if self._t_last_modeled is None
-                    else max(self._t_last_modeled, t.t_done_modeled))
+        """Store the results of retired ``tickets`` and book them; one
+        ``server.finalize`` profiler span when there is any."""
+        if not tickets:
+            return
+        with span("server.finalize", tickets=len(tickets),
+                  n=sum(t.batch.n_requests for t in tickets)):
+            for t in tickets:
+                per_request = t.batch.crop(t.outputs)
+                n = max(1, t.batch.n_requests)
+                # modeled start of the batch's service window on its lane
+                # (t_done_modeled already includes any queueing behind the
+                # lane's busy timeline)
+                fused_s = t.fused.total_s if t.fused is not None else 0.0
+                # clamped: an idle lane starts at t_launch exactly, and the
+                # subtraction may land an ulp before it
+                exec_start = (max(t.t_launch, t.t_done_modeled - fused_s)
+                              if t.t_done_modeled is not None else t.t_launch)
+                for req, outs in zip(t.batch.requests, per_request):
+                    self._results[req.rid] = outs
+                    while len(self._results) > self._results_window:
+                        old_rid, _ = self._results.popitem(last=False)
+                        self._results_evicted += 1
+                        self._evicted_upto = max(self._evicted_upto, old_rid)
+                    if t.fused is not None:
+                        # each request *experiences* the whole batch's fused
+                        # latency; its amortized cost share (the throughput
+                        # view) and energy split across the live requests
+                        self._modeled_latency.append(t.fused.total_s)
+                        self._modeled_cost.append(
+                            t.fused.scaled(1.0 / n).total_s)
+                        self._modeled_energy.append(t.energy_j / n)
+                        # flame attribution: the request's end-to-end modeled
+                        # latency (submit -> t_done_modeled) split by phase —
+                        # the five deques always sum to it (see DECOMP_PHASES)
+                        freq = t.fused.freq_hz
+                        self._decomp["admission"].append(0.0)
+                        self._decomp["queueing"].append(
+                            t.t_launch - req.t_submit)
+                        self._decomp["dispatch"].append(
+                            (exec_start - t.t_launch)
+                            + (t.fused.startup + t.fused.scheduling) / freq)
+                        self._decomp["compute"].append(t.fused.compute / freq)
+                        self._decomp["transfer"].append(
+                            t.fused.transfer / freq)
+                    # deadline accounting against the deterministic modeled
+                    # completion time (requests without a deadline are always
+                    # "in deadline" for goodput purposes)
+                    violated = (req.deadline_s is not None
+                                and t.t_done_modeled is not None
+                                and t.t_done_modeled > req.deadline_s)
+                    if violated:
+                        self._n_deadline_violations += 1
+                    else:
+                        self._n_in_deadline += 1
+                    self._n_done += 1
+                    if self.tracer is not None:
+                        self._trace_completion(t, req, exec_start, violated)
+                if t.t_done is not None:
+                    self._t_last = (t.t_done if self._t_last is None
+                                    else max(self._t_last, t.t_done))
+                if t.t_done_modeled is not None:
+                    self._t_last_modeled = (
+                        t.t_done_modeled if self._t_last_modeled is None
+                        else max(self._t_last_modeled, t.t_done_modeled))
 
     # -- reporting ----------------------------------------------------------
     def report(self) -> ServeReport:

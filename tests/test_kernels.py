@@ -4,6 +4,9 @@ Every Pallas kernel targets TPU (pl.pallas_call + BlockSpec) and validates
 here in interpret mode; the XLA fallbacks are swept too via impl flags.
 """
 
+import ast
+import pathlib
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -266,3 +269,42 @@ def test_mamba_chunked_equals_sequential():
     np.testing.assert_allclose(jnp.concatenate([y1, y2], 1), y_full,
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(s2, s_full, rtol=1e-4, atol=1e-4)
+
+
+# -- device names ------------------------------------------------------------
+KERNELS_DIR = (pathlib.Path(__file__).resolve().parents[1]
+               / "src" / "repro" / "kernels")
+
+
+def _pallas_calls(path):
+    """(enclosing function, its pallas_call node) of every call in ``path``."""
+    out = []
+    for fn in ast.walk(ast.parse(path.read_text())):
+        if isinstance(fn, ast.FunctionDef):
+            out += [(fn.name, c) for c in ast.walk(fn)
+                    if isinstance(c, ast.Call)
+                    and getattr(c.func, "attr",
+                                getattr(c.func, "id", None)) == "pallas_call"]
+    return out
+
+
+PALLAS_FILES = sorted(p for p in KERNELS_DIR.rglob("*.py") if _pallas_calls(p))
+
+
+def test_every_kernel_package_is_guarded():
+    assert {p.parent.name for p in PALLAS_FILES} == {
+        "decode_attention", "delineate", "fir", "flash_attention", "gemm",
+        "mamba_scan", "rwkv6_scan", "stockham_fft", "svm"}
+
+
+@pytest.mark.parametrize("path", PALLAS_FILES,
+                         ids=lambda p: p.relative_to(KERNELS_DIR).as_posix())
+def test_pallas_call_is_named_after_its_wrapper(path):
+    """The device trace names a kernel's operation after its wrapper, and
+    the benchmark's kernel patterns match that name: each ``pallas_call``
+    states it, so the name survives a wrapper that is inlined."""
+    for wrapper, call in _pallas_calls(path):
+        names = [k.value for k in call.keywords if k.arg == "name"]
+        assert names, f"{path.name}: pallas_call in {wrapper} has no name="
+        assert isinstance(names[0], ast.Constant), path.name
+        assert names[0].value == wrapper, (path.name, names[0].value)

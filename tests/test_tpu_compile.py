@@ -177,7 +177,7 @@ def test_qwen_engine_decode_step_compiles(one_chip):
     from repro.serve.engine import ENGINE_REGISTRY
     cfg, params = _qwen_params(one_chip)
     kern = Program.build(EGPU_16T, registry=ENGINE_REGISTRY).create_kernel(
-        "engine.decode_step", cfg=cfg, num_slots=4, cache_dtype="bfloat16")
+        "engine.generate", cfg=cfg, num_slots=4, cache_dtype="bfloat16")
     kern.executor._params_def = jax.tree_util.tree_structure(params)
     cache = [jax.ShapeDtypeStruct(c.shape, c.dtype, sharding=one_chip)
              for c in jax.tree_util.tree_leaves(
