@@ -10,21 +10,28 @@ import jax.numpy as jnp
 from ...core.device import EGPU_16T, EGPUConfig
 from ...core.program import kernel_family
 from ...core.runtime import Kernel
-from ..common import pad_dim, round_up
-from .fir import fir_pallas
+from ..common import cdiv, pad_dim, round_up
+from .fir import LANES, fir_pallas, halo_rows
 from .ref import FXP_SHIFT, counts as fir_counts, fir_ref
 
 
 @functools.partial(jax.jit, static_argnames=("block",))
 def fir(x: jax.Array, h: jax.Array, block: int = 512) -> jax.Array:
-    """Causal FIR filter of any length/dtype via the Pallas kernel."""
+    """Causal FIR filter of any length via the Pallas kernels.
+
+    Integer inputs take the Q15 kernel, ``block`` samples a grid step;
+    float inputs the banded Toeplitz kernel, up to ``block`` rows of 128
+    samples a grid step."""
     n = x.shape[0]
     taps = h.shape[0]
-    block = max(block, round_up(taps, 128))
-    fixed = jnp.issubdtype(x.dtype, jnp.integer)
-    xp = pad_dim(x, 0, block)
-    y = fir_pallas(xp, h, block=block,
-                   fxp_shift=FXP_SHIFT if fixed else None)
+    if jnp.issubdtype(x.dtype, jnp.integer):
+        block = max(block, round_up(taps, 128))
+        y = fir_pallas(pad_dim(x, 0, block), h, block=block,
+                       fxp_shift=FXP_SHIFT)
+        return y[:n]
+    halo = halo_rows(taps)
+    rows = min(round_up(block, halo), round_up(cdiv(n, LANES), halo))
+    y = fir_pallas(pad_dim(x, 0, LANES * rows), h, block=rows)
     return y[:n]
 
 

@@ -7,6 +7,7 @@ here in interpret mode; the XLA fallbacks are swept too via impl flags.
 import ast
 import pathlib
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -67,6 +68,39 @@ def test_fir_matches_numpy_convolve():
     x, h = rand(512), rand(17)
     ref = np.convolve(np.asarray(x), np.asarray(h))[:512]
     np.testing.assert_allclose(fir(x, h), ref, rtol=1e-4, atol=1e-4)
+
+
+def band_case(shape, taps):
+    """A signal and taps with unit L1 norm, as the served filter has
+    (|y| <= max |x|), from a generator of their own."""
+    rng = np.random.default_rng([taps, *shape])
+    h = rng.standard_normal(taps).astype(np.float32)
+    return (jnp.asarray(rng.standard_normal(shape).astype(np.float32)),
+            jnp.asarray(h / np.abs(h).sum()))
+
+
+@pytest.mark.parametrize("n,taps,block", [
+    (65_536, 128, 512),     # the served recording: one tile, K = 2
+    (4096, 31, 512),        # taps that do not divide 128
+    (4096, 200, 512),       # K = 3
+    (1000, 128, 512),       # n not a multiple of 128
+    (8192, 200, 16),        # four tiles of 16 rows: the halo carries
+])
+def test_fir_band(n, taps, block):
+    """The float path's banded Toeplitz product against the oracle."""
+    x, h = band_case((n,), taps)
+    np.testing.assert_allclose(fir(x, h, block), fir_ref(x, h),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_fir_band_vmapped_batch():
+    """A batch of 16 served recordings, lifted as the batcher lifts the
+    stage, equals the oracle row by row."""
+    x, h = band_case((16, 65_536), 128)
+    y = jax.vmap(fir, in_axes=(0, None))(x, h)
+    for row in range(16):
+        np.testing.assert_allclose(y[row], fir_ref(x[row], h),
+                                   rtol=1e-5, atol=1e-5)
 
 
 def test_fir_int_fixed_point():

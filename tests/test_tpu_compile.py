@@ -12,6 +12,7 @@ process may load the TPU library, and every test worker imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -96,6 +97,19 @@ def test_fir_compiles(one_chip):
     x = jax.ShapeDtypeStruct((65_536,), jnp.float32, sharding=one_chip)
     h = jax.ShapeDtypeStruct((128,), jnp.float32, sharding=one_chip)
     assert _mosaic_kernels(_compile(fir, x, h)) >= 1
+
+
+def test_fir_served_batch_compiles(one_chip):
+    """The float FIR as the batcher lifts it, vmapped over 16 recordings:
+    its Mosaic kernels are named ``fir_pallas`` (the benchmark finds them
+    so)."""
+    from repro.kernels.fir.ops import fir
+    x = jax.ShapeDtypeStruct((16, 65_536), jnp.float32, sharding=one_chip)
+    h = jax.ShapeDtypeStruct((128,), jnp.float32, sharding=one_chip)
+    text = _compile(jax.vmap(fir, in_axes=(0, None)), x, h).as_text()
+    kernels = re.findall(r"%(\S+) = .*custom_call_target=\"tpu_custom_call\"",
+                         text)
+    assert kernels and all(k.startswith("fir_pallas") for k in kernels)
 
 
 def test_svm_compiles(one_chip):
