@@ -14,6 +14,7 @@ tests (small widths/depths/experts, same block structure).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Tuple
 
 
@@ -54,7 +55,24 @@ class ModelConfig:
     top_k: int = 0
     d_ff_expert: int = 0
     d_ff_dense: int = 0                    # dense-MLP width when mixed w/ MoE
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25          # training dispatch only
+    # routing (deepseek-v2: group_limited_greedy, 8 groups, top 3 of them)
+    n_group: int = 1                       # expert groups, scored by best
+    topk_group: int = 1                    # groups a token may route into
+    norm_topk_prob: bool = True            # renormalise the top-k weights
+    routed_scaling_factor: float = 1.0     # routed output multiplier
+    # held share: this chip computes experts [first, first + count) of the
+    # router's n_experts (expert parallelism); 0 -> all of them
+    expert_first: int = 0
+    experts_held: int = 0
+
+    # YaRN rope scaling (deepseek-v2: factor 40 over 4096 positions); 0 off
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # Mamba (jamba)
     mamba_d_state: int = 16
@@ -98,12 +116,21 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.n_experts and self.experts_held == 0:
+            object.__setattr__(self, "experts_held", self.n_experts)
         if self.mamba_dt_rank == 0:
             object.__setattr__(self, "mamba_dt_rank",
                                max(1, self.d_model // 16))
         period = len(self.block_pattern)
         if len(self.mlp_pattern) != period:
             raise ValueError("block_pattern and mlp_pattern lengths differ")
+        if self.n_experts and (
+                self.n_experts % self.n_group
+                or self.expert_first + self.experts_held > self.n_experts):
+            raise ValueError(
+                f"{self.name}: {self.n_experts} experts in {self.n_group} "
+                f"groups, held [{self.expert_first}, "
+                f"{self.expert_first + self.experts_held})")
         scanned = self.n_layers - (1 if self.first_layer_dense else 0)
         if scanned % period:
             raise ValueError(
@@ -169,7 +196,7 @@ class ModelConfig:
                "none": 0}
         if self.n_experts:
             ff = self.d_ff_expert or self.d_ff
-            mlp["moe"] = (self.n_experts * 3 * d * ff + d * self.n_experts
+            mlp["moe"] = (self.experts_held * 3 * d * ff + d * self.n_experts
                           + self.n_shared_experts * 3 * d * ff)
         layers = 0
         for b, m in zip(self.block_pattern, self.mlp_pattern):
@@ -186,13 +213,15 @@ class ModelConfig:
             return self.param_count()
         d = self.d_model
         ff = self.d_ff_expert or self.d_ff
-        inactive = (self.n_experts - self.top_k) * 3 * d * ff
+        inactive = max(0, self.experts_held - self.top_k) * 3 * d * ff
         n_moe = sum(1 for m in self.mlp_pattern if m == "moe") * self.n_groups
         return int(self.param_count() - n_moe * inactive)
 
     # ------------------------------------------------------------------
     def reduced(self) -> "ModelConfig":
         """Family-preserving smoke-test config (runs a step on 1 CPU)."""
+        n_experts = min(self.n_experts, 8)
+        n_group = math.gcd(self.n_group, n_experts or 1)
         changes = dict(
             name=self.name + "-smoke",
             n_layers=(1 if self.first_layer_dense else 0) + self.period,
@@ -207,7 +236,11 @@ class ModelConfig:
             qk_nope_head_dim=32 if self.qk_nope_head_dim else 0,
             qk_rope_head_dim=16 if self.qk_rope_head_dim else 0,
             v_head_dim=32 if self.v_head_dim else 0,
-            n_experts=min(self.n_experts, 8),
+            n_experts=n_experts,
+            experts_held=0,
+            expert_first=0,
+            n_group=n_group,
+            topk_group=min(self.topk_group, n_group),
             top_k=min(self.top_k, 2),
             d_ff_expert=128 if self.d_ff_expert else 0,
             d_ff_dense=256 if self.d_ff_dense else 0,

@@ -95,19 +95,54 @@ def rope_frequencies(dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(dim: int, cfg: ModelConfig) -> jax.Array:
+    """DeepSeek-V2's YaRN inverse frequencies (``DeepseekV2YarnRotary
+    Embedding``): ``base^(-2i/d)`` kept for the fast-rotating channels,
+    divided by ``factor`` for the slow ones, blended by a linear ramp over
+    the correction range that ``beta_fast`` and ``beta_slow`` set."""
+    def corr_dim(rotations):
+        return (dim * math.log(cfg.yarn_original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(cfg.rope_theta)))
+
+    low = max(math.floor(corr_dim(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(corr_dim(cfg.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = rope_frequencies(dim, cfg.rope_theta)
+    inter = 1.0 / (cfg.yarn_factor * cfg.rope_theta ** (
+        jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return inter * (1.0 - keep) + extra * keep
+
+
 def apply_rotary(x: jax.Array, positions: jax.Array, theta: float,
-                 rotary_pct: float = 1.0) -> jax.Array:
+                 rotary_pct: float = 1.0, *,
+                 inv_freq: jax.Array | None = None,
+                 mscale: float = 1.0) -> jax.Array:
     """x (..., S, D); positions (S,) or (B, S).  Rotates the first
-    ``rotary_pct * D`` channels (pairwise halves convention)."""
+    ``rotary_pct * D`` channels (pairwise halves convention).
+    ``inv_freq`` replaces the plain ``theta`` frequencies (YaRN) and
+    ``mscale`` scales cos and sin."""
     d = x.shape[-1]
     rd = int(d * rotary_pct)
     rd -= rd % 2
     if rd == 0:
         return x
     xr, xp = x[..., :rd], x[..., rd:]
-    freqs = rope_frequencies(rd, theta)                       # (rd/2,)
+    freqs = (rope_frequencies(rd, theta) if inv_freq is None
+             else inv_freq)                                   # (rd/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs    # (..., S, rd/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     while cos.ndim < xr.ndim:                                 # add head axis
         cos, sin = cos[..., None, :, :], sin[..., None, :, :]
     x1, x2 = xr[..., : rd // 2], xr[..., rd // 2:]

@@ -24,7 +24,8 @@ import jax.numpy as jnp
 from ..distributed.sharding import constrain
 from ..kernels.flash_attention.ops import flash_attention
 from .config import ModelConfig
-from .layers import apply_rotary, cdtype, rms_norm_1d
+from .layers import (apply_rotary, cdtype, rms_norm_1d, yarn_frequencies,
+                     yarn_mscale)
 from .params import ParamSpec, dense_spec
 
 NEG_INF = -1e30
@@ -56,6 +57,26 @@ def mla_spec(cfg: ModelConfig, stacked: int = 0) -> Dict[str, ParamSpec]:
     return out
 
 
+def _rope(x: jax.Array, positions: jax.Array, cfg: ModelConfig):
+    """Rotary embedding of the rope channels; YaRN where configured."""
+    if not cfg.yarn_factor:
+        return apply_rotary(x, positions, cfg.rope_theta)
+    return apply_rotary(
+        x, positions, cfg.rope_theta,
+        inv_freq=yarn_frequencies(x.shape[-1], cfg),
+        mscale=(yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+                / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)))
+
+
+def softmax_scale(cfg: ModelConfig) -> float:
+    """``(nope + rope)^-0.5``, times ``mscale(factor, mscale_all_dim)^2``
+    under YaRN."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
 def _latents(p, x: jax.Array, cfg: ModelConfig, positions: jax.Array):
     """x (B,S,D) -> (c_kv (B,S,kvl) normed, k_rope (B,1,S,rope) rotated)."""
     b, s, _ = x.shape
@@ -64,7 +85,7 @@ def _latents(p, x: jax.Array, cfg: ModelConfig, positions: jax.Array):
     kv_a = jnp.dot(x.astype(dt), p["wkv_a"].astype(dt))
     c_kv = rms_norm_1d(kv_a[..., :kvl], p["kv_norm"], cfg.norm_eps)
     k_rope = kv_a[..., kvl:].reshape(b, s, 1, rope).transpose(0, 2, 1, 3)
-    k_rope = apply_rotary(k_rope, positions, cfg.rope_theta)
+    k_rope = _rope(k_rope, positions, cfg)
     return c_kv, k_rope
 
 
@@ -78,7 +99,7 @@ def _queries(p, x: jax.Array, cfg: ModelConfig, positions: jax.Array):
     qb = jnp.dot(qa.astype(dt), p["wq_b"].astype(dt))
     qb = qb.reshape(b, s, h, nope + rope).transpose(0, 2, 1, 3)
     q_nope, q_rope = qb[..., :nope], qb[..., nope:]
-    q_rope = apply_rotary(q_rope, positions, cfg.rope_theta)
+    q_rope = _rope(q_rope, positions, cfg)
     return q_nope, q_rope
 
 
@@ -108,8 +129,7 @@ def mla_full(p, x: jax.Array, cfg: ModelConfig, *,
     k = jnp.concatenate([k_nope,
                          jnp.broadcast_to(k_rope, (b, h, s, rope))], axis=-1)
     q = constrain(q, "batch", "heads", "seq", None)
-    out = flash_attention(q, k, v, causal=True,
-                          scale=(nope + rope) ** -0.5)
+    out = flash_attention(q, k, v, causal=True, scale=softmax_scale(cfg))
     out = out.transpose(0, 2, 1, 3).reshape(b, s, h * vd)
     y = jnp.dot(out.astype(dt), p["wo"].astype(dt))
     if return_cache:
@@ -183,7 +203,7 @@ def mla_decode(p, x: jax.Array, cache: Dict[str, jax.Array], pos,
     # scores over the latent cache + shared rope key — bf16 cache reads
     # with f32 accumulation (no f32 cache copy; see attention.py note)
     t = c_kv.shape[1]
-    scale = (nope + rope) ** -0.5
+    scale = softmax_scale(cfg)
     s_lat = jnp.einsum("bhk,btk->bht", q_lat.astype(dtype), c_kv,
                        preferred_element_type=jnp.float32)
     s_rope = jnp.einsum("bhr,btr->bht", q_rope[:, :, 0].astype(dtype),

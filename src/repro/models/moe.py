@@ -21,16 +21,32 @@ Aux losses: switch-style load-balance loss + router z-loss, returned to the
 trainer (summed over scan groups).
 
 Shared experts (deepseek-v2: 2) run densely on every token and add in.
+
+Serving (prefill and decode) takes the dropless path instead,
+:func:`apply_moe_serve`: the router is computed in float32 over all
+``n_experts``, the top-k rule of :func:`route` picks each token's experts
+(deepseek-v2: group-limited greedy, unnormalised, scaled by 16), and the
+assignments to the experts this chip holds (``expert_first`` ..
+``+ experts_held``) are sorted by expert into tile-aligned runs that the
+grouped-matmul kernel (``repro.kernels.moe_gmm``) multiplies, so no token
+is dropped and no capacity exists.  The result is this chip's part of the
+layer; what experts held elsewhere would add is not computed.  Training
+keeps the capacity path: its EP sharding, aux losses and the capacity
+drop test use it.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from jax.custom_batching import custom_vmap
+
 from ..distributed.sharding import constrain
+from ..kernels.common import round_up
+from ..kernels.moe_gmm.ops import moe_gmm, row_tile
 from .config import ModelConfig
 from .layers import act_fn, cdtype
 from .params import ParamSpec, dense_spec
@@ -41,11 +57,11 @@ from .params import ParamSpec, dense_spec
 # ---------------------------------------------------------------------------
 def moe_spec(cfg: ModelConfig, stacked: int = 0) -> Dict[str, ParamSpec]:
     d = cfg.d_model
-    e = cfg.n_experts
+    e = cfg.n_experts                     # the router keeps every output
     ff = cfg.d_ff_expert or cfg.d_ff
 
     def expert_w(din, dout, axes):
-        shape = (e, din, dout)
+        shape = (cfg.experts_held, din, dout)
         ax: Tuple = ("expert",) + axes
         if stacked:
             shape = (stacked,) + shape
@@ -75,6 +91,37 @@ def capacity(cfg: ModelConfig, group_tokens: int) -> int:
 
 
 # ---------------------------------------------------------------------------
+# Routing
+# ---------------------------------------------------------------------------
+def route(logits: jax.Array, cfg: ModelConfig):
+    """Router logits (T, E) -> (weights (T, k) f32, experts (T, k) int32).
+
+    Softmax over all experts; with ``n_group > 1`` each group is scored by
+    its best expert and only the ``topk_group`` best groups stay eligible
+    (DeepSeek-V2's ``group_limited_greedy``).  With ``top_k > 1`` and
+    ``norm_topk_prob`` the weights are renormalised to sum to 1, otherwise
+    scaled by ``routed_scaling_factor`` (the published gate's either/or).
+    """
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    k = cfg.top_k
+    if cfg.n_group > 1:
+        t, e = probs.shape
+        group_best = probs.reshape(t, cfg.n_group, -1).max(axis=-1)
+        _, top_groups = jax.lax.top_k(group_best, cfg.topk_group)
+        keep = jax.nn.one_hot(top_groups, cfg.n_group,
+                              dtype=jnp.int32).sum(axis=1) > 0
+        keep = jnp.repeat(keep, e // cfg.n_group, axis=1)
+        top_w, top_e = jax.lax.top_k(jnp.where(keep, probs, 0.0), k)
+    else:
+        top_w, top_e = jax.lax.top_k(probs, k)
+    if k > 1 and cfg.norm_topk_prob:
+        top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    else:
+        top_w = top_w * cfg.routed_scaling_factor
+    return top_w, top_e
+
+
+# ---------------------------------------------------------------------------
 # Routing + dispatch (per group, vmapped)
 # ---------------------------------------------------------------------------
 def _route_group(x: jax.Array, logits: jax.Array, cfg: ModelConfig, c: int):
@@ -87,8 +134,7 @@ def _route_group(x: jax.Array, logits: jax.Array, cfg: ModelConfig, c: int):
     e, k = cfg.n_experts, cfg.top_k
 
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    top_w, top_e = jax.lax.top_k(probs, k)                       # (g, k)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    top_w, top_e = route(logits, cfg)                            # (g, k)
 
     flat_e = top_e.reshape(-1)                                   # (g*k,)
     flat_w = top_w.reshape(-1)
@@ -129,26 +175,27 @@ def _combine_group(y: jax.Array, slot, weight, flat_tok, g: int):
 # ---------------------------------------------------------------------------
 # The layer
 # ---------------------------------------------------------------------------
-def apply_moe(p, x: jax.Array, cfg: ModelConfig, *,
-              group_size: Optional[int] = None):
+def apply_moe(p, x: jax.Array, cfg: ModelConfig):
     """x (B, S, D) -> (y (B, S, D), aux_losses (2,) [load_balance, z]).
 
-    ``group_size`` defaults to S (one routing group per sequence), keeping
-    groups aligned with the batch sharding so dispatch scatters stay local.
+    One routing group per sequence, keeping groups aligned with the batch
+    sharding so dispatch scatters stay local.
     """
     b, s, d = x.shape
     e = cfg.n_experts
+    if cfg.experts_held != e:
+        raise ValueError(f"{cfg.name}: training dispatch computes every "
+                         f"expert; {cfg.experts_held} of {e} are held")
     dt = cdtype(cfg)
-    g = group_size or s
-    n_groups = (b * s) // g
+    n_groups, g = b, s
     c = capacity(cfg, g)
 
     xg = x.reshape(n_groups, g, d)
     xg = constrain(xg, "batch", None, None)
     logits = jnp.einsum("ngd,de->nge", xg.astype(dt), p["router"].astype(dt))
 
-    route = jax.vmap(lambda xx, ll: _route_group(xx, ll, cfg, c))
-    buf, slot, weight, flat_tok, aux = route(xg, logits)
+    dispatch = jax.vmap(lambda xx, ll: _route_group(xx, ll, cfg, c))
+    buf, slot, weight, flat_tok, aux = dispatch(xg, logits)
     # buf: (n_groups, E*c, D) -> expert-major for EP
     he = buf.reshape(n_groups, e, c, d)
     he = constrain(he, "batch", "expert", None, None)   # all-to-all boundary
@@ -170,3 +217,88 @@ def apply_moe(p, x: jax.Array, cfg: ModelConfig, *,
         y = y + jnp.dot(h, sp["wo"].astype(dt))
 
     return y, aux.mean(axis=0)
+
+
+# ---------------------------------------------------------------------------
+# Serving: dropless, over the held experts, through the grouped matmul
+# ---------------------------------------------------------------------------
+def serve_row_tile(cfg: ModelConfig, tokens: int) -> int:
+    """The grouped matmul's row tile for a layer call over ``tokens``
+    tokens (each held expert expects ``tokens * top_k / n_experts`` rows)."""
+    return row_tile(tokens * cfg.top_k / cfg.n_experts)
+
+
+def _moe_tokens(p, x: jax.Array, cfg: ModelConfig, layer):
+    """x (N, D) -> (routed + shared output (N, D), each token's routed
+    experts (N, top_k) int32, global ids).  ``p``'s expert weights may be
+    stacked over layers (L, E_held, ...), ``layer`` picking this one."""
+    n, d = x.shape
+    dt = cdtype(cfg)
+    eh, k = cfg.experts_held, cfg.top_k
+    logits = jnp.dot(x.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    weight, expert = route(logits, cfg)                          # (N, k)
+    local = expert - cfg.expert_first
+    held = (local >= 0) & (local < eh)
+    onehot = jax.nn.one_hot(jnp.where(held, local, eh).reshape(-1), eh,
+                            dtype=jnp.int32)                     # (N*k, eh)
+    counts = onehot.sum(axis=0)                                  # (eh,)
+    # expert e's rows start at a multiple of the tile and fill whole tiles
+    tm = serve_row_tile(cfg, n)
+    padded = -(-counts // tm) * tm
+    start = jnp.cumsum(padded) - padded
+    rank = (jnp.cumsum(onehot, axis=0) * onehot).sum(axis=1) - 1
+    rows = round_up(n * min(k, eh) + min(eh, n * k) * (tm - 1), tm)
+    flat_held = held.reshape(-1)
+    dest = jnp.where(flat_held, start[jnp.where(flat_held, local.reshape(-1),
+                                                0)] + rank, rows)
+    token = jnp.zeros((rows,), jnp.int32).at[dest].set(
+        jnp.arange(n * k, dtype=jnp.int32) // k, mode="drop")
+    xs = x.astype(dt)[token]                                     # (rows, D)
+
+    wi, wg, wo = (p["wi"].astype(dt), p["wg"].astype(dt), p["wo"].astype(dt))
+    h = act_fn(cfg)(moe_gmm(xs, wg, padded, layer, tm=tm))
+    h = h * moe_gmm(xs, wi, padded, layer, tm=tm)
+    y_rows = moe_gmm(h, wo, padded, layer, tm=tm)                # (rows, D)
+
+    dest = jnp.minimum(dest.reshape(n, k), rows - 1)
+    y = jnp.zeros((n, d), jnp.float32)
+    for j in range(k):
+        y = y + jnp.where(held[:, j, None],
+                          y_rows[dest[:, j]].astype(jnp.float32)
+                          * weight[:, j, None], 0.0)
+    y = y.astype(dt)
+    if cfg.n_shared_experts:
+        sp = p["shared"]
+        hs = act_fn(cfg)(jnp.dot(x.astype(dt), sp["wg"].astype(dt)))
+        hs = hs * jnp.dot(x.astype(dt), sp["wi"].astype(dt))
+        y = y + jnp.dot(hs, sp["wo"].astype(dt))
+    return y, expert
+
+
+def apply_moe_serve(p, x: jax.Array, cfg: ModelConfig, layer=0):
+    """x (B, S, D) -> (y (B, S, D), each token's routed experts (B, S,
+    top_k) int32, global ids).  The expert weights ``wi`` / ``wg`` / ``wo``
+    may be the whole stack over layers, ``layer`` picking this one (the
+    kernel then reads that layer's touched experts in place).  Under
+    ``jax.vmap`` (the decode engine's per-slot lanes) the tokens of every
+    lane go through ONE grouped matmul, so an expert that several slots
+    route to has its weights read once; each lane still gets its own
+    routing."""
+    def plain(p, x, layer):
+        y, expert = _moe_tokens(p, x.reshape(-1, x.shape[-1]), cfg, layer)
+        return y.reshape(x.shape), expert.reshape(x.shape[:-1] + (-1,))
+
+    fn = custom_vmap(plain)
+
+    @fn.def_vmap
+    def _lanes(axis_size, in_batched, p, x, layer):
+        p_batched, _, layer_batched = in_batched
+        if any(jax.tree_util.tree_leaves(p_batched)) or layer_batched:
+            raise NotImplementedError(
+                "the expert layer batches tokens, not weights or layers")
+        y, expert = _moe_tokens(p, x.reshape(-1, x.shape[-1]), cfg, layer)
+        return ((y.reshape(x.shape), expert.reshape(x.shape[:-1] + (-1,))),
+                (True, True))
+
+    return fn(p, x, jnp.asarray(layer, jnp.int32))

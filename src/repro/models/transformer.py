@@ -34,7 +34,7 @@ from .mamba import (init_mamba_state, mamba_decode, mamba_full, mamba_spec,
                     mamba_state_struct)
 from .mla import (init_mla_cache, mla_cache_from_prefill, mla_cache_struct,
                   mla_decode, mla_full, mla_spec)
-from .moe import apply_moe, moe_spec
+from .moe import apply_moe, apply_moe_serve, moe_spec
 from .rwkv import (init_rwkv_state, rwkv_channel_mix, rwkv_spec,
                    rwkv_state_struct, rwkv_time_mix)
 
@@ -106,8 +106,10 @@ def embed_inputs(params, inputs: Dict[str, jax.Array], cfg: ModelConfig
 # One block position (shared by train / prefill / decode bodies)
 # ---------------------------------------------------------------------------
 def _apply_position(p, x, cfg: ModelConfig, kind: str, mlp_kind: str, *,
-                    mode: str = "train", cache=None, pos=None):
-    """Returns (x, aux (2,), new_cache_or_None)."""
+                    mode: str = "train", cache=None, pos=None, layer=0):
+    """Returns (x, aux, new_cache_or_None).  ``aux`` is the (2,) MoE
+    auxiliary losses in training; in serving (prefill / decode) a MoE
+    position's routed experts per token (B, S, top_k) int32."""
     rs = residual_scale(cfg)
     aux = jnp.zeros((2,), jnp.float32)
     new_cache = None
@@ -168,10 +170,10 @@ def _apply_position(p, x, cfg: ModelConfig, kind: str, mlp_kind: str, *,
     x = x + out * rs
     if mlp_kind != "none":
         h2 = apply_norm(p["norm2"], x, cfg)
-        if mlp_kind == "moe":
-            b, s, _ = h2.shape
-            gs = s if mode != "decode" else max(1, (b * s) // 16)
-            m_out, aux = apply_moe(p["mlp"], h2, cfg, group_size=gs)
+        if mlp_kind == "moe" and mode == "train":
+            m_out, aux = apply_moe(p["mlp"], h2, cfg)
+        elif mlp_kind == "moe":
+            m_out, aux = apply_moe_serve(p["mlp"], h2, cfg, layer)
         else:
             m_out = apply_mlp(p["mlp"], h2, cfg)
         x = x + m_out * rs
@@ -392,9 +394,47 @@ def cache_axes(cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 # Prefill
 # ---------------------------------------------------------------------------
+def moe_layers(cfg: ModelConfig) -> int:
+    """How many layers of the stack carry a MoE feed-forward."""
+    return cfg.mlp_pattern.count("moe") * cfg.n_groups
+
+
+def _serve_scan_xs(cfg: ModelConfig, blocks):
+    """(scan xs, expert weights) for a serving scan over the layer groups:
+    the MoE positions' routed-expert weights stay whole-stack, outside the
+    scan, so that each group's grouped matmul reads its layer in place
+    (``_serve_params``) instead of a per-group copy of all its experts."""
+    xs, experts = {}, {}
+    for i, mlp_kind in enumerate(cfg.mlp_pattern):
+        pos = blocks[f"pos{i}"]
+        if mlp_kind == "moe":
+            mlp = dict(pos["mlp"])
+            experts[f"pos{i}"] = {k: mlp.pop(k) for k in ("wi", "wg", "wo")}
+            pos = dict(pos, mlp=mlp)
+        xs[f"pos{i}"] = pos
+    return (xs, jnp.arange(cfg.n_groups, dtype=jnp.int32)), experts
+
+
+def _serve_params(group_params, experts, i: int):
+    """Position ``i``'s params of one group, its experts whole-stack."""
+    p = group_params[f"pos{i}"]
+    if f"pos{i}" in experts:
+        p = dict(p, mlp=dict(p["mlp"], **experts[f"pos{i}"]))
+    return p
+
+
+def _stack_experts(experts):
+    """Per-position (n_groups, B, S, k) routed experts -> (MoE layers, B,
+    S, k), in depth order."""
+    return jnp.stack(experts, axis=1).reshape((-1,) + experts[0].shape[1:])
+
+
 def prefill(params, inputs: Dict[str, jax.Array], cfg: ModelConfig,
-            max_len: int, cache_dtype=jnp.bfloat16):
-    """Process the prompt; -> (last-token logits (B, Vp), cache at S)."""
+            max_len: int, cache_dtype=jnp.bfloat16,
+            return_experts: bool = False):
+    """Process the prompt; -> (last-token logits (B, Vp), cache at S)
+    [, each MoE layer's routed experts per token (L_moe, B, S, top_k)
+    int32, global ids]."""
     if cfg.is_encoder:
         raise ValueError(f"{cfg.name} is encoder-only: no prefill/decode")
     x = embed_inputs(params, inputs, cfg)
@@ -405,20 +445,28 @@ def prefill(params, inputs: Dict[str, jax.Array], cfg: ModelConfig,
         cache["layer0"] = _pad_prefill(cfg, cfg.block_pattern[0], c0,
                                        max_len, cache_dtype)
 
-    def body(x, group_params):
-        caches = []
+    xs, experts = _serve_scan_xs(cfg, params["blocks"])
+
+    def body(x, xs):
+        group_params, layer = xs
+        caches, routed = [], []
         for i, (kind, mlp_kind) in enumerate(
                 zip(cfg.block_pattern, cfg.mlp_pattern)):
-            x, _, c = _apply_position(group_params[f"pos{i}"], x, cfg,
-                                      kind, mlp_kind, mode="prefill")
+            x, r, c = _apply_position(
+                _serve_params(group_params, experts, i), x, cfg, kind,
+                mlp_kind, mode="prefill", layer=layer)
             caches.append(_pad_prefill(cfg, kind, c, max_len, cache_dtype))
-        return x, tuple(caches)
+            if mlp_kind == "moe":
+                routed.append(r)
+        return x, (tuple(caches), tuple(routed))
 
-    x, stacked = jax.lax.scan(body, x, params["blocks"])
+    x, (stacked, routed) = jax.lax.scan(body, x, xs)
     for i in range(cfg.period):
         cache[f"pos{i}"] = stacked[i]
     x = apply_norm(params["final_norm"], x, cfg)
     logits = logits_from_hidden(params["embed"], x[:, -1:], cfg)[:, 0]
+    if return_experts:
+        return logits, cache, _stack_experts(routed)
     return logits, cache
 
 
@@ -433,10 +481,12 @@ def _pad_prefill(cfg, kind, c, max_len, dtype):
 # ---------------------------------------------------------------------------
 # Decode
 # ---------------------------------------------------------------------------
-def decode_step(params, cache, tokens: jax.Array, pos, cfg: ModelConfig):
+def decode_step(params, cache, tokens: jax.Array, pos, cfg: ModelConfig,
+                return_experts: bool = False):
     """One token for every sequence.  tokens (B,) int32, pos scalar int32.
 
-    Returns (logits (B, Vp) fp32, updated cache).
+    Returns (logits (B, Vp) fp32, updated cache) [, each MoE layer's
+    routed experts (L_moe, B, 1, top_k) int32, global ids].
     """
     if cfg.is_encoder:
         raise ValueError(f"{cfg.name} is encoder-only: no decode step")
@@ -447,22 +497,29 @@ def decode_step(params, cache, tokens: jax.Array, pos, cfg: ModelConfig):
                               cache=cache["layer0"], pos=pos)
         new_layer0 = c0
 
+    xs, experts = _serve_scan_xs(cfg, params["blocks"])
+
     def body(x, xs):
-        group_params, group_cache = xs
-        new_caches = []
+        (group_params, layer), group_cache = xs
+        new_caches, routed = [], []
         for i, (kind, mlp_kind) in enumerate(
                 zip(cfg.block_pattern, cfg.mlp_pattern)):
-            x, _, c = _apply_position(
-                group_params[f"pos{i}"], x, cfg, kind, mlp_kind,
-                mode="decode", cache=group_cache[f"pos{i}"], pos=pos)
+            x, r, c = _apply_position(
+                _serve_params(group_params, experts, i), x, cfg, kind,
+                mlp_kind, mode="decode", cache=group_cache[f"pos{i}"],
+                pos=pos, layer=layer)
             new_caches.append(c)
-        return x, tuple(new_caches)
+            if mlp_kind == "moe":
+                routed.append(r)
+        return x, (tuple(new_caches), tuple(routed))
 
     scan_cache = {k: v for k, v in cache.items() if k != "layer0"}
-    x, stacked = jax.lax.scan(body, x, (params["blocks"], scan_cache))
+    x, (stacked, routed) = jax.lax.scan(body, x, (xs, scan_cache))
     new_cache = {f"pos{i}": stacked[i] for i in range(cfg.period)}
     if cfg.first_layer_dense:
         new_cache["layer0"] = new_layer0
     x = apply_norm(params["final_norm"], x, cfg)
     logits = logits_from_hidden(params["embed"], x, cfg)[:, 0]
+    if return_experts:
+        return logits, new_cache, _stack_experts(routed)
     return logits, new_cache

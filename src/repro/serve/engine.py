@@ -31,9 +31,9 @@ Engine invariants (pinned by ``tests/test_decode_serve.py``):
   :class:`~repro.core.machine.WorkCounts` each node was actually priced
   with — never re-derived on the side.
 - **No full-vocabulary output rides the step graph.**  The decode kernel
-  uses :func:`~repro.train.serve.make_decode_step` with
-  ``return_logits=False``; :meth:`DecodeEngine.decode_graph`'s out avals
-  carry tokens + cache only (aval-checked at capture).
+  takes the greedy argmax inside the step;
+  :meth:`DecodeEngine.decode_graph`'s out avals carry tokens + cache (and
+  a MoE model's routed experts) only (aval-checked at capture).
 """
 
 from __future__ import annotations
@@ -54,10 +54,11 @@ from ..core.program import KernelRegistry, Program, kernel_family
 from ..core.runtime import CommandGraph, Kernel
 from ..core.scheduler import optimal_ndrange
 from ..models.config import ModelConfig
-from ..models.transformer import cache_axes, cache_struct
+from ..models.moe import serve_row_tile
+from ..models.transformer import (cache_axes, cache_struct, decode_step,
+                                  moe_layers, prefill)
 from ..obs import Tracer
 from ..obs.profiler import span
-from ..train.serve import make_decode_step, make_prefill_step
 from .batching import MicroBatch
 from .cache import GraphCache
 from .dispatch import QueueWorker
@@ -112,14 +113,17 @@ ENGINE_REGISTRY = KernelRegistry()
 def build_prefill_kernel(config: EGPUConfig = EGPU_16T, *,
                          cfg: ModelConfig, max_len: int,
                          cache_dtype: str = "bfloat16") -> Kernel:
-    """Batch-1 prompt pass -> (first greedy token (1,), *cache leaves).
+    """Batch-1 prompt pass -> (first greedy token (1,), *cache leaves[,
+    routed experts (S, L_moe, top_k)]).
 
     One kernel serves every prompt length — the per-length specialization
     lives in the :class:`~repro.serve.cache.GraphCache` key (input avals),
     so distinct lengths get distinct captured graphs of the same kernel.
+    A model with MoE layers adds one output: each prompt token's routed
+    experts in every MoE layer.
     """
     dtype = jnp.dtype(cache_dtype)
-    step = make_prefill_step(cfg, max_len, dtype)
+    moe = moe_layers(cfg) > 0
 
     # ``_params_def`` (the params treedef) is stamped on the executor by the
     # engine before first use — builders only see hashable variant keys, and
@@ -127,9 +131,12 @@ def build_prefill_kernel(config: EGPUConfig = EGPU_16T, *,
     def engine_prefill(prompt, *param_leaves):
         params = jax.tree_util.tree_unflatten(
             engine_prefill._params_def, param_leaves)
-        logits, cache = step(params, {"tokens": prompt})
+        logits, cache, *experts = prefill(params, {"tokens": prompt}, cfg,
+                                          max_len, dtype,
+                                          return_experts=moe)
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (tok, *jax.tree_util.tree_leaves(cache))
+        return (tok, *jax.tree_util.tree_leaves(cache),
+                *(jnp.swapaxes(e[:, 0], 0, 1) for e in experts))
 
     return Kernel(name="engine.prefill", executor=engine_prefill,
                   counts=_engine_counts)
@@ -140,36 +147,41 @@ def build_decode_kernel(config: EGPUConfig = EGPU_16T, *,
                         cfg: ModelConfig, num_slots: int,
                         cache_dtype: str = "bfloat16") -> Kernel:
     """One token for every slot: (tokens (B,), positions (B,), *cache,
-    *params) -> (next tokens (B,), *new cache leaves).
+    *params) -> (next tokens (B,), *new cache leaves[, routed experts (B,
+    L_moe, top_k)]).
 
     Each slot is an independent ``jax.vmap`` lane over (cache slot, token,
     position) — per-slot positions are what make staggered insertion
     bit-identical to each request's own whole-batch trajectory.  The step
-    body is the ``return_logits=False`` fast path, so no ``(B, vocab)``
-    buffer rides the captured graph's outputs.
+    takes its greedy argmax inside, so no ``(B, vocab)`` buffer rides the
+    captured graph's outputs.  A model with MoE layers adds one output:
+    each slot's routed experts in every MoE layer.
     """
     del num_slots                        # identity only: one graph per width
     bidx = batch_axes(cfg)
     cache_def = jax.tree_util.tree_structure(bidx)
     n_cache = cache_def.num_leaves
-    step = make_decode_step(cfg, return_logits=False)
+    moe = moe_layers(cfg) > 0
 
     def one(params, cache_slot, tok, pos):
         cache_b = jax.tree_util.tree_map(
             lambda c, i: jnp.expand_dims(c, i), cache_slot, bidx)
-        nxt, new_cache = step(params, cache_b, tok[None], pos)
+        logits, new_cache, *experts = decode_step(
+            params, cache_b, tok[None], pos, cfg, return_experts=moe)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         new_slot = jax.tree_util.tree_map(
             lambda c, i: jnp.squeeze(c, axis=i), new_cache, bidx)
-        return nxt[0], new_slot
+        return nxt[0], new_slot, [e[:, 0, 0] for e in experts]
 
-    vstep = jax.vmap(one, in_axes=(None, bidx, 0, 0), out_axes=(0, bidx))
+    vstep = jax.vmap(one, in_axes=(None, bidx, 0, 0),
+                     out_axes=(0, bidx, 0))
 
     def engine_decode(tokens, positions, *state):
         cache = jax.tree_util.tree_unflatten(cache_def, state[:n_cache])
         params = jax.tree_util.tree_unflatten(
             engine_decode._params_def, state[n_cache:])
-        toks, new_cache = vstep(params, cache, tokens, positions)
-        return (toks, *jax.tree_util.tree_leaves(new_cache))
+        toks, new_cache, experts = vstep(params, cache, tokens, positions)
+        return (toks, *jax.tree_util.tree_leaves(new_cache), *experts)
 
     return Kernel(name="engine.generate", executor=engine_decode,
                   counts=_engine_counts)
@@ -358,6 +370,17 @@ class DecodeEngine:
         self.decode_modeled_s = 0.0
         self.energy_j = 0.0
         self._occupancy_sum = 0.0
+        # MoE: each launch's routed experts, counted on the host after the
+        # readback the launch already makes
+        self._moe = moe_layers(cfg) > 0
+        self.moe_rows_routed = 0         # rows routed to held experts
+        self.moe_rows_computed = 0       # rows the grouped matmuls computed
+        self.moe_experts_touched = 0     # (layer, held expert) pairs with rows
+        #: the last launch's routed experts (tokens, L_moe, top_k), global
+        #: ids: a prefill's prompt positions, a step's slots
+        self.moe_last_experts: Optional[np.ndarray] = None
+        #: and its rows per MoE layer and held expert (L_moe, E_held)
+        self.moe_last_rows: Optional[np.ndarray] = None
 
     # -- state construction -------------------------------------------------
     def _decode_kernel(self) -> Kernel:
@@ -388,7 +411,7 @@ class DecodeEngine:
         for _ in range(3):                       # fixed point in <= 1 pass
             outs = jax.eval_shape(kern.executor, *io, *leaves, *pstructs)
             new = [jax.ShapeDtypeStruct(o.shape, o.dtype)
-                   for o in outs[1:]]
+                   for o in outs[1:1 + len(leaves)]]
             if [(l.shape, l.dtype) for l in new] == \
                     [(l.shape, l.dtype) for l in leaves]:
                 break
@@ -406,6 +429,23 @@ class DecodeEngine:
             tokens=jnp.zeros((b,), jnp.int32),
             positions=jnp.zeros((b,), jnp.int32),
             cache=cache, occupied=[False] * b, rids=[None] * b)
+
+    def _count_moe(self, experts: np.ndarray) -> None:
+        """Add one launch's routed experts (tokens, L_moe, top_k) to the
+        counters; the grouped matmul computes each touched held expert's
+        rows in whole tiles of the row tile it used for that many tokens."""
+        cfg = self.cfg
+        held = experts - cfg.expert_first
+        held = np.where((held >= 0) & (held < cfg.experts_held), held,
+                        cfg.experts_held)
+        rows = np.stack([np.bincount(held[:, i].ravel(),
+                                     minlength=cfg.experts_held + 1)
+                         for i in range(held.shape[1])])[:, :-1]
+        tm = serve_row_tile(cfg, experts.shape[0])
+        self.moe_last_experts, self.moe_last_rows = experts, rows
+        self.moe_rows_routed += int(rows.sum())
+        self.moe_rows_computed += int((-(-rows // tm) * tm).sum())
+        self.moe_experts_touched += int((rows > 0).sum())
 
     # -- counts -------------------------------------------------------------
     def _decode_counts_params(self) -> Dict[str, Any]:
@@ -472,7 +512,7 @@ class DecodeEngine:
                 raise AssertionError(
                     f"generate-step graph carries full-vocab outputs "
                     f"{[(a.shape, str(a.dtype)) for a in bad]}; "
-                    "make_decode_step(return_logits=False) must elide them")
+                    "the engine's decode step must elide them")
             # donation-aware sanitizer sweep at capture time (repro.analyze):
             # steady-state launches donate the cache-leaf slots, so prove
             # NOW that every reader of those slots sits on the ordered path
@@ -524,12 +564,14 @@ class DecodeEngine:
                                inputs=(prompt, *self._param_leaves),
                                requests=(), capacity=1, crop_outputs=False)
             ticket, _ = self.worker.launch(graph, batch, t_now=self.clock())
-            outs = ticket.outputs
-            tok = outs[0].data
+            outs = [b.data for b in ticket.outputs]
+            experts = outs.pop() if self._moe else None
+            tok = outs[0]
             cache = jax.tree_util.tree_unflatten(
-                jax.tree_util.tree_structure(self._bidx),
-                [b.data for b in outs[1:]])
+                jax.tree_util.tree_structure(self._bidx), outs[1:])
             jax.block_until_ready(tok)
+            if experts is not None:
+                self._count_moe(np.asarray(jax.device_get(experts)))
             self.worker.drain()
             modeled = ticket.modeled_latency_s or 0.0
             self.n_prefills += 1
@@ -597,13 +639,17 @@ class DecodeEngine:
                                capacity=self.num_slots, crop_outputs=False,
                                donate=donate)
             ticket, _ = self.worker.launch(graph, batch, t_now=self.clock())
-            outs = ticket.outputs
-            toks = outs[0].data
-            new_leaves = [b.data for b in outs[1:]]
+            outs = [b.data for b in ticket.outputs]
+            experts = outs.pop() if self._moe else None
+            toks = outs[0]
+            new_leaves = outs[1:]
             # realize BEFORE retiring: the next launch donates these buffers
             with span("engine.readback"):
-                tokens_np = np.asarray(jax.device_get(toks))
+                tokens_np, experts_np = jax.device_get((toks, experts))
+                tokens_np = np.asarray(tokens_np)
                 jax.block_until_ready(new_leaves)
+            if experts_np is not None:
+                self._count_moe(np.asarray(experts_np))
             self.worker.drain()
             state.tokens = toks
             state.positions = state.positions + 1
@@ -671,6 +717,14 @@ class DecodeEngine:
         registry.gauge("repro_engine_tokens_per_s_modeled",
                        "modeled steady-state decode throughput").set(
             self.tokens_per_s_modeled)
+        if self._moe:
+            moe = registry.counter(
+                "repro_moe_events_total",
+                "held-expert rows routed / computed (tile padding "
+                "included) and (layer, expert) pairs touched")
+            moe.set_total(self.moe_rows_routed, kind="rows_routed")
+            moe.set_total(self.moe_rows_computed, kind="rows_computed")
+            moe.set_total(self.moe_experts_touched, kind="experts_touched")
         ro = self.roofline()
         if ro is not None:
             registry.gauge("repro_engine_bytes_per_step",

@@ -1226,6 +1226,8 @@ class Server:
         registry = MetricsRegistry() if registry is None else registry
         self.report().publish_metrics(registry)
         self.cache.publish_metrics(registry)
+        if self.engine is not None:
+            self.engine.publish_metrics(registry)
         plans = {id(w.fault_plan): w.fault_plan
                  for w in self.dispatcher.workers
                  if w.fault_plan is not None}

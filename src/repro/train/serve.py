@@ -32,21 +32,10 @@ def make_prefill_step(cfg: ModelConfig, max_len: int,
     return prefill_step
 
 
-def make_decode_step(cfg: ModelConfig, return_logits: bool = True) -> Callable:
-    """One decode step: (params, cache, tokens, pos) -> next tokens.
-
-    ``return_logits=False`` is the serving fast path: the greedy argmax is
-    all a decode engine reads, so the step never materializes the
-    ``(B, vocab)`` logits as an output — a captured per-step graph stays
-    free of a full-vocabulary buffer it would otherwise carry every token
-    (pinned by an aval check in ``tests/test_decode_serve.py``).
-    """
-    if not return_logits:
-        def greedy_step(params, cache, tokens, pos):
-            logits, new_cache = decode_step(params, cache, tokens, pos, cfg)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_cache
-
-        return greedy_step
+def make_decode_step(cfg: ModelConfig) -> Callable:
+    """One decode step: (params, cache, tokens, pos) -> (next tokens,
+    logits, cache).  The decode engine takes its argmax inside its own
+    step, so that no ``(B, vocab)`` logits ride its captured graph."""
 
     def serve_step(params, cache, tokens, pos):
         logits, new_cache = decode_step(params, cache, tokens, pos, cfg)

@@ -18,6 +18,7 @@ HTTP ingress deliver the same bits.
 
 import asyncio
 import dataclasses
+import hashlib
 import json
 
 import jax
@@ -27,6 +28,7 @@ import pytest
 
 from repro.configs import ARCHS
 from repro.models import init_params, model_spec
+from repro.models.transformer import decode_step, prefill
 from repro.obs import Tracer
 from repro.serve import DecodeEngine, EngineHTTPServer, Server
 from repro.serve.faults import InjectedFault
@@ -42,8 +44,6 @@ FAMILIES = ["qwen2.5-3b",       # GQA: plain KV cache
 
 def _setup(arch, batch, prompt_len, seed=1):
     cfg = ARCHS[arch].reduced()
-    if cfg.n_experts:
-        cfg = dataclasses.replace(cfg, capacity_factor=4.0)
     params = init_params(model_spec(cfg), jax.random.PRNGKey(0))
     prompts = jnp.asarray(
         np.random.default_rng(seed).integers(0, cfg.vocab,
@@ -256,3 +256,48 @@ def test_engine_real_error_propagates(phase, monkeypatch):
         with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
             list(srv.stream(rid))
     assert srv.n_shed == 0
+
+
+# -- the dense decoder's served numbers, bit for bit ---------------------------
+
+#: qwen2.5-3b ``.reduced()`` in each dtype: a SHA-256 prefix of the logits
+#: of a batch-2 prefill of 12 tokens and 4 greedy decode steps after it,
+#: and the final cache; and the tokens ``Server`` -> ``DecodeEngine``
+#: serves to two staggered prompts.  Recorded (XLA:CPU, x86-64) from the
+#: serving path as it stood before YaRN, the layer index of the serving
+#: scans and the MoE layers' routed-expert outputs joined the code that
+#: qwen2.5-3b shares with DeepSeek-V2; a change that moves any bit of the
+#: dense decoder's served numbers fails here.  The digests depend on
+#: XLA:CPU's code generation, so a new JAX records them anew.
+QWEN_SERVED = {
+    "float32": ("163786422f4ceed3", [[485, 485, 485, 144, 241, 241],
+                                     [372, 63, 72, 427, 67, 67]]),
+    "bfloat16": ("f7c240c58f50d9b6", [[485, 485, 485, 144, 241, 241],
+                                      [372, 63, 72, 427, 67, 67]]),
+}
+
+
+@pytest.mark.parametrize("dtype", sorted(QWEN_SERVED))
+def test_qwen_served_numbers_unchanged(dtype):
+    cfg = dataclasses.replace(ARCHS["qwen2.5-3b"].reduced(), dtype=dtype)
+    params = init_params(model_spec(cfg), jax.random.PRNGKey(0))
+    toks = jnp.asarray(np.random.default_rng(1).integers(0, cfg.vocab,
+                                                         (2, 12)), jnp.int32)
+    logits, cache = prefill(params, {"tokens": toks}, cfg, max_len=20)
+    steps = [logits]
+    for pos in range(12, 16):
+        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+        logits, cache = decode_step(params, cache, nxt, jnp.int32(pos), cfg)
+        steps.append(logits)
+    h = hashlib.sha256()
+    for leaf in jax.tree_util.tree_leaves((steps, cache)):
+        h.update(np.ascontiguousarray(np.asarray(leaf)).tobytes())
+    want_digest, want_tokens = QWEN_SERVED[dtype]
+    assert h.hexdigest()[:16] == want_digest
+    eng = DecodeEngine(cfg, params, num_slots=2, max_len=24)
+    server = Server((), workers=(), engine=eng)
+    rids = [server.submit_decode(np.asarray(toks[i, : 8 + 4 * i]), 6)
+            for i in range(2)]
+    server.flush()
+    got = [np.asarray(server.result(r)[0]).tolist() for r in rids]
+    assert got == want_tokens

@@ -30,6 +30,7 @@ from repro.kernels.decode_attention.ops import (combine_partials,
                                                 decode_attention_ref)
 from repro.kernels.rwkv6_scan.ops import rwkv6_scan, rwkv6_scan_ref
 from repro.kernels.mamba_scan.ops import mamba_scan, mamba_scan_ref
+from repro.kernels.moe_gmm.ops import moe_gmm, moe_gmm_ref, tile_plan
 
 RNG = np.random.default_rng(0)
 
@@ -305,6 +306,47 @@ def test_mamba_chunked_equals_sequential():
     np.testing.assert_allclose(s2, s_full, rtol=1e-4, atol=1e-4)
 
 
+# -- grouped matmul over expert-sorted rows ----------------------------------
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+@pytest.mark.parametrize("sizes,tm,k,n", [
+    ((32, 0, 16, 48, 0), 16, 256, 384),     # an expert with no rows
+    ((0, 0, 0, 0, 0), 16, 128, 128),        # no row routed to this chip
+    ((256, 512, 0), 256, 384, 256),         # prefill-sized tiles
+    ((16,), 16, 96, 200),                   # widths off the 128 lanes
+])
+def test_moe_gmm(impl, sizes, tm, k, n):
+    """Pallas (interpret mode) and ragged_dot against the tile oracle on
+    every routed row; f32 operands, so only the summation order differs."""
+    gs = jnp.asarray(sizes, jnp.int32)
+    m = sum(sizes) + 2 * tm                 # unused tiles after the groups
+    x, w = rand(m, k), rand(len(sizes), k, n, scale=k ** -0.5)
+    tile_expert, n_active = tile_plan(gs, m, tm)
+    want = moe_gmm_ref(x, w, tile_expert, n_active, tm)
+    got = moe_gmm(x, w, gs, tm=tm, impl=impl)
+    r = sum(sizes)
+    np.testing.assert_allclose(got[:r], want[:r], rtol=1e-5, atol=1e-5)
+    assert int(n_active[0]) * tm == r
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_moe_gmm_reads_one_layer_of_a_stack(impl):
+    """Weights stacked over layers (L, E, K, N) with a layer index give what
+    that layer's (E, K, N) gives."""
+    gs = jnp.asarray([16, 32, 0], jnp.int32)
+    x, w = rand(64, 128), rand(3, 3, 128, 256, scale=128 ** -0.5)
+    want = moe_gmm(x, w[2], gs, tm=16, impl=impl)
+    got = moe_gmm(x, w, gs, jnp.int32(2), tm=16, impl=impl)
+    np.testing.assert_array_equal(got[:48], want[:48])
+
+
+def test_moe_gmm_tile_plan_skips_past_the_last_group():
+    te, na = tile_plan(jnp.asarray([32, 0, 16, 48, 0]), 160, 16)
+    assert int(na[0]) == 6
+    # tiles past the active ones repeat the last active tile's expert, so
+    # the kernel's index maps fetch nothing new for them
+    assert list(np.asarray(te)) == [0, 0, 2, 3, 3, 3, 3, 3, 3, 3]
+
+
 # -- device names ------------------------------------------------------------
 KERNELS_DIR = (pathlib.Path(__file__).resolve().parents[1]
                / "src" / "repro" / "kernels")
@@ -328,7 +370,7 @@ PALLAS_FILES = sorted(p for p in KERNELS_DIR.rglob("*.py") if _pallas_calls(p))
 def test_every_kernel_package_is_guarded():
     assert {p.parent.name for p in PALLAS_FILES} == {
         "decode_attention", "delineate", "fir", "flash_attention", "gemm",
-        "mamba_scan", "rwkv6_scan", "stockham_fft", "svm"}
+        "mamba_scan", "moe_gmm", "rwkv6_scan", "stockham_fft", "svm"}
 
 
 @pytest.mark.parametrize("path", PALLAS_FILES,

@@ -203,3 +203,87 @@ def test_qwen_engine_decode_step_compiles(one_chip):
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < V5E_HBM_BYTES)
+
+
+def _deepseek_share(one_chip):
+    """The benchmark's DeepSeek-V2 chip share: published widths, 5 layers,
+    group 0's 20 experts of the router's 160, 12800 vocabulary rows."""
+    import dataclasses
+
+    from repro.configs import ARCHS
+    from repro.models import model_spec
+    from repro.models.params import abstract_params
+    cfg = dataclasses.replace(ARCHS["deepseek-v2-236b"], n_layers=5,
+                              experts_held=20, vocab=12800)
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        abstract_params(model_spec(cfg), jnp.bfloat16))
+    return cfg, params
+
+
+def _kernel_names(compiled):
+    return set(re.findall(
+        r"%(\D+?)(?:\.\d+)? = .*custom_call_target=\"tpu_custom_call\"",
+        compiled.as_text()))
+
+
+def test_deepseek_prefill_step_compiles(one_chip):
+    """A 12288-token prefill of the DeepSeek-V2 chip share fits one chip;
+    MLA attends through the flash kernel at dk 192 / dv 128 and the held
+    experts run through the grouped matmul (the DecodeEngine's prefill
+    kernel, with its routed experts as an output)."""
+    from repro.core import EGPU_16T, Program
+    from repro.serve.engine import ENGINE_REGISTRY
+    cfg, params = _deepseek_share(one_chip)
+    kern = Program.build(EGPU_16T, registry=ENGINE_REGISTRY).create_kernel(
+        "engine.prefill", cfg=cfg, max_len=12544, cache_dtype="bfloat16")
+    kern.executor._params_def = jax.tree_util.tree_structure(params)
+    tokens = jax.ShapeDtypeStruct((1, 12288), jnp.int32, sharding=one_chip)
+    compiled = _compile(kern.executor, tokens,
+                        *jax.tree_util.tree_leaves(params))
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < V5E_HBM_BYTES
+    assert _kernel_names(compiled) == {"flash_attention_pallas",
+                                       "moe_gmm_pallas"}
+
+
+def test_deepseek_engine_decode_step_compiles(one_chip):
+    """The DecodeEngine's 16-slot step over the 12544-position latent
+    cache, with the cache donated, fits one chip."""
+    from repro.core import EGPU_16T, Program
+    from repro.models.transformer import cache_struct
+    from repro.serve.engine import ENGINE_REGISTRY
+    cfg, params = _deepseek_share(one_chip)
+    kern = Program.build(EGPU_16T, registry=ENGINE_REGISTRY).create_kernel(
+        "engine.generate", cfg=cfg, num_slots=16, cache_dtype="bfloat16")
+    kern.executor._params_def = jax.tree_util.tree_structure(params)
+    cache = [jax.ShapeDtypeStruct(c.shape, c.dtype, sharding=one_chip)
+             for c in jax.tree_util.tree_leaves(
+                 cache_struct(cfg, 16, 12544, jnp.bfloat16))]
+    io = [jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)] * 2
+    donate = tuple(range(2, 2 + len(cache)))
+    compiled = jax.jit(kern.executor, donate_argnums=donate).lower(
+        *io, *cache, *jax.tree_util.tree_leaves(params)).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < V5E_HBM_BYTES)
+    assert _kernel_names(compiled) == {"moe_gmm_pallas"}
+
+
+@pytest.mark.parametrize("rows,tm", [(79104, 256), (400, 16)],
+                         ids=["prefill", "decode"])
+def test_moe_gmm_compiles(one_chip, rows, tm):
+    """The grouped matmuls of one DeepSeek-V2 layer at the row counts of a
+    12288-token prefill and of a 16-slot decode step (20 held experts):
+    gate/up (5120 -> 1536) and down (1536 -> 5120)."""
+    from repro.kernels.moe_gmm.ops import moe_gmm
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    gs = spec(20, dtype=jnp.int32)
+    for k, n in ((5120, 1536), (1536, 5120)):
+        fn = lambda x, w, g: moe_gmm(x, w, g, tm=tm)  # noqa: E731
+        compiled = _compile(fn, spec(rows, k), spec(20, k, n), gs)
+        assert _kernel_names(compiled) == {"moe_gmm_pallas"}
