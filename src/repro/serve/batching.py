@@ -15,8 +15,9 @@ batched (paper §IV-B / §VIII-B).  The batcher closes the gap:
 3. :func:`batched_stages` lifts the pipeline's per-request kernels over the
    batch axis with ``jax.vmap`` (constants broadcast, work counts scaled by
    the batch size so the machine model stays honest);
-4. after launch, :meth:`MicroBatch.crop` slices each request's true extent
-   back out.
+4. after launch, :meth:`MicroBatch.crop` fetches the batch's outputs to
+   the host in one transfer and slices each request's true extent back out
+   of that copy: results are host ``numpy`` arrays.
 
 Correctness contract: pipeline kernels must be *pad-stable* — zero-padding a
 request along ``pad_axis`` must not change the outputs at the request's
@@ -35,6 +36,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.apu import Stage
 from ..core.machine import WorkCounts
@@ -84,8 +86,16 @@ class MicroBatch:
     def n_requests(self) -> int:
         return len(self.requests)
 
-    def crop(self, outputs: Sequence[Any]) -> List[Tuple[jax.Array, ...]]:
-        """Slice each live request's true extent out of the batched outputs.
+    def crop(self, outputs: Sequence[Any]) -> List[Tuple[np.ndarray, ...]]:
+        """Fetch the batched outputs to the host once and slice each live
+        request's true extent out of the host copy.
+
+        The outputs come back in ONE ``jax.device_get`` of the whole
+        launch (a sharded output is gathered from its shards); every
+        request's row is then a numpy slice of that copy, so a ticket
+        costs one transfer, not an eager device slice and a read per
+        request.  The padding rows of a partial batch travel with the
+        fetch and are never handed out.
 
         Every output is expected to carry the batch on axis 0; the request's
         ``pad_axis`` (an axis of the *un-batched* row, so axis ``pad_axis``
@@ -104,9 +114,10 @@ class MicroBatch:
         ``crop_outputs=False`` on the batcher/server and slice results
         themselves using ``ServeRequest.lengths``.
         """
+        host = [np.asarray(h) for h in jax.device_get(
+            tuple(o.data if hasattr(o, "data") else o for o in outputs))]
         if not self.crop_outputs:
-            return [tuple((o.data if hasattr(o, "data") else o)[i]
-                          for o in outputs)
+            return [tuple(arr[i, ...] for arr in host)
                     for i in range(len(self.requests))]
         ax = self.pad_axis
 
@@ -116,12 +127,11 @@ class MicroBatch:
             return (src.shape[ax + 1]
                     if src is not None and src.ndim > ax + 1 else None)
 
-        per_request: List[Tuple[jax.Array, ...]] = []
+        per_request: List[Tuple[np.ndarray, ...]] = []
         for i, req in enumerate(self.requests):
             rows = []
-            for j, out in enumerate(outputs):
-                arr = out.data if hasattr(out, "data") else out
-                row = arr[i]
+            for j, arr in enumerate(host):
+                row = arr[i, ...]        # a 0-d array, not a scalar
                 length = (req.lengths[j if j < len(req.lengths) else 0]
                           if req.lengths else None)
                 padded = padded_len(j)

@@ -117,6 +117,11 @@ class ServeReport:
     #: completed results dropped by the bounded LRU store (not fetched or
     #: ``keep``-refreshed within the last ``metrics_window`` completions)
     results_evicted: int = 0
+    #: retired tickets whose outputs came back to the host (one
+    #: ``jax.device_get`` each) and the bytes those fetches moved; on the
+    #: Stage path fetches equal batches
+    n_result_fetches: int = 0
+    result_fetch_bytes: int = 0
     # -- robustness counters (ISSUE 6) --------------------------------------
     #: requests shed: admission rejects + priority preemptions + batches
     #: that exhausted every dispatch retry
@@ -208,6 +213,12 @@ class ServeReport:
           "completed requests").set_total(self.n_requests)
         c("repro_serve_batches_total",
           "launched micro-batches").set_total(self.n_batches)
+        c("repro_serve_result_fetches_total",
+          "retired tickets fetched to the host").set_total(
+            self.n_result_fetches)
+        c("repro_serve_result_fetch_bytes_total",
+          "bytes of batched outputs fetched to the host").set_total(
+            self.result_fetch_bytes)
         c("repro_serve_shed_total",
           "requests shed (door rejects + preemptions + dispatch "
           "exhaustion)").set_total(self.n_shed)
@@ -509,6 +520,8 @@ class Server:
         self._results: "OrderedDict[int, Tuple[Any, ...]]" = OrderedDict()
         self._results_window = max(1, int(metrics_window))
         self._results_evicted = 0
+        self._n_result_fetches = 0
+        self._result_fetch_bytes = 0
         self._evicted_upto = -1          # highest rid ever evicted unread
         # Accepted-then-shed requests (priority preemption, dispatch
         # exhaustion): rid -> reason.  Bounded like the results store so a
@@ -745,7 +758,8 @@ class Server:
 
     # -- results ------------------------------------------------------------
     def result(self, rid: int, keep: bool = False) -> Tuple[Any, ...]:
-        """Per-request outputs (cropped back to the request's true extent).
+        """Per-request outputs as host ``numpy`` arrays, cropped back to
+        the request's true extent (engine requests: their tokens).
 
         Pops the stored result by default (pass ``keep=True`` to leave it
         readable again).  The store is a bounded LRU: results neither
@@ -1053,6 +1067,8 @@ class Server:
                   n=sum(t.batch.n_requests for t in tickets)):
             for t in tickets:
                 per_request = t.batch.crop(t.outputs)
+                self._n_result_fetches += 1
+                self._result_fetch_bytes += sum(o.nbytes for o in t.outputs)
                 n = max(1, t.batch.n_requests)
                 # modeled start of the batch's service window on its lane
                 # (t_done_modeled already includes any queueing behind the
@@ -1188,6 +1204,8 @@ class Server:
             sanitizer_findings=self.cache.findings,
             mesh_utilization=mesh_util,
             results_evicted=self._results_evicted,
+            n_result_fetches=self._n_result_fetches,
+            result_fetch_bytes=self._result_fetch_bytes,
             n_shed=self.n_shed,
             n_deadline_violations=self._n_deadline_violations,
             goodput_per_s=(self._n_in_deadline / wall if wall > 0 else 0.0),

@@ -578,9 +578,14 @@ def test_crop_uses_per_output_lengths():
              jnp.arange(7, dtype=jnp.float32))   # both pad to bucket 8
     (mb,) = b.drain()
     assert mb.requests[0].lengths == (5, 7)
-    (o0, o1) = mb.crop([mb.inputs[0] * 2, mb.inputs[1] * 3])[0]
+    outs = [mb.inputs[0] * 2, mb.inputs[1] * 3]
+    (o0, o1) = mb.crop(outs)[0]
     assert o0.shape == (5,)
     assert o1.shape == (7,)              # was wrongly cropped to 5
+    # host arrays, bit-identical to the device rows they were cut from
+    assert isinstance(o0, np.ndarray) and isinstance(o1, np.ndarray)
+    assert o0.tobytes() == np.asarray(outs[0][0, :5]).tobytes()
+    assert o1.tobytes() == np.asarray(outs[1][0, :7]).tobytes()
     np.testing.assert_array_equal(np.asarray(o1),
                                   3 * np.arange(7, dtype=np.float32))
 
@@ -593,6 +598,9 @@ def test_crop_per_output_padded_extent_detection():
     b.submit(jnp.arange(5, dtype=jnp.float32),     # -> bucket 8
              jnp.arange(12, dtype=jnp.float32))    # -> bucket 16
     (mb,) = b.drain()
-    (o0, o1) = mb.crop([mb.inputs[0] * 2, mb.inputs[1] * 3])[0]
+    outs = [mb.inputs[0] * 2, mb.inputs[1] * 3]
+    (o0, o1) = mb.crop(outs)[0]
     assert o0.shape == (5,)
     assert o1.shape == (12,)             # was returned whole (16,) before
+    assert isinstance(o0, np.ndarray) and isinstance(o1, np.ndarray)
+    assert o1.tobytes() == np.asarray(outs[1][0, :12]).tobytes()

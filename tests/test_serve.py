@@ -8,6 +8,7 @@ with bit-identical batched results, event-lifecycle memory bounds, and
 dispatcher backpressure.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -271,6 +272,117 @@ def test_crop_outputs_false_returns_padded_rows():
     (row,) = mb.crop([mb.inputs[0] * 2])[0]
     assert row.shape == (8,)             # padded extent kept
     assert r.lengths == (5,)             # caller slices with this
+
+
+def _counting_device_get(monkeypatch):
+    """Count every ``jax.device_get`` from here on; returns the counter."""
+    calls = [0]
+    real = jax.device_get
+
+    def counted(x):
+        calls[0] += 1
+        return real(x)
+
+    monkeypatch.setattr(jax, "device_get", counted)
+    return calls
+
+
+# (batcher kwargs, per-request array shapes, outputs from the batched
+#  inputs, expected per-request output shapes)
+CROP_CASES = {
+    "full_pad0": (dict(bucket_sizes=(8,), max_batch=2),
+                  [[(5,)], [(8,)]], lambda x: [x[0] * 2],
+                  [[(5,)], [(8,)]]),
+    "partial_pad0": (dict(bucket_sizes=(8,), max_batch=3),
+                     [[(5,)]], lambda x: [x[0] * 2], [[(5,)]]),
+    "full_pad1": (dict(bucket_sizes=(8,), max_batch=2, pad_axis=1),
+                  [[(3, 5)], [(3, 8)]], lambda x: [x[0] * 2],
+                  [[(3, 5)], [(3, 8)]]),
+    "partial_pad1": (dict(bucket_sizes=(8,), max_batch=3, pad_axis=1),
+                     [[(3, 5)], [(3, 2)]], lambda x: [x[0] - 1],
+                     [[(3, 5)], [(3, 2)]]),
+    "per_output_lengths": (dict(bucket_sizes=(8,), max_batch=2),
+                           [[(5,), (7,)]], lambda x: [x[0] * 2, x[1] * 3],
+                           [[(5,), (7,)]]),
+    "crop_outputs_false": (dict(bucket_sizes=(8,), max_batch=2,
+                                crop_outputs=False),
+                           [[(5,)], [(3,)]], lambda x: [x[0] * 2],
+                           [[(8,)], [(8,)]]),
+    "two_outputs_one_reduced": (dict(bucket_sizes=(8, 16), max_batch=3),
+                                [[(5,)], [(6,)]],
+                                lambda x: [x[0] * 2, x[0].sum(axis=-1)],
+                                [[(5,), ()], [(6,), ()]]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CROP_CASES))
+def test_crop_fetches_host_rows(case, monkeypatch):
+    """``crop`` fetches the launch's outputs in ONE ``jax.device_get`` and
+    returns each request's true extent as host numpy arrays, bit-identical
+    to the device rows they were cut from (full and partial batches, both
+    pad axes, per-output lengths, ``crop_outputs=False``, two outputs)."""
+    kwargs, shapes, outputs_of, expected = CROP_CASES[case]
+    rng = np.random.default_rng(len(case))
+    b = BucketBatcher(**kwargs)
+    for arrays in shapes:
+        b.submit(*(jnp.asarray(rng.standard_normal(s), jnp.float32)
+                   for s in arrays))
+    (mb,) = b.drain()
+    outs = outputs_of(mb.inputs)
+    device_rows = [np.asarray(o) for o in outs]
+    calls = _counting_device_get(monkeypatch)
+    got = mb.crop(outs)
+    assert calls[0] == 1
+    assert len(got) == len(shapes)
+    for i, (row, want) in enumerate(zip(got, expected)):
+        assert len(row) == len(want)
+        for j, (arr, shape) in enumerate(zip(row, want)):
+            assert isinstance(arr, np.ndarray)
+            assert arr.shape == shape
+            ref = device_rows[j][i][tuple(slice(0, n) for n in shape)]
+            assert arr.dtype == ref.dtype
+            assert arr.tobytes() == ref.tobytes()
+
+
+def test_server_fetches_each_ticket_once(monkeypatch):
+    """A Server run of full and partial batches makes exactly one
+    ``jax.device_get`` per retired ticket, counted by
+    ``repro_serve_result_fetches_total`` beside
+    ``repro_serve_batches_total``; results are host arrays equal to the
+    per-request eager offload."""
+    stages = _mm_stages(n=2)
+    srv = Server(stages, workers=(EGPU_16T,), bucket_sizes=(8,),
+                 max_batch=2, max_in_flight=2)
+    srv.warmup(jnp.zeros((1, 8), jnp.float32))
+    calls = _counting_device_get(monkeypatch)
+    rng = np.random.default_rng(21)
+    rids = []
+    for n_submit in (5, 2, 3):           # full batches and partial ones
+        for _ in range(n_submit):
+            x = jnp.asarray(rng.standard_normal(
+                (int(rng.integers(1, 9)), 8)), jnp.float32)
+            rids.append((srv.submit(x), x))
+        srv.flush()
+    rep = srv.report()
+    assert rep.n_requests == 10
+    assert rep.n_batches == 6            # 4 full, 2 partial
+    assert rep.n_result_fetches == rep.n_batches == calls[0]
+    # one (2, 8, 8) float32 output a ticket, padding rows included
+    assert rep.result_fetch_bytes == rep.n_batches * 2 * 8 * 8 * 4
+    registry = srv.publish_metrics()
+    fetches = registry.get("repro_serve_result_fetches_total").value()
+    batches = registry.get("repro_serve_batches_total").value()
+    assert fetches == batches == rep.n_batches
+    assert (registry.get("repro_serve_result_fetch_bytes_total").value()
+            == rep.result_fetch_bytes)
+    apu = APU(EGPU_16T)
+    for rid, x in rids:
+        (got,) = srv.result(rid)
+        assert isinstance(got, np.ndarray) and got.shape == x.shape
+        ref, _ = apu.offload(stages, (x,), mode="eager")
+        # a padded batch and a lone request may sum in another order on
+        # the CPU; bit-identity with the device rows is pinned above
+        np.testing.assert_allclose(got, np.asarray(ref[0].data), rtol=1e-6)
 
 
 def test_batched_stages_scale_counts():

@@ -258,7 +258,7 @@ def serve(worker):
     srv.cache = cache
     rids = [srv.submit(s) for s in sigs]
     srv.flush()
-    return [tuple(np.asarray(o) for o in srv.result(r)) for r in rids], srv
+    return [srv.result(r) for r in rids], srv
 
 plain, _ = serve(QueueWorker(EGPU_16T, name="single"))
 sharded, srv = serve(ShardedWorker(EGPU_16T, data_mesh(2), name="mesh"))
@@ -266,9 +266,13 @@ sharded, srv = serve(ShardedWorker(EGPU_16T, data_mesh(2), name="mesh"))
 identical = all(
     len(a) == len(b) and all(np.array_equal(x, y) for x, y in zip(a, b))
     for a, b in zip(plain, sharded))
-qs = srv.report().queues[0]
+host = all(isinstance(o, np.ndarray) for r in plain + sharded for o in r)
+rep = srv.report()
+qs = rep.queues[0]
 print(json.dumps({
     "identical": identical,
+    "host": host,
+    "fetches": [rep.n_result_fetches, rep.n_batches],
     "cache": cache.stats(),
     "shards": qs.shards,
     "util": dict(qs.mesh_utilization),
@@ -277,17 +281,12 @@ print(json.dumps({
 
 
 def test_tinybio_sharded_bit_identical_subprocess():
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)           # the script sets its own
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(os.path.dirname(__file__), "..", "src")]
-        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-    proc = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
-                          capture_output=True, text=True, timeout=1200)
-    assert proc.returncode == 0, proc.stderr[-4000:]
-    result = json.loads(proc.stdout.strip().splitlines()[-1])
-    # bit-identical through the sharded lane
+    result = _run_two_device_script(SCRIPT)
+    # bit-identical through the sharded lane, fetched to the host once a
+    # ticket (the device_get gathers the data-sharded output)
     assert result["identical"]
+    assert result["host"]
+    assert result["fetches"] == [1, 1]
     # one entry per worker in the SHARED cache: zero key collisions (a
     # collision would read as 1 miss + 1 hit), nothing evicted
     assert result["cache"]["misses"] == 2
@@ -296,6 +295,74 @@ def test_tinybio_sharded_bit_identical_subprocess():
     # the full TinyBio bucket (batch 2 over data=2) genuinely sharded
     assert result["shards"] == 2
     assert result["util"] == {"data": 1.0}
+
+
+MM_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import json
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from repro.core import EGPU_16T, Kernel, Stage
+from repro.kernels.gemm.ref import gemm_ref
+from repro.serve import QueueWorker, Server, ShardedWorker, data_mesh
+
+assert len(jax.devices()) == 2, jax.devices()
+rng = np.random.default_rng(0)
+w = jnp.asarray(rng.standard_normal((8, 8)) * 0.2, jnp.float32)
+stages = [Stage(Kernel("mlp", executor=lambda x, w: jnp.maximum(
+    gemm_ref(x, w), 0.0)), consts=(w,), n_inputs=1)]
+xs = [jnp.asarray(rng.standard_normal((int(rng.integers(1, 9)), 8)),
+                  jnp.float32) for _ in range(7)]
+
+def serve(worker):
+    srv = Server(stages, workers=(worker,), bucket_sizes=(8,), max_batch=2)
+    out = []
+    for group in (xs[:5], xs[5:]):       # 2 full + 1 partial, then 1 full
+        rids = [srv.submit(x) for x in group]
+        srv.flush()
+        out += [srv.result(r) for r in rids]
+    rep = srv.report()
+    return out, [rep.n_result_fetches, rep.n_batches]
+
+plain, plain_fetches = serve(QueueWorker(EGPU_16T, name="single"))
+sharded, sharded_fetches = serve(
+    ShardedWorker(EGPU_16T, data_mesh(2), name="mesh"))
+print(json.dumps({
+    "host": all(isinstance(o, np.ndarray)
+                for r in plain + sharded for o in r),
+    "shapes": [list(r[0].shape) == list(x.shape)
+               for r, x in zip(sharded, xs)],
+    "identical": all(a[0].tobytes() == b[0].tobytes()
+                     for a, b in zip(plain, sharded)),
+    "fetches": [plain_fetches, sharded_fetches],
+}))
+"""
+
+
+def _run_two_device_script(script):
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)           # the script sets its own
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src")]
+        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    proc = subprocess.run([sys.executable, "-c", script], env=env,
+                          capture_output=True, text=True, timeout=1200)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_sharded_results_fetched_to_host_subprocess():
+    """On a 2-device data mesh, full and partial batches come back through
+    one host fetch a ticket, cropped to each request's rows, and equal a
+    plain QueueWorker's results bit for bit."""
+    result = _run_two_device_script(MM_SCRIPT)
+    assert result["host"]
+    assert all(result["shapes"])
+    assert result["identical"]
+    assert result["fetches"] == [[4, 4], [4, 4]]
 
 
 # ---------------------------------------------------------------------------
