@@ -1,6 +1,6 @@
 """Benchmark orchestrator: one bench per paper table/figure + roofline.
 
-    PYTHONPATH=src python -m benchmarks.run [--only static|gemm|tinybio|dispatch|multiqueue|serve|overload|power|decode|roofline]
+    PYTHONPATH=src python -m benchmarks.run [--only static|gemm|tinybio|dispatch|multiqueue|transfer|serve|sharded|overload|roofline]
                                             [--trace PATH]
 
 ``--trace PATH`` exports each traced serve bench's Chrome trace JSON
@@ -15,10 +15,9 @@ import time
 
 from repro.launch.compile_cache import enable_compile_cache
 
-from . import (bench_decode, bench_dispatch, bench_gemm_overhead,
-               bench_multiqueue, bench_overload, bench_power, bench_roofline,
-               bench_serve, bench_sharded, bench_static, bench_tinybio,
-               bench_transfer)
+from . import (bench_dispatch, bench_gemm_overhead, bench_multiqueue,
+               bench_overload, bench_roofline, bench_serve, bench_sharded,
+               bench_static, bench_tinybio, bench_transfer)
 
 BENCHES = {
     "static": bench_static.run,        # paper Fig 2
@@ -30,8 +29,6 @@ BENCHES = {
     "serve": bench_serve.run,          # ISSUE-2 cached-graph serving path
     "sharded": bench_sharded.run,      # ISSUE-5 mesh-sharded serving lane
     "overload": bench_overload.run,    # ISSUE-6 open-loop goodput under faults
-    "power": bench_power.run,          # ISSUE-8 goodput-per-watt under budget
-    "decode": bench_decode.run,        # ISSUE-9 continuous-batching decode
     "roofline": bench_roofline.run,    # EXPERIMENTS §Roofline table
 }
 

@@ -20,7 +20,7 @@ Public API:
 from .apu import APU, PipelineReport, Stage, StageReport
 from .device import (EGPU_4T, EGPU_8T, EGPU_16T, HOST, OP_ANCHOR,
                      OPERATING_POINTS, PRESETS, EGPUConfig, KernelKnobs,
-                     OperatingPoint, check_vmem_budget, env_op_point)
+                     OperatingPoint, check_vmem_budget)
 from .machine import (CAL, PhaseBreakdown, WorkCounts, egpu_time,
                       fuse_breakdowns, host_time, speedup, transfer_time)
 from .ndrange import NDRange, crop_from_groups, edge_mask, global_ids, pad_to_groups
@@ -38,7 +38,7 @@ __all__ = [
     "APU", "PipelineReport", "Stage", "StageReport",
     "EGPU_4T", "EGPU_8T", "EGPU_16T", "HOST", "OP_ANCHOR", "OPERATING_POINTS",
     "PRESETS", "EGPUConfig", "KernelKnobs", "OperatingPoint",
-    "check_vmem_budget", "env_op_point",
+    "check_vmem_budget",
     "CAL", "PhaseBreakdown", "WorkCounts", "egpu_time", "fuse_breakdowns",
     "host_time", "speedup", "transfer_time",
     "NDRange", "crop_from_groups", "edge_mask", "global_ids", "pad_to_groups",

@@ -32,8 +32,7 @@ they can parameterize jitted functions as static arguments.
 from __future__ import annotations
 
 import dataclasses
-import os
-from typing import Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 KIB = 1024
 MIB = 1024 * KIB
@@ -85,30 +84,6 @@ OPERATING_POINTS: Dict[str, OperatingPoint] = {
         OperatingPoint("turbo", 450e6, 0.95).validate(),
     )
 }
-
-
-def env_op_point(value: Optional[str] = None) -> Optional[OperatingPoint]:
-    """Resolve the ``REPRO_OP_POINT`` environment override (CI's non-anchor
-    leg re-runs the serving suites under it to pin op-point-independent
-    bit-identical outputs).
-
-    ``value`` (or the env var) is a name from :data:`OPERATING_POINTS` or an
-    explicit ``"<freq_hz>:<voltage_v>"`` pair, e.g. ``"200e6:0.7"``.
-    Returns ``None`` when unset/empty.
-    """
-    raw = os.environ.get("REPRO_OP_POINT", "") if value is None else value
-    raw = raw.strip()
-    if not raw:
-        return None
-    if raw in OPERATING_POINTS:
-        return OPERATING_POINTS[raw]
-    parts = raw.split(":")
-    if len(parts) != 2:
-        raise ValueError(
-            f"REPRO_OP_POINT={raw!r}: expected a name in "
-            f"{sorted(OPERATING_POINTS)} or '<freq_hz>:<voltage_v>'")
-    return OperatingPoint(f"env:{raw}", float(parts[0]),
-                          float(parts[1])).validate()
 
 
 @dataclasses.dataclass(frozen=True)

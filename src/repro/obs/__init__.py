@@ -70,22 +70,6 @@ injections, breaker trips, shed decisions, and cache hits/misses attached
 as span events.  :meth:`Tracer.validate_request_trees` pins the
 completeness contract: every accepted rid's tree closes with exactly one
 terminal span (``result`` or a named ``shed``).
-
-Power telemetry (ISSUE 8): serving under a
-:class:`~repro.serve.PowerBudget` lands in the same channels — the
-dispatcher emits a ``power-throttle`` track instant for every candidate
-lane skipped over a budget breach and a ``power-shed`` request event on
-every rid shed because no lane had headroom, while
-``ServeReport.publish_metrics`` adds the fleet power series
-(``repro_fleet_avg_power_watts``, ``repro_fleet_peak_power_watts``,
-``repro_fleet_energy_joules`` / ``repro_fleet_idle_energy_joules``,
-``repro_serve_requests_per_second_per_watt``,
-``repro_serve_goodput_per_second_per_watt``) and the enforcement
-counters (``repro_serve_power_shed_total``,
-``repro_serve_power_throttled_total``,
-``repro_serve_budget_violations_total`` — the last must read 0) plus
-per-lane ``repro_lane_idle_power_watts`` /
-``repro_lane_budget_violations_total``.
 """
 
 from .metrics import (DEFAULT_BUCKETS, Counter, Gauge, Histogram,

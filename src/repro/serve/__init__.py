@@ -17,8 +17,7 @@ engine:
   totals scale by the shard count;
 * :class:`Server` / :class:`ServeReport` — the front-end tying them
   together: submit -> batch -> cached fused launch -> per-request results +
-  requests/s, modeled latency percentiles, per-mesh-axis utilization and
-  energy per request;
+  requests/s, modeled latency percentiles and per-mesh-axis utilization;
 * the open-loop front door (ISSUE 6) — SLO-aware intake
   (``Server.submit(deadline=..., priority=...)`` with modeled-capacity
   admission control and loud :class:`AdmissionError` sheds), deadline-aware
@@ -35,17 +34,6 @@ engine:
   :attr:`ServeReport.latency_decomposition_s` carries the p50/p99 flame
   attribution over :data:`DECOMP_PHASES`.  All opt-in: an untraced server
   allocates nothing from ``repro.obs`` on its hot dispatch path;
-* power-budget-aware serving (ISSUE 8) — ``Server(power_budget=...)``
-  installs a :class:`PowerBudget` (per-lane and/or fleet caps in mW, the
-  paper's <= 28 mW envelope) on the dispatcher, which prices every
-  candidate lane per launch (:class:`LanePrice`: modeled window,
-  window-average power, requests-per-joule), routes to the most efficient
-  on-budget lane, throttles breachy candidates, and sheds loudly with
-  :class:`PowerBudgetError` when no lane has headroom.  Idle lanes burn
-  their clock-gated leakage floor in :class:`ServeReport`'s honest fleet
-  energy, and every launch re-audits its booked window price
-  (``n_budget_violations`` must stay 0).  Composes with DVFS operating
-  points (:class:`~repro.core.OperatingPoint`, ``EGPUConfig.at``);
 * the continuous-batching decode engine (ISSUE 9) —
   :class:`DecodeEngine` serves autoregressive decode maxtext/JetStream
   style: per-request ``prefill`` -> ``insert`` into a slot of a persistent
@@ -55,9 +43,7 @@ engine:
   never a re-capture), bit-identical to whole-batch greedy decoding for
   every cache family under staggered arrival.  ``Server(engine=...)``
   opens the streaming front (``submit_decode`` / per-rid ``stream``
-  iterators — a finished request never blocks neighbors), the step cost
-  is priced by the machine model with a bytes-per-step roofline read off
-  the captured schedule (:class:`EngineRoofline`), and
+  iterators — a finished request never blocks neighbors), and
   :mod:`repro.serve.http` puts a dependency-free asyncio streaming HTTP
   ingress in front of it.  Engine classes load lazily — pipeline-only
   servers keep the model stack off their import path.
@@ -68,11 +54,9 @@ from .batching import (BucketBatcher, MicroBatch, ServeRequest,
 from .cache import (GraphCache, input_signature, stage_signature,
                     stages_signature)
 from .dispatch import (CircuitBreaker, DispatchError, LaunchTicket,
-                       MultiQueueDispatcher, PowerBudgetError, QueueStats,
-                       QueueWorker)
+                       MultiQueueDispatcher, QueueStats, QueueWorker)
 from .faults import (Blackout, FaultDecision, FaultPlan, InjectedFault,
                      apply_spike, env_seed)
-from .power import LanePrice, PowerBudget
 from .server import (DECOMP_PERCENTILES, DECOMP_PHASES, PERCENTILES,
                      AdmissionError, Server, ServeReport)
 from .sharded import (BATCH_AXIS, ShardedWorker, data_mesh, mesh_signature,
@@ -80,8 +64,7 @@ from .sharded import (BATCH_AXIS, ShardedWorker, data_mesh, mesh_signature,
 
 #: engine symbols resolved lazily (PEP 562): importing them pulls the model
 #: stack (repro.models / repro.train), which pipeline-only servers avoid
-_ENGINE_EXPORTS = ("DecodeEngine", "DecodeState", "EngineRoofline", "Prefix",
-                   "batch_axes", "engine_roofline", "graph_traffic")
+_ENGINE_EXPORTS = ("DecodeEngine", "DecodeState", "Prefix", "batch_axes")
 _HTTP_EXPORTS = ("EngineHTTPServer",)
 
 
@@ -99,10 +82,9 @@ __all__ = [
     "BucketBatcher", "MicroBatch", "ServeRequest", "batched_stages", "pad_to",
     "GraphCache", "input_signature", "stage_signature", "stages_signature",
     "CircuitBreaker", "DispatchError", "LaunchTicket", "MultiQueueDispatcher",
-    "PowerBudgetError", "QueueStats", "QueueWorker",
+    "QueueStats", "QueueWorker",
     "Blackout", "FaultDecision", "FaultPlan", "InjectedFault", "apply_spike",
     "env_seed",
-    "LanePrice", "PowerBudget",
     "DECOMP_PERCENTILES", "DECOMP_PHASES", "PERCENTILES",
     "AdmissionError", "Server", "ServeReport",
     "BATCH_AXIS", "ShardedWorker", "data_mesh", "mesh_signature",

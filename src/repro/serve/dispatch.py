@@ -19,8 +19,8 @@ worker's queue only — per-queue accounting is exact by construction, not by
 coincidence.  Workers retire tickets through the Event-lifecycle API: after
 a ticket's outputs are realized, ``queue.drain(n)`` +
 ``queue.release_events(upto=n)`` return the worker's queue to O(in-flight)
-memory while the released events' modeled time/energy stay in the queue's
-running totals.
+memory while the released events' modeled time stays in the queue's running
+totals.
 
 Fault tolerance (ISSUE 6): a worker built with a
 :class:`~repro.serve.faults.FaultPlan` gates every ``_do_launch`` through
@@ -50,13 +50,11 @@ from typing import Callable, List, Optional, Sequence, Set, Tuple
 from ..core.apu import APU
 from ..core.device import EGPUConfig
 from ..core.machine import PhaseBreakdown
-from ..core.power import egpu_idle_power_mw
 from ..core.runtime import Buffer, CommandGraph
 from ..obs import Tracer
 from ..obs.profiler import span
 from .batching import MicroBatch
 from .faults import FaultPlan, InjectedFault, apply_spike
-from .power import LanePrice, PowerBudget
 
 
 class DispatchError(RuntimeError):
@@ -71,16 +69,6 @@ class DispatchError(RuntimeError):
         self.retired = tuple(retired)
 
 
-class PowerBudgetError(DispatchError):
-    """No lane can take the micro-batch within the :class:`PowerBudget`.
-
-    A :class:`DispatchError` subclass so the server's existing loud-shed
-    machinery applies unchanged: the batch's requests surface an
-    :class:`~repro.serve.server.AdmissionError` naming the budget — an
-    over-budget fleet throttles and sheds, it never quietly overdraws.
-    """
-
-
 @dataclasses.dataclass
 class LaunchTicket:
     """One in-flight micro-batch launch and its modeled cost."""
@@ -91,7 +79,6 @@ class LaunchTicket:
     #: fused breakdown of the whole batched chain (startup+scheduling paid
     #: once per launch — every request in the batch experiences this latency)
     fused: Optional[PhaseBreakdown]
-    energy_j: float
     t_launch: float
     t_done: Optional[float] = None
     #: events this launch appended to the launching worker's queue (one per
@@ -105,10 +92,6 @@ class LaunchTicket:
     @property
     def done(self) -> bool:
         return self.t_done is not None
-
-    @property
-    def modeled_latency_s(self) -> Optional[float]:
-        return None if self.fused is None else self.fused.total_s
 
 
 class QueueWorker:
@@ -155,22 +138,13 @@ class QueueWorker:
         #: timeline); launches queue behind it, giving deterministic
         #: per-ticket modeled completion times
         self.modeled_busy_until = 0.0
-        #: clock-gated leakage floor of this lane, watts (§IV SLEEP_REQ):
-        #: what the lane draws between launches; scales with the config's
-        #: DVFS operating point through the power model
-        self.idle_power_w = egpu_idle_power_mw(config) * 1e-3
-        #: the fleet's :class:`PowerBudget` (installed by the dispatcher);
-        #: when set, every launch re-audits its own window-average power
-        self.power_budget: Optional[PowerBudget] = None
         # accounting
         self.n_batches = 0
         self.n_requests = 0
         self.modeled_s = 0.0
-        self.energy_j = 0.0
         self.peak_in_flight = 0
         self.backpressure_stalls = 0
         self.launch_failures = 0         # injected faults this lane absorbed
-        self.budget_violations = 0       # launches that broke the lane cap
 
     @property
     def depth(self) -> int:
@@ -181,58 +155,6 @@ class QueueWorker:
         """Live requests across this lane's in-flight tickets (admission
         control counts them as queue depth)."""
         return sum(t.batch.n_requests for t in self._inflight)
-
-    # -- power pricing (ISSUE 8) --------------------------------------------
-    def estimate(self, graph: CommandGraph
-                 ) -> Tuple[Optional[PhaseBreakdown], float]:
-        """Modeled (fused breakdown, energy) a launch of ``graph`` would
-        book on this lane — the dispatcher's pricing view, matching
-        :meth:`_do_launch`'s accounting exactly minus injected latency
-        spikes (which only *lengthen* the window, so the price is an upper
-        bound on the booked window-average power).  ShardedWorker overrides
-        with its shard-scaled breakdown."""
-        return graph.fused_modeled()
-
-    def pending_energy_j(self, t_now: float) -> float:
-        """Energy this lane's in-flight tickets still deliver after
-        ``t_now``: each ticket's launch energy, scaled by the unelapsed
-        fraction of its modeled service window."""
-        e = 0.0
-        for t in self._inflight:
-            if t.fused is None or t.t_done_modeled is None:
-                continue
-            dur = t.fused.total_s
-            if dur <= 0.0:
-                continue
-            remaining = min(dur, max(0.0, t.t_done_modeled - t_now))
-            e += t.energy_j * (remaining / dur)
-        return e
-
-    def price(self, fused: Optional[PhaseBreakdown], energy_j: float,
-              t_now: float, n_requests: int = 1) -> LanePrice:
-        """Price a candidate launch: modeled latency (backlog + service on
-        this lane's timeline) and the window-average power committing to
-        it implies.  Pure read — books nothing."""
-        modeled_s = fused.total_s if fused is not None else 0.0
-        backlog_s = max(0.0, self.modeled_busy_until - t_now)
-        window_s = backlog_s + modeled_s
-        total_e = self.pending_energy_j(t_now) + energy_j
-        avg_power_w = total_e / window_s if window_s > 0.0 else 0.0
-        rpj = (n_requests / total_e) if total_e > 0.0 else float("inf")
-        return LanePrice(lane=self.name, modeled_s=modeled_s,
-                         window_s=window_s, avg_power_w=avg_power_w,
-                         energy_j=energy_j, requests_per_joule=rpj)
-
-    def current_power_w(self, t_now: float) -> float:
-        """This lane's modeled draw right now: remaining in-flight energy
-        over the remaining busy window, floored at the clock-gated leakage
-        the silicon burns regardless; an idle lane sits exactly on that
-        floor (§IV — SLEEP_REQ gates the clocks, leakage stays)."""
-        backlog_s = max(0.0, self.modeled_busy_until - t_now)
-        if backlog_s <= 0.0:
-            return self.idle_power_w
-        return max(self.idle_power_w,
-                   self.pending_energy_j(t_now) / backlog_s)
 
     # -- launch / retire ----------------------------------------------------
     def _fault_gate(self) -> float:
@@ -257,9 +179,8 @@ class QueueWorker:
         return decision.spike_s
 
     def _do_launch(self, graph: CommandGraph, batch: MicroBatch
-                   ) -> Tuple[Tuple[Buffer, ...],
-                              Optional[PhaseBreakdown], float]:
-        """Fire one launch and return (outputs, fused breakdown, energy).
+                   ) -> Tuple[Tuple[Buffer, ...], Optional[PhaseBreakdown]]:
+        """Fire one launch and return (outputs, fused breakdown).
 
         Gated by :meth:`_fault_gate` (ISSUE 6) — an injected failure raises
         before the graph runs, so retries replay identical pure code.  The
@@ -272,8 +193,8 @@ class QueueWorker:
         # the historical non-donating launch.
         outs = graph.launch_prefix(batch.inputs, queue=self.queue,
                                    donate=batch.donate)
-        fused, energy = graph.fused_modeled()   # memoized: launch-invariant
-        return outs, apply_spike(fused, spike_s), energy
+        fused, _ = graph.fused_modeled()   # memoized: launch-invariant
+        return outs, apply_spike(fused, spike_s)
 
     def launch(self, graph: CommandGraph, batch: MicroBatch,
                t_now: Optional[float] = None
@@ -289,29 +210,16 @@ class QueueWorker:
             self.backpressure_stalls += 1
             retired.append(self._retire_oldest())
         try:
-            outs, fused, energy = self._do_launch(graph, batch)
+            outs, fused = self._do_launch(graph, batch)
         except InjectedFault as e:
             e.retired = tuple(retired)
             raise
         t_now = self.clock() if t_now is None else t_now
-        if (self.power_budget is not None
-                and self.power_budget.lane_mw is not None):
-            # the enforcement invariant's audit hook (ISSUE 8): re-price the
-            # launch actually being booked — post-backpressure, spike
-            # included — against the lane cap.  The dispatcher's pre-launch
-            # pricing upper-bounds this, so the counter stays 0 whenever
-            # routing enforced the budget; a non-zero count means a request
-            # executed over budget (gated to zero by the hypothesis sweep).
-            booked = self.price(fused, energy, t_now,
-                                n_requests=batch.n_requests)
-            if not self.power_budget.lane_ok(booked.avg_power_w):
-                self.budget_violations += 1
         start = max(t_now, self.modeled_busy_until)
         t_done_modeled = start + (fused.total_s if fused is not None else 0.0)
         self.modeled_busy_until = t_done_modeled
         ticket = LaunchTicket(batch=batch, outputs=outs, worker=self,
-                              fused=fused, energy_j=energy,
-                              t_launch=t_now,
+                              fused=fused, t_launch=t_now,
                               n_events=len(graph.nodes),
                               t_done_modeled=t_done_modeled)
         self._inflight.append(ticket)
@@ -320,7 +228,6 @@ class QueueWorker:
         self.n_requests += batch.n_requests
         if fused is not None:
             self.modeled_s += fused.total_s
-        self.energy_j += energy
         if self.tracer is not None:
             self._trace_launch(graph, batch, start, t_done_modeled, fused)
         return ticket, retired
@@ -405,12 +312,10 @@ class QueueWorker:
         return QueueStats(
             name=self.name, config=self.apu.egpu.config.name,
             batches=self.n_batches, requests=self.n_requests,
-            modeled_s=self.modeled_s, energy_j=self.energy_j,
+            modeled_s=self.modeled_s,
             peak_in_flight=self.peak_in_flight,
             backpressure_stalls=self.backpressure_stalls,
-            launch_failures=self.launch_failures,
-            idle_power_w=self.idle_power_w,
-            budget_violations=self.budget_violations)
+            launch_failures=self.launch_failures)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -422,7 +327,6 @@ class QueueStats:
     batches: int
     requests: int
     modeled_s: float
-    energy_j: float
     peak_in_flight: int
     backpressure_stalls: int
     #: mesh lane width: total devices this worker's launches span (1 for a
@@ -440,12 +344,6 @@ class QueueStats:
     breaker_state: str = "closed"
     #: times this lane's breaker tripped OPEN (quarantines)
     breaker_trips: int = 0
-    #: clock-gated leakage floor of this lane, watts (ISSUE 8) — the serve
-    #: report integrates it over the lane's idle modeled time
-    idle_power_w: float = 0.0
-    #: launches whose booked window-average power broke the lane cap
-    #: (stays 0 while the dispatcher enforces the budget)
-    budget_violations: int = 0
 
     def publish_metrics(self, registry) -> None:
         """Publish this lane's totals into a
@@ -469,19 +367,11 @@ class QueueStats:
         g = registry.gauge
         g("repro_lane_modeled_seconds",
           "modeled seconds served per lane").set(self.modeled_s, **labels)
-        g("repro_lane_energy_joules",
-          "modeled energy per lane").set(self.energy_j, **labels)
         g("repro_lane_peak_in_flight",
           "peak in-flight depth per lane").set(self.peak_in_flight, **labels)
         g("repro_lane_breaker_open",
           "1 when the lane's breaker is OPEN").set(
             1.0 if self.breaker_state == "open" else 0.0, **labels)
-        g("repro_lane_idle_power_watts",
-          "clock-gated leakage floor per lane").set(
-            self.idle_power_w, **labels)
-        c("repro_lane_budget_violations_total",
-          "launches booked over the lane power cap").set_total(
-            self.budget_violations, **labels)
 
 
 class CircuitBreaker:
@@ -561,16 +451,6 @@ class MultiQueueDispatcher:
     re-admit them via half-open probes after ``breaker_cooldown`` dispatch
     ticks.  A batch that exhausts every retry raises
     :class:`DispatchError` so the server can shed it loudly.
-
-    Power budgets (ISSUE 8): built with ``budget=``\\
-    :class:`~repro.serve.power.PowerBudget`, routing switches to
-    :meth:`_pick_powered` — every candidate lane is priced (modeled
-    latency, window-average power over the launch window), over-cap lanes
-    are throttled, budget-eligible ones compete on requests-per-joule, and
-    a batch no lane can take on-budget raises :class:`PowerBudgetError`
-    (a :class:`DispatchError`, so the server's loud-shed path applies).
-    All pricing is on the modeled virtual timeline — deterministic, never
-    wall clock.
     """
 
     def __init__(self, workers: Sequence[QueueWorker],
@@ -578,8 +458,7 @@ class MultiQueueDispatcher:
                  max_attempts: Optional[int] = None,
                  backoff_base_s: float = 0.001,
                  backoff_cap_s: float = 0.05,
-                 tracer: Optional[Tracer] = None,
-                 budget: Optional[PowerBudget] = None):
+                 tracer: Optional[Tracer] = None):
         if not workers:
             raise ValueError("need at least one QueueWorker")
         names = [w.name for w in workers]
@@ -596,18 +475,9 @@ class MultiQueueDispatcher:
         self.backoff_cap_s = backoff_cap_s
         #: opt-in span tracer (ISSUE 7); guarded at every hook
         self.tracer = tracer
-        #: fleet power budget (ISSUE 8); ``None`` keeps the historical
-        #: latency-greedy routing with zero pricing overhead
-        self.budget = budget
-        if budget is not None:
-            for w in self.workers:
-                w.power_budget = budget
         self._tick = 0                   # dispatch calls (breaker clock)
         self.retries = 0                 # failed attempts that were rerouted
         self.dispatch_failures = 0       # batches that exhausted every retry
-        self.power_throttles = 0         # lane candidates skipped for power
-        self.power_sheds = 0             # batches no lane could take on-budget
-        self.peak_fleet_power_w = 0.0    # max modeled fleet draw sampled
 
     @staticmethod
     def _route_key(w: QueueWorker) -> Tuple[float, int, float, int]:
@@ -641,78 +511,20 @@ class MultiQueueDispatcher:
             candidates = self.workers
         return min(candidates, key=self._route_key)
 
-    # -- power-aware routing (ISSUE 8) --------------------------------------
-    def fleet_power_w(self, t_now: float) -> float:
-        """Modeled instantaneous fleet draw: busy lanes at their remaining
-        window-average power, idle lanes at their clock-gated leakage
-        floor."""
-        return sum(w.current_power_w(t_now) for w in self.workers)
-
-    def _pick_powered(self, batch: MicroBatch,
-                      estimator: Callable[[QueueWorker],
-                                          Tuple[Optional[PhaseBreakdown],
-                                                float]],
-                      t_now: float,
-                      exclude: Sequence[str]) -> Optional[QueueWorker]:
-        """Budget-aware routing: price every candidate lane — (modeled
-        latency, window-average power) — and return the best
-        requests-per-joule among budget-eligible ones, breaking ties on
-        the shorter window and then the classic depth route key.  Lanes
-        whose window price breaks the lane cap, or would push the modeled
-        fleet draw over the fleet cap, are throttled (skipped and
-        counted).  Returns ``None`` when no candidate can take the batch
-        on-budget — the caller sheds loudly."""
-        excluded: Set[str] = set(exclude)
-        candidates = [w for w in self.available_workers()
-                      if w.name not in excluded]
-        if not candidates:
-            candidates = [w for w in self.workers if w.name not in excluded]
-        if not candidates:
-            candidates = self.workers
-        fleet_now = self.fleet_power_w(t_now)
-        best, best_key = None, None
-        for w in candidates:
-            fused, energy = estimator(w)
-            price = w.price(fused, energy, t_now,
-                            n_requests=batch.n_requests)
-            fleet_with = (fleet_now - w.current_power_w(t_now)
-                          + price.avg_power_w)
-            if not (self.budget.lane_ok(price.avg_power_w)
-                    and self.budget.fleet_ok(fleet_with)):
-                self.power_throttles += 1
-                if self.tracer is not None:
-                    self.tracer.instant(
-                        f"lane:{w.name}", t_now, "power-throttle",
-                        avg_power_w=price.avg_power_w,
-                        fleet_power_w=fleet_with)
-                continue
-            key = (-price.requests_per_joule, price.window_s,
-                   self._route_key(w))
-            if best is None or key < best_key:
-                best, best_key = w, key
-        return best
-
     def dispatch(self, batch: MicroBatch,
                  graph_for: Callable[[QueueWorker], CommandGraph],
-                 t_now: Optional[float] = None,
-                 estimate_for: Optional[
-                     Callable[[QueueWorker],
-                              Tuple[Optional[PhaseBreakdown], float]]] = None
+                 t_now: Optional[float] = None
                  ) -> Tuple[LaunchTicket, List[LaunchTicket]]:
         """Launch ``batch`` with retry + quarantine (the fault-tolerant
         front the server uses).
 
         ``graph_for(worker)`` supplies the worker's cached graph (graphs
         are per-APU/placement, so the cache lookup happens per attempt).
-        With a :class:`PowerBudget` installed, ``estimate_for(worker)``
-        (defaulting to ``worker.estimate(graph_for(worker))``) supplies
-        the pricing view and routing goes through :meth:`_pick_powered`;
-        a batch no lane can take on-budget raises
-        :class:`PowerBudgetError`.  Returns the successful ticket plus
-        every ticket retired for backpressure along the way — including
-        by failed attempts.  Raises :class:`DispatchError` (carrying
-        those retired tickets) when the attempt budget is exhausted.
-        The whole call is one ``dispatch.launch`` profiler span.
+        Returns the successful ticket plus every ticket retired for
+        backpressure along the way — including by failed attempts.  Raises
+        :class:`DispatchError` (carrying those retired tickets) when the
+        attempt budget is exhausted.  The whole call is one
+        ``dispatch.launch`` profiler span.
         """
         with span("dispatch.launch", n=batch.n_requests) as sp:
             self._tick += 1
@@ -722,30 +534,7 @@ class MultiQueueDispatcher:
             tried: Set[str] = set()
             last: Optional[InjectedFault] = None
             for attempt in range(cap):
-                if self.budget is None:
-                    worker = self.pick(exclude=tried)
-                else:
-                    t_ref = (t_now if t_now is not None
-                             else self.workers[0].clock())
-                    est = (estimate_for if estimate_for is not None
-                           else lambda w: w.estimate(graph_for(w)))
-                    picked = self._pick_powered(batch, est, t_ref, tried)
-                    if picked is None:
-                        self.power_sheds += 1
-                        fleet_mw = self.fleet_power_w(t_ref) * 1e3
-                        if self.tracer is not None:
-                            for req in batch.requests:
-                                self.tracer.request_event(
-                                    req.rid, t_ref, "power-shed",
-                                    fleet_power_mw=fleet_mw)
-                        raise PowerBudgetError(
-                            f"power budget (lane {self.budget.lane_mw} mW, "
-                            f"fleet {self.budget.fleet_mw} mW) leaves no lane "
-                            f"for a micro-batch of {batch.n_requests} "
-                            f"request(s): modeled fleet draw "
-                            f"{fleet_mw:.2f} mW",
-                            retired=retired_all)
-                    worker = picked
+                worker = self.pick(exclude=tried)
                 breaker = self.breakers[worker.name]
                 breaker.on_attempt()
                 sp.set_metadata(lane=worker.name)
@@ -799,11 +588,6 @@ class MultiQueueDispatcher:
                     continue
                 breaker.record_success()
                 retired_all.extend(retired)
-                if self.budget is not None:
-                    # sample the modeled fleet draw with the new launch booked
-                    t_ref = t_now if t_now is not None else worker.clock()
-                    self.peak_fleet_power_w = max(self.peak_fleet_power_w,
-                                                  self.fleet_power_w(t_ref))
                 return ticket, retired_all
             self.dispatch_failures += 1
             raise DispatchError(

@@ -25,11 +25,6 @@ Engine invariants (pinned by ``tests/test_decode_serve.py``):
   position); decode under staggered arrival is bit-identical to whole-batch
   :func:`~repro.train.serve.greedy_generate` for every cache family (plain
   KV, MLA latent, rwkv6 O(1) state).
-- **Honest accounting.**  The bytes-per-step roofline
-  (:func:`engine_roofline`) is summed off the captured schedule's
-  :class:`~repro.core.runtime.GraphNode` counts — the
-  :class:`~repro.core.machine.WorkCounts` each node was actually priced
-  with — never re-derived on the side.
 - **No full-vocabulary output rides the step graph.**  The decode kernel
   takes the greedy argmax inside the step;
   :meth:`DecodeEngine.decode_graph`'s out avals carry tokens + cache (and
@@ -84,22 +79,17 @@ def batch_axes(cfg: ModelConfig):
 
 
 def _engine_counts(*, batch: int, params_bytes: float, cache_bytes: float,
-                   write_bytes: float, ops: float, io_bytes: float,
-                   resident: bool = True) -> WorkCounts:
-    """First-order structural work of one engine step (or prefill).
-
-    ``resident=True`` is the engine's captured-state contract: only token /
-    position I/O crosses the host bus, the params + cache stream through
-    the D$ hierarchy.  ``resident=False`` models the naive
-    rebatch-per-step baseline that round-trips the whole cache through the
-    host every token (out + back in) — the bench's comparison arm.
-    """
-    host = float(io_bytes) + (0.0 if resident else 2.0 * float(cache_bytes))
+                   write_bytes: float, ops: float,
+                   io_bytes: float) -> WorkCounts:
+    """First-order structural work of one engine step (or prefill): the
+    decode state stays on the lane, so only token / position I/O crosses
+    the host bus while the params + cache stream through the D$
+    hierarchy."""
     return WorkCounts(
         ops=float(ops),
         dcache_bytes=float(params_bytes) + float(cache_bytes)
         + float(write_bytes),
-        host_bytes=host,
+        host_bytes=float(io_bytes),
         working_set=float(params_bytes) + float(cache_bytes))
 
 
@@ -196,8 +186,6 @@ class Prefix:
     pos: int                             # next decode position (= prompt len)
     prompt_len: int
     rid: Optional[int] = None            # server request id (None standalone)
-    modeled_s: float = 0.0               # fused modeled prefill latency
-    energy_j: float = 0.0
 
 
 @dataclasses.dataclass
@@ -228,61 +216,6 @@ class DecodeState:
         return [i for i, o in enumerate(self.occupied) if not o]
 
 
-@dataclasses.dataclass(frozen=True)
-class EngineRoofline:
-    """Memory-bandwidth roofline of ONE captured generate step, summed off
-    the schedule's :class:`~repro.core.runtime.GraphNode` counts."""
-
-    dcache_bytes: float                  # core <-> D$ traffic per step
-    host_bytes: float                    # counts-level host traffic per step
-    transfer_bytes: float                # explicit transfer-node bytes
-    dcache_bw_bytes_per_s: float         # line width x CUs x clock
-    modeled_step_s: float                # fused modeled latency of the step
-
-    @property
-    def bytes_per_step(self) -> float:
-        return self.dcache_bytes + self.host_bytes + self.transfer_bytes
-
-    @property
-    def min_step_s(self) -> float:
-        """Bandwidth-bound floor: D$ traffic over D$ bandwidth."""
-        if self.dcache_bw_bytes_per_s <= 0.0:
-            return 0.0
-        return self.dcache_bytes / self.dcache_bw_bytes_per_s
-
-    @property
-    def mem_bound_fraction(self) -> float:
-        """How much of the modeled step the bandwidth floor explains
-        (→ 1.0 when decode is purely memory-bound, as AR decode is)."""
-        if self.modeled_step_s <= 0.0:
-            return 0.0
-        return min(1.0, self.min_step_s / self.modeled_step_s)
-
-
-def graph_traffic(graph: CommandGraph) -> Tuple[float, float, float]:
-    """(dcache, host, transfer) bytes of one launch, read straight off the
-    captured schedule — each kernel node carries the WorkCounts it was
-    priced with, transfer nodes their payload size."""
-    dcache = host = moved = 0.0
-    for n in graph.nodes:
-        if n.counts is not None:
-            dcache += n.counts.dcache_bytes
-            host += n.counts.host_bytes
-        moved += n.nbytes
-    return dcache, host, moved
-
-
-def engine_roofline(graph: CommandGraph, config: EGPUConfig
-                    ) -> EngineRoofline:
-    dcache, host, moved = graph_traffic(graph)
-    fused, _ = graph.fused_modeled()
-    bw = (config.dcache_line_bytes * config.compute_units * config.freq_hz)
-    return EngineRoofline(
-        dcache_bytes=dcache, host_bytes=host, transfer_bytes=moved,
-        dcache_bw_bytes_per_s=float(bw),
-        modeled_step_s=fused.total_s if fused is not None else 0.0)
-
-
 class DecodeEngine:
     """Continuous-batching decode on one :class:`QueueWorker` lane.
 
@@ -295,8 +228,7 @@ class DecodeEngine:
 
     The worker must capture WITHOUT explicit transfers: the decode state is
     resident — donated back to each launch, never round-tripped — and the
-    counts model prices exactly token/position I/O as host traffic
-    (``resident=False`` builds the naive baseline arm for the bench).
+    counts model prices exactly token/position I/O as host traffic.
 
     Donation discipline: donated inputs are consumed by XLA, so every
     launch realizes its token output and retires (drains) before the next
@@ -309,7 +241,6 @@ class DecodeEngine:
                  worker: Optional[QueueWorker] = None,
                  cache: Optional[GraphCache] = None,
                  cache_dtype: Any = jnp.bfloat16,
-                 resident: bool = True,
                  tracer: Optional[Tracer] = None,
                  clock: Callable[[], float] = time.perf_counter,
                  name: str = "engine"):
@@ -322,7 +253,6 @@ class DecodeEngine:
         self.num_slots = num_slots
         self.max_len = max_len
         self.cache_dtype = jnp.dtype(cache_dtype)
-        self.resident = resident
         self.name = name
         self.worker = worker if worker is not None else QueueWorker(
             config, name=name, max_in_flight=1, explicit_transfers=False,
@@ -361,14 +291,11 @@ class DecodeEngine:
         #: the captured per-step graph (None until the first generate) —
         #: tests pin the no-(B, vocab)-output invariant on its out_avals
         self.decode_graph: Optional[CommandGraph] = None
-        # accounting (all modeled / machine-model virtual time)
+        # accounting
         self.n_prefills = 0
         self.n_inserts = 0
         self.n_steps = 0
         self.n_tokens = 0                # tokens emitted from occupied slots
-        self.prefill_modeled_s = 0.0
-        self.decode_modeled_s = 0.0
-        self.energy_j = 0.0
         self._occupancy_sum = 0.0
         # MoE: each launch's routed experts, counted on the host after the
         # readback the launch already makes
@@ -456,8 +383,7 @@ class DecodeEngine:
             cache_bytes=self._slot_cache_bytes * b,
             write_bytes=self._slot_write_bytes * b,
             ops=self._param_elems * b,
-            io_bytes=float(3 * b * _TOKEN_BYTES),   # tokens+pos in, tokens out
-            resident=self.resident)
+            io_bytes=float(3 * b * _TOKEN_BYTES))   # tokens+pos in, tokens out
 
     def _prefill_counts_params(self, prompt_len: int) -> Dict[str, Any]:
         return dict(
@@ -466,8 +392,7 @@ class DecodeEngine:
             cache_bytes=self._slot_cache_bytes,
             write_bytes=self._slot_write_bytes * prompt_len,
             ops=self._param_elems * prompt_len,
-            io_bytes=float(prompt_len * _TOKEN_BYTES + _TOKEN_BYTES),
-            resident=self.resident)
+            io_bytes=float(prompt_len * _TOKEN_BYTES + _TOKEN_BYTES))
 
     # -- graphs -------------------------------------------------------------
     def _prefill_graph(self, prompt: jax.Array) -> CommandGraph:
@@ -573,12 +498,8 @@ class DecodeEngine:
             if experts is not None:
                 self._count_moe(np.asarray(jax.device_get(experts)))
             self.worker.drain()
-            modeled = ticket.modeled_latency_s or 0.0
             self.n_prefills += 1
-            self.prefill_modeled_s += modeled
-            self.energy_j += ticket.energy_j
-            return Prefix(token=tok, cache=cache, pos=s, prompt_len=s, rid=rid,
-                          modeled_s=modeled, energy_j=ticket.energy_j)
+            return Prefix(token=tok, cache=cache, pos=s, prompt_len=s, rid=rid)
 
     def insert(self, prefix: Prefix, state: DecodeState,
                slot: int) -> DecodeState:
@@ -655,11 +576,8 @@ class DecodeEngine:
             state.positions = state.positions + 1
             state.cache = jax.tree_util.tree_unflatten(
                 jax.tree_util.tree_structure(self._bidx), new_leaves)
-            modeled = ticket.modeled_latency_s or 0.0
             self.n_steps += 1
             self.n_tokens += occ
-            self.decode_modeled_s += modeled
-            self.energy_j += ticket.energy_j
             self._occupancy_sum += occ / self.num_slots
             return state, tokens_np
 
@@ -669,36 +587,14 @@ class DecodeEngine:
         """Mean occupied-slot fraction across generate steps."""
         return self._occupancy_sum / self.n_steps if self.n_steps else 0.0
 
-    @property
-    def tokens_per_s_modeled(self) -> float:
-        """Steady-state decode throughput on the machine-model timeline."""
-        if self.decode_modeled_s <= 0.0:
-            return 0.0
-        return self.n_tokens / self.decode_modeled_s
-
-    def roofline(self) -> Optional[EngineRoofline]:
-        """Bytes/step roofline of the captured generate graph (None before
-        the first step)."""
-        if self.decode_graph is None:
-            return None
-        return engine_roofline(self.decode_graph, self.config)
-
     def stats(self) -> Dict[str, float]:
-        ro = self.roofline()
         return {
             "num_slots": self.num_slots,
             "n_prefills": self.n_prefills,
             "n_inserts": self.n_inserts,
             "n_steps": self.n_steps,
             "n_tokens": self.n_tokens,
-            "prefill_modeled_s": self.prefill_modeled_s,
-            "decode_modeled_s": self.decode_modeled_s,
-            "energy_j": self.energy_j,
             "occupancy": self.occupancy,
-            "tokens_per_s_modeled": self.tokens_per_s_modeled,
-            "bytes_per_step": ro.bytes_per_step if ro is not None else 0.0,
-            "mem_bound_fraction": (ro.mem_bound_fraction
-                                   if ro is not None else 0.0),
         }
 
     def publish_metrics(self, registry) -> None:
@@ -714,9 +610,6 @@ class DecodeEngine:
                        "decode-engine slot width").set(self.num_slots)
         registry.gauge("repro_engine_occupancy",
                        "mean occupied-slot fraction").set(self.occupancy)
-        registry.gauge("repro_engine_tokens_per_s_modeled",
-                       "modeled steady-state decode throughput").set(
-            self.tokens_per_s_modeled)
         if self._moe:
             moe = registry.counter(
                 "repro_moe_events_total",
@@ -725,11 +618,3 @@ class DecodeEngine:
             moe.set_total(self.moe_rows_routed, kind="rows_routed")
             moe.set_total(self.moe_rows_computed, kind="rows_computed")
             moe.set_total(self.moe_experts_touched, kind="experts_touched")
-        ro = self.roofline()
-        if ro is not None:
-            registry.gauge("repro_engine_bytes_per_step",
-                           "modeled traffic of one generate step").set(
-                ro.bytes_per_step)
-            registry.gauge("repro_engine_mem_bound_fraction",
-                           "bandwidth-floor share of the modeled step").set(
-                ro.mem_bound_fraction)

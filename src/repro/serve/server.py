@@ -37,7 +37,7 @@ fast:
 :meth:`Server.report` rolls the per-queue machine-model accounting into a
 :class:`ServeReport`: measured requests/s, modeled per-request latency
 percentiles (each request experiences its batch's fused-chain latency),
-modeled energy per request, and the robustness counters — goodput
+and the robustness counters — goodput
 (in-deadline completions/s, measured and modeled), sheds, deadline
 violations, retries and quarantines.
 """
@@ -58,15 +58,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.apu import Stage
-from ..core.device import EGPUConfig, EGPU_16T, OP_ANCHOR, env_op_point
+from ..core.device import EGPUConfig, EGPU_16T
 from ..obs import MetricsRegistry, Tracer
 from ..obs.profiler import span
 from .batching import BucketBatcher, MicroBatch, batched_stages
 from .cache import GraphCache, stages_signature
 from .dispatch import (DispatchError, LaunchTicket, MultiQueueDispatcher,
-                       PowerBudgetError, QueueStats, QueueWorker)
+                       QueueStats, QueueWorker)
 from .faults import FaultPlan, InjectedFault
-from .power import PowerBudget
 
 PERCENTILES = (50, 90, 99)
 
@@ -105,7 +104,6 @@ class ServeReport:
     #: mean amortized cost per request (batch fused time / live requests) —
     #: the throughput view of the same launches
     modeled_cost_per_request_s: float
-    modeled_energy_per_request_j: float
     avg_batch_fill: float              # live requests / batch capacity
     padded_elements: int               # elements added purely by padding
     queues: Tuple[QueueStats, ...]
@@ -148,53 +146,13 @@ class ServeReport:
     #: :data:`DECOMP_PHASES`); empty before any profiled completion
     latency_decomposition_s: Dict[str, Dict[int, float]] = \
         dataclasses.field(default_factory=dict)
-    # -- power & energy accounting (ISSUE 8) --------------------------------
-    #: modeled average fleet power over the serving makespan:
-    #: ``fleet_energy_j / makespan``; 0.0 before any modeled launch
-    avg_fleet_power_w: float = 0.0
-    #: peak modeled instantaneous fleet draw, sampled at every budgeted
-    #: launch (0.0 when serving uncapped — nothing samples it)
-    peak_fleet_power_w: float = 0.0
-    #: idle-lane leakage integrated over the modeled makespan — each lane
-    #: burns its clock-gated floor (§IV SLEEP_REQ) whenever it is not
-    #: serving, energy the active-only ledger used to omit
-    fleet_idle_energy_j: float = 0.0
-    #: honest fleet energy: active launch energy + idle-lane leakage
-    fleet_energy_j: float = 0.0
-    #: completed requests per modeled second per watt of modeled fleet
-    #: draw — algebraically, requests per joule of ``fleet_energy_j``
-    requests_per_s_per_watt: float = 0.0
-    #: in-deadline completions per modeled second per watt — the
-    #: ``bench=power`` gate's goodput-per-watt number
-    goodput_per_s_per_watt: float = 0.0
-    #: requests shed because no lane could take them on-budget
-    n_power_shed: int = 0
-    #: candidate lanes skipped during routing for a budget breach
-    n_power_throttled: int = 0
-    #: launches whose booked window-average power broke the lane cap —
-    #: MUST stay 0 while the dispatcher enforces the budget (hypothesis-
-    #: swept in tests/test_power_serve.py)
-    n_budget_violations: int = 0
-    #: the configured caps (mW), ``None`` when serving uncapped
-    power_budget_lane_mw: Optional[float] = None
-    power_budget_fleet_mw: Optional[float] = None
     # -- continuous-batching decode engine (ISSUE 9) ------------------------
     #: generate steps launched (each ONE cached-graph launch over all slots)
     engine_steps: int = 0
     #: tokens emitted from occupied slots across those steps
     engine_tokens: int = 0
-    #: modeled time split: prompt passes vs autoregressive generate steps
-    engine_prefill_s_modeled: float = 0.0
-    engine_decode_s_modeled: float = 0.0
-    #: steady-state decode throughput, tokens per modeled second
-    engine_tokens_per_s_modeled: float = 0.0
     #: mean occupied-slot fraction across generate steps
     engine_slot_occupancy: float = 0.0
-    #: modeled traffic of ONE captured generate step (bytes, summed off the
-    #: captured schedule's per-node WorkCounts — the roofline numerator)
-    engine_bytes_per_step: float = 0.0
-    #: share of the modeled step the D$-bandwidth floor explains
-    engine_mem_bound_fraction: float = 0.0
     # -- capture-time graph sanitizer (ISSUE 10, repro.analyze) -------------
     #: fresh captures statically verified at GraphCache miss time (a warm
     #: server replays verified graphs and never re-verifies)
@@ -245,36 +203,6 @@ class ServeReport:
             self.goodput_per_s_modeled)
         g("repro_serve_batch_fill_ratio",
           "live requests / batch capacity").set(self.avg_batch_fill)
-        g("repro_serve_energy_per_request_joules",
-          "modeled energy per request").set(
-            self.modeled_energy_per_request_j)
-        # power telemetry (ISSUE 8)
-        g("repro_fleet_avg_power_watts",
-          "modeled average fleet power over the makespan").set(
-            self.avg_fleet_power_w)
-        g("repro_fleet_peak_power_watts",
-          "peak modeled instantaneous fleet draw").set(
-            self.peak_fleet_power_w)
-        g("repro_fleet_energy_joules",
-          "fleet energy incl. idle leakage").set(self.fleet_energy_j)
-        g("repro_fleet_idle_energy_joules",
-          "idle-lane leakage over the makespan").set(
-            self.fleet_idle_energy_j)
-        g("repro_serve_requests_per_second_per_watt",
-          "completed requests per modeled second per watt").set(
-            self.requests_per_s_per_watt)
-        g("repro_serve_goodput_per_second_per_watt",
-          "in-deadline completions per modeled second per watt").set(
-            self.goodput_per_s_per_watt)
-        c("repro_serve_power_shed_total",
-          "requests shed because no lane had power headroom").set_total(
-            self.n_power_shed)
-        c("repro_serve_power_throttled_total",
-          "lane candidates skipped for a budget breach").set_total(
-            self.n_power_throttled)
-        c("repro_serve_budget_violations_total",
-          "launches booked over the lane power cap").set_total(
-            self.n_budget_violations)
         lat = g("repro_serve_modeled_latency_seconds",
                 "modeled request latency percentiles")
         for p, v in self.modeled_latency_s.items():
@@ -293,15 +221,6 @@ class ServeReport:
             ec.set_total(self.engine_tokens, kind="tokens")
             g("repro_engine_occupancy",
               "mean occupied-slot fraction").set(self.engine_slot_occupancy)
-            g("repro_engine_tokens_per_s_modeled",
-              "modeled steady-state decode throughput").set(
-                self.engine_tokens_per_s_modeled)
-            g("repro_engine_bytes_per_step",
-              "modeled traffic of one generate step").set(
-                self.engine_bytes_per_step)
-            g("repro_engine_mem_bound_fraction",
-              "bandwidth-floor share of the modeled step").set(
-                self.engine_mem_bound_fraction)
         # same series GraphCache.publish_metrics writes — set_total is
         # idempotent, so publishing a report over a live cache never skews
         cache = registry.counter("repro_graph_cache_events_total",
@@ -329,8 +248,7 @@ class ServeReport:
                 f"p{p} {self.modeled_latency_s[p] * 1e3:.3f} ms"
                 for p in sorted(self.modeled_latency_s)),
             f"modeled cost    {self.modeled_cost_per_request_s * 1e3:.3f} "
-            f"ms/request amortized, "
-            f"{self.modeled_energy_per_request_j * 1e6:.2f} uJ/request",
+            f"ms/request amortized",
             f"graph cache     {self.cache['hits']} hits / "
             f"{self.cache['misses']} misses / "
             f"{self.cache['evictions']} evictions "
@@ -350,12 +268,7 @@ class ServeReport:
             lines.append(
                 f"engine          {self.engine_tokens} tokens in "
                 f"{self.engine_steps} steps "
-                f"(occupancy {self.engine_slot_occupancy:.0%})  "
-                f"{self.engine_tokens_per_s_modeled:,.0f} tok/s modeled  "
-                f"prefill {self.engine_prefill_s_modeled * 1e3:.3f} ms / "
-                f"decode {self.engine_decode_s_modeled * 1e3:.3f} ms  "
-                f"{self.engine_bytes_per_step:,.0f} B/step "
-                f"({self.engine_mem_bound_fraction:.0%} mem-bound)")
+                f"(occupancy {self.engine_slot_occupancy:.0%})")
         if (self.n_shed or self.n_deadline_violations
                 or self.deadline_flushes):
             lines.append(
@@ -364,27 +277,6 @@ class ServeReport:
                 f"{self.n_shed} shed  "
                 f"{self.n_deadline_violations} deadline misses  "
                 f"{self.deadline_flushes} deadline flushes")
-        if self.fleet_energy_j > 0.0:
-            budget = ""
-            if (self.power_budget_lane_mw is not None
-                    or self.power_budget_fleet_mw is not None):
-                caps = [f"lane<={self.power_budget_lane_mw:g} mW"
-                        if self.power_budget_lane_mw is not None else "",
-                        f"fleet<={self.power_budget_fleet_mw:g} mW"
-                        if self.power_budget_fleet_mw is not None else ""]
-                budget = "  budget " + " ".join(cp for cp in caps if cp)
-            lines.append(
-                f"power           avg {self.avg_fleet_power_w * 1e3:.2f} mW "
-                f"(peak {self.peak_fleet_power_w * 1e3:.2f} mW)  "
-                f"energy {self.fleet_energy_j * 1e6:.1f} uJ "
-                f"(idle {self.fleet_idle_energy_j * 1e6:.1f} uJ)  "
-                f"goodput/W {self.goodput_per_s_per_watt:,.0f}" + budget)
-        if (self.n_power_shed or self.n_power_throttled
-                or self.n_budget_violations):
-            lines.append(
-                f"power events    {self.n_power_shed} power sheds  "
-                f"{self.n_power_throttled} throttles  "
-                f"{self.n_budget_violations} budget violations")
         if (self.n_retries or self.n_quarantines
                 or self.n_dispatch_failures):
             lines.append(
@@ -408,7 +300,7 @@ class ServeReport:
             lines.append(
                 f"  queue {qs.name:12s} {qs.batches:4d} batches "
                 f"{qs.requests:5d} reqs  modeled {qs.modeled_s * 1e3:8.2f} ms "
-                f"{qs.energy_j * 1e6:8.1f} uJ  peak in-flight "
+                f"peak in-flight "
                 f"{qs.peak_in_flight} ({qs.backpressure_stalls} stalls)"
                 + mesh + breaker)
         return "\n".join(lines)
@@ -458,29 +350,18 @@ class Server:
                  breaker_threshold: int = 3, breaker_cooldown: int = 8,
                  clock: Callable[[], float] = time.perf_counter,
                  tracer: Optional[Tracer] = None,
-                 power_budget: Optional[PowerBudget] = None,
                  engine: Optional["DecodeEngine"] = None):
         self.stages = tuple(stages)
         self.clock = clock
         self.max_pending = max_pending
         self.admission = admission
         self.deadline_flush = deadline_flush
-        #: power envelope (ISSUE 8): when set, the dispatcher prices every
-        #: candidate lane and routes for requests-per-joule under the caps
-        self.power_budget = power_budget
-        self._n_power_shed = 0
         #: opt-in span tracer (ISSUE 7), installed on the dispatcher and
         #: every lane; ``None`` (the default) keeps the hot dispatch path
         #: free of any obs allocation — every hook guards on it
         self.tracer = tracer
         self.batcher = BucketBatcher(bucket_sizes, max_batch=max_batch,
                                      fill=fill, crop_outputs=crop_outputs)
-        # REPRO_OP_POINT (ISSUE 8): rebase anchor-point config presets onto
-        # the environment's DVFS operating point — outputs must stay
-        # bit-identical across op points (CI re-runs the serve suite under
-        # it), only modeled time/power move.  Pre-built workers and configs
-        # already rebased via ``config.at(point)`` keep their chosen point.
-        point = env_op_point()
         lanes = []
         for i, w in enumerate(workers):
             if isinstance(w, QueueWorker):
@@ -492,10 +373,8 @@ class Server:
                     w.tracer = tracer
                 lanes.append(w)
             else:
-                cfg = (w.at(point) if point is not None
-                       and w.operating_point is OP_ANCHOR else w)
                 lanes.append(QueueWorker(
-                    cfg, name=f"{i}:{w.name}", max_in_flight=max_in_flight,
+                    w, name=f"{i}:{w.name}", max_in_flight=max_in_flight,
                     fault_plan=fault_plan, clock=clock, tracer=tracer))
         if not lanes and engine is not None:
             # engine-only server: the engine's lane doubles as the (unused)
@@ -503,8 +382,7 @@ class Server:
             lanes = [engine.worker]
         self.dispatcher = MultiQueueDispatcher(
             lanes, failure_threshold=breaker_threshold,
-            breaker_cooldown=breaker_cooldown, tracer=tracer,
-            budget=power_budget)
+            breaker_cooldown=breaker_cooldown, tracer=tracer)
         self.cache = GraphCache(cache_capacity)
         # Every micro-batch is padded to max_batch, so ONE batched pipeline
         # covers all traffic; its (const-hashing) signature is computed once
@@ -534,7 +412,6 @@ class Server:
         # memory is O(window), matching the O(in-flight) queue contract.
         self._modeled_latency: Deque[float] = deque(maxlen=metrics_window)
         self._modeled_cost: Deque[float] = deque(maxlen=metrics_window)
-        self._modeled_energy: Deque[float] = deque(maxlen=metrics_window)
         self._n_done = 0
         self._n_in_deadline = 0
         self._n_deadline_violations = 0
@@ -1006,35 +883,17 @@ class Server:
                             lane=worker.name)
                 return graph
 
-            # Power routing prices EVERY candidate lane, not just the
-            # chosen one — a quiet estimator keeps speculative pricing out
-            # of the request trace (graph_for emits cache events per call)
-            estimate_for = None
-            if self.dispatcher.budget is not None:
-                def estimate_for(worker: QueueWorker,
-                                 batch: MicroBatch = batch):
-                    graph, _hit = self.cache.get_or_capture(
-                        worker.apu, self._bstages, batch.inputs,
-                        key_prefix=self._bsig)
-                    return worker.estimate(graph)
             try:
                 _ticket, retired = self.dispatcher.dispatch(
-                    batch, graph_for, t_now=self.clock(),
-                    estimate_for=estimate_for)
+                    batch, graph_for, t_now=self.clock())
             except DispatchError as e:
-                # the batch exhausted every lane/retry (or, under a power
-                # budget, no lane could take it on-budget): its launches
-                # never happened, so shed every carried request LOUDLY —
-                # the backpressure-retired tickets from failed attempts
-                # were real launches and still finalize below
+                # the batch exhausted every lane/retry: its launches never
+                # happened, so shed every carried request LOUDLY — the
+                # backpressure-retired tickets from failed attempts were
+                # real launches and still finalize below
                 self._finalize(e.retired)
-                if isinstance(e, PowerBudgetError):
-                    self._n_power_shed += len(batch.requests)
-                    reason = f"power budget shed: {e}"
-                else:
-                    reason = f"dispatch failed: {e}"
                 for req in batch.requests:
-                    self._record_shed(req.rid, reason)
+                    self._record_shed(req.rid, f"dispatch failed: {e}")
                 continue
             self._finalize(retired)
 
@@ -1087,11 +946,10 @@ class Server:
                     if t.fused is not None:
                         # each request *experiences* the whole batch's fused
                         # latency; its amortized cost share (the throughput
-                        # view) and energy split across the live requests
+                        # view) splits across the live requests
                         self._modeled_latency.append(t.fused.total_s)
                         self._modeled_cost.append(
                             t.fused.scaled(1.0 / n).total_s)
-                        self._modeled_energy.append(t.energy_j / n)
                         # flame attribution: the request's end-to-end modeled
                         # latency (submit -> t_done_modeled) split by phase —
                         # the five deques always sum to it (see DECOMP_PHASES)
@@ -1138,8 +996,6 @@ class Server:
                for p in PERCENTILES}
         cost = (float(np.mean(self._modeled_cost))
                 if self._modeled_cost else 0.0)
-        energy = (float(np.mean(self._modeled_energy))
-                  if self._modeled_energy else 0.0)
         n_batches = self.batcher.n_batches
         fill = (self._n_done / (n_batches * self.batcher.max_batch)
                 if n_batches else 0.0)
@@ -1147,8 +1003,8 @@ class Server:
         if (self.engine is not None
                 and self.engine.worker not in self.dispatcher.workers):
             # the engine's lane books its launches like any dispatcher
-            # lane, so fleet power/energy roll-ups stay honest (engine-only
-            # servers already list it as the dispatch lane)
+            # lane (engine-only servers already list it as the dispatch
+            # lane)
             queues = (*queues, self.engine.worker.stats())
         # batch-weighted mean utilization per mesh axis across sharded lanes
         axis_sum: Dict[str, float] = {}
@@ -1166,28 +1022,13 @@ class Server:
                             np.asarray(self._decomp[phase], np.float64), p))
                         for p in DECOMP_PERCENTILES}
                 for phase in DECOMP_PHASES}
-        # -- power & energy (ISSUE 8): honest fleet energy over the modeled
-        # makespan — active launch energy per lane, plus each lane's
-        # clock-gated leakage floor (§IV SLEEP_REQ) for every modeled second
-        # it was NOT serving.  All derived efficiency numbers divide by the
-        # honest total, never the active-only ledger.
-        active_energy = sum(qs.energy_j for qs in queues)
-        idle_energy = (sum(max(0.0, modeled_span - qs.modeled_s)
-                           * qs.idle_power_w for qs in queues)
-                       if modeled_span > 0 else 0.0)
-        fleet_energy = active_energy + idle_energy
         engine_kwargs: Dict[str, Any] = {}
         if self.engine is not None and self.engine.n_steps:
             es = self.engine.stats()
             engine_kwargs = dict(
                 engine_steps=int(es["n_steps"]),
                 engine_tokens=int(es["n_tokens"]),
-                engine_prefill_s_modeled=es["prefill_modeled_s"],
-                engine_decode_s_modeled=es["decode_modeled_s"],
-                engine_tokens_per_s_modeled=es["tokens_per_s_modeled"],
-                engine_slot_occupancy=es["occupancy"],
-                engine_bytes_per_step=es["bytes_per_step"],
-                engine_mem_bound_fraction=es["mem_bound_fraction"])
+                engine_slot_occupancy=es["occupancy"])
         return ServeReport(
             n_requests=self._n_done,
             n_batches=n_batches,
@@ -1195,7 +1036,6 @@ class Server:
             requests_per_s=(self._n_done / wall if wall > 0 else 0.0),
             modeled_latency_s=pct,
             modeled_cost_per_request_s=cost,
-            modeled_energy_per_request_j=energy,
             avg_batch_fill=fill,
             padded_elements=self.batcher.padded_elements,
             queues=queues,
@@ -1216,22 +1056,6 @@ class Server:
             n_dispatch_failures=self.dispatcher.dispatch_failures,
             n_quarantines=self.dispatcher.quarantines(),
             latency_decomposition_s=decomp,
-            avg_fleet_power_w=(fleet_energy / modeled_span
-                               if modeled_span > 0 else 0.0),
-            peak_fleet_power_w=self.dispatcher.peak_fleet_power_w,
-            fleet_idle_energy_j=idle_energy,
-            fleet_energy_j=fleet_energy,
-            requests_per_s_per_watt=(self._n_done / fleet_energy
-                                     if fleet_energy > 0 else 0.0),
-            goodput_per_s_per_watt=(self._n_in_deadline / fleet_energy
-                                    if fleet_energy > 0 else 0.0),
-            n_power_shed=self._n_power_shed,
-            n_power_throttled=self.dispatcher.power_throttles,
-            n_budget_violations=sum(qs.budget_violations for qs in queues),
-            power_budget_lane_mw=(None if self.power_budget is None
-                                  else self.power_budget.lane_mw),
-            power_budget_fleet_mw=(None if self.power_budget is None
-                                   else self.power_budget.fleet_mw),
             **engine_kwargs,
         )
 

@@ -229,24 +229,9 @@ class ShardedWorker(QueueWorker):
         self._shard_memo[graph] = memo
         return memo
 
-    # -- power pricing (ISSUE 8) ---------------------------------------------
-    def estimate(self, graph: CommandGraph
-                 ) -> Tuple[Optional[PhaseBreakdown], float]:
-        """The dispatcher's pricing view of a launch on this mesh lane:
-        the shard-scaled breakdown :meth:`_do_launch` would book (energy
-        stays total — the same ops run, just spread over more devices, so
-        a sharded lane prices a *higher* window-average power over its
-        *shorter* window, exactly the physics a fleet budget must see)."""
-        fused, energy = graph.fused_modeled()
-        if fused is not None:
-            _in, _out, shards, _ = self.shardings_for(graph)
-            fused = shard_breakdown(fused, shards)
-        return fused, energy
-
     # -- launch --------------------------------------------------------------
     def _do_launch(self, graph: CommandGraph, batch: MicroBatch
-                   ) -> Tuple[Tuple[Buffer, ...],
-                              Optional[PhaseBreakdown], float]:
+                   ) -> Tuple[Tuple[Buffer, ...], Optional[PhaseBreakdown]]:
         # fault gate first — an injected failure fires before any real
         # sharded work, exactly like the plain-lane path
         spike_s = self._fault_gate()
@@ -259,12 +244,10 @@ class ShardedWorker(QueueWorker):
         outs = graph.launch_prefix(batch.inputs, queue=self.queue,
                                    in_shardings=in_sh, out_shardings=out_sh,
                                    per_device=per_device)
-        fused, energy = graph.fused_modeled()
+        fused, _ = graph.fused_modeled()
         if fused is not None:
             # transfer + compute split across the mesh slices; startup +
-            # scheduling paid once per launch on every slice concurrently.
-            # Energy is total work and stays unscaled — the same ops run,
-            # just spread over more devices.
+            # scheduling paid once per launch on every slice concurrently
             fused = shard_breakdown(fused, shards)
         fused = apply_spike(fused, spike_s)
         # utilization: fraction of each mesh axis this launch exploited —
@@ -273,7 +256,7 @@ class ShardedWorker(QueueWorker):
         for a, size in self._axis_sizes().items():
             self._axis_util_sum[a] += axis_factor.get(a, 1) / size
         self._util_launches += 1
-        return outs, fused, energy
+        return outs, fused
 
     def stats(self) -> QueueStats:
         base = super().stats()

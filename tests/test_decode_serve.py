@@ -134,10 +134,6 @@ def test_engine_decode_graph_carries_no_logits():
                     and aval.shape[0] == eng.num_slots
                     and aval.shape[-1] == cfg.vocab_padded), (
             f"decode step leaked a logits-shaped output {aval.shape}")
-    # roofline comes straight off the captured schedule
-    roof = eng.roofline()
-    assert roof is not None and roof.bytes_per_step > 0
-    assert 0.0 <= roof.mem_bound_fraction <= 1.0
 
 
 def test_server_engine_streaming_front():
@@ -159,7 +155,7 @@ def test_server_engine_streaming_front():
     rep = srv.report()
     # decode steps produce NEW-1 tokens/request (token 1 is prefill's)
     assert rep.engine_tokens == 3 * (NEW - 1)
-    assert rep.engine_steps > 0 and rep.engine_tokens_per_s_modeled > 0
+    assert rep.engine_steps > 0
     assert 0.0 < rep.engine_slot_occupancy <= 1.0
     assert "engine" in rep.summary()
     # every accepted rid terminates in exactly one result/shed span

@@ -9,12 +9,11 @@ stage.
 """
 
 import dataclasses
-import os
 
 import pytest
 
 from repro.core import (EGPU_4T, EGPU_8T, EGPU_16T, OP_ANCHOR,
-                        OPERATING_POINTS, OperatingPoint, env_op_point)
+                        OPERATING_POINTS, OperatingPoint)
 from repro.core.machine import PhaseBreakdown, fuse_breakdowns
 from repro.core.power import (characterize, dynamic_scale, egpu_active_power_mw,
                               egpu_energy_j, egpu_idle_power_mw, leakage_scale)
@@ -24,7 +23,7 @@ TURBO = OPERATING_POINTS["turbo"]
 
 
 # ---------------------------------------------------------------------------
-# OperatingPoint / EGPUConfig.at / env plumbing
+# OperatingPoint / EGPUConfig.at
 # ---------------------------------------------------------------------------
 def test_operating_point_table_and_anchor():
     assert OP_ANCHOR.freq_hz == 300e6 and OP_ANCHOR.voltage_v == 0.8
@@ -50,19 +49,6 @@ def test_config_at_rebases_and_validates():
         .operating_point.name == "custom"
     with pytest.raises(ValueError):
         dataclasses.replace(EGPU_16T, voltage_v=-1.0).validate()
-
-
-def test_env_op_point_parsing(monkeypatch):
-    monkeypatch.delenv("REPRO_OP_POINT", raising=False)
-    assert env_op_point() is None
-    monkeypatch.setenv("REPRO_OP_POINT", "low")
-    assert env_op_point() == LOW
-    monkeypatch.setenv("REPRO_OP_POINT", "200e6:0.7")
-    p = env_op_point()
-    assert (p.freq_hz, p.voltage_v) == (200e6, 0.7)
-    monkeypatch.setenv("REPRO_OP_POINT", "not-a-point")
-    with pytest.raises(ValueError):
-        env_op_point()
 
 
 # ---------------------------------------------------------------------------
@@ -174,11 +160,3 @@ def test_characterize_lru_does_not_alias_op_points():
             characterize(EGPU_16T.at(LOW)).total_leak_uw,
             characterize(EGPU_16T.at(TURBO)).total_leak_uw}
     assert len(seen) == 3
-
-
-def test_env_op_point_matches_direct_rebase(monkeypatch):
-    monkeypatch.setenv("REPRO_OP_POINT", "turbo")
-    assert os.environ["REPRO_OP_POINT"] == "turbo"
-    p = env_op_point()
-    assert egpu_active_power_mw(EGPU_16T.at(p)) \
-        == egpu_active_power_mw(EGPU_16T.at(TURBO))

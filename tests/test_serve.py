@@ -435,7 +435,6 @@ def test_server_warmup_precaptures_every_bucket_worker_pair():
     rep = srv.report()
     assert rep.n_requests == 12
     assert rep.modeled_latency_s[50] > 0.0
-    assert rep.modeled_energy_per_request_j > 0.0
     assert rep.cache["misses"] == 4
     assert sum(q.requests for q in rep.queues) == 12
     assert len(rep.summary()) > 0
