@@ -171,26 +171,57 @@ def cache_from_prefill(cfg: ModelConfig, k: jax.Array, v: jax.Array,
 # ---------------------------------------------------------------------------
 # Decode (one token per sequence)
 # ---------------------------------------------------------------------------
-def attend_decode(p, x: jax.Array, cache: Dict[str, jax.Array], pos,
-                  cfg: ModelConfig):
-    """x (B, 1, D) + cache at absolute position ``pos`` (scalar int32).
+def write_rows(stack: jax.Array, rows: jax.Array, layer, pos: jax.Array,
+               seq_axis: int = -2) -> jax.Array:
+    """Write each sequence's one new cache row in place.
 
-    Returns (y (B, 1, D), updated cache).  The masked-softmax reduction over
-    the cache's T axis is written so GSPMD's partial reductions implement
-    flash-decoding when T is sharded (DESIGN.md §5).
+    ``stack`` (L, B, ...) is a whole layer stack whose T axis is
+    ``seq_axis``; ``rows`` (B, ...) are the new rows, shaped like one
+    layer's cache without T; ``pos`` (B,) is each sequence's position, so
+    row ``b`` lands at ``[layer, b, ...]`` with T = ``pos[b]``.  With T
+    second-minor the rows are contiguous: one scatter, every other axis
+    indexed.  With T minor (a column) it is one ``dynamic_update_slice``
+    per sequence, since a scatter there would make the compiler lay the
+    whole stack out anew.  Either way a donated stack is updated in place:
+    the rest of it is never read, rebuilt or copied.
+    """
+    rows = rows.astype(stack.dtype)
+    axis = seq_axis % stack.ndim
+    if axis == stack.ndim - 2:
+        grid = rows.shape[:-1]                              # (B, ...)
+        ix = [jnp.arange(n).reshape((n,) + (1,) * (len(grid) - 1 - i))
+              for i, n in enumerate(grid)]
+        at = pos.reshape((-1,) + (1,) * (len(grid) - 1))
+        return stack.at[(layer, *ix, at)].set(rows)
+    for b in range(rows.shape[0]):
+        start = [layer, b] + [0] * (stack.ndim - 2)
+        start[axis] = pos[b]
+        stack = jax.lax.dynamic_update_slice(
+            stack, jnp.expand_dims(rows[b], (0, 1, axis)), start)
+    return stack
+
+
+def attend_decode(p, x: jax.Array, cache: Dict[str, jax.Array], pos,
+                  cfg: ModelConfig, layer=0):
+    """x (B, 1, D) against layer ``layer`` of the stacked cache ``{"k",
+    "v"}`` (L, B, KVH, T, hd); ``pos`` (B,) int32 is each sequence's
+    absolute position.
+
+    Writes each sequence's new key and value row at ``[layer, b, :,
+    pos[b]]`` in place (:func:`write_rows`) and returns (y (B, 1, D), the
+    updated stack).  The masked-softmax reduction over the cache's T axis
+    is written so GSPMD's partial reductions implement flash-decoding when
+    T is sharded (DESIGN.md §5).
     """
     b = x.shape[0]
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    positions = jnp.full((1,), 0, jnp.int32) + pos
-    q, k_new, v_new = _project_qkv(p, x, cfg, positions)
+    q, k_new, v_new = _project_qkv(p, x, cfg, pos[:, None])
 
-    dtype = cache["k"].dtype
-    k_cache = jax.lax.dynamic_update_slice_in_dim(
-        cache["k"], k_new.astype(dtype), pos, axis=2)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(
-        cache["v"], v_new.astype(dtype), pos, axis=2)
-    k_cache = constrain(k_cache, "batch", None, "kv_seq", None)
-    v_cache = constrain(v_cache, "batch", None, "kv_seq", None)
+    k_stack = write_rows(cache["k"], k_new[:, :, 0], layer, pos)
+    v_stack = write_rows(cache["v"], v_new[:, :, 0], layer, pos)
+    dtype = k_stack.dtype
+    k_cache = constrain(k_stack[layer], "batch", None, "kv_seq", None)
+    v_cache = constrain(v_stack[layer], "batch", None, "kv_seq", None)
 
     group = h // kvh
     t = k_cache.shape[2]
@@ -201,7 +232,7 @@ def attend_decode(p, x: jax.Array, cache: Dict[str, jax.Array], pos,
     # on minicpm decode_32k before this, see EXPERIMENTS §Perf)
     s = jnp.einsum("bgqd,bgtd->bgqt", qd, k_cache,
                    preferred_element_type=jnp.float32) * scale  # (B,KVH,G,T)
-    valid = (jnp.arange(t) <= pos)[None, None, None, :]
+    valid = (jnp.arange(t)[None] <= pos[:, None])[:, None, None, :]
     s = jnp.where(valid, s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     pexp = jnp.exp(s - m)
@@ -211,4 +242,4 @@ def attend_decode(p, x: jax.Array, cache: Dict[str, jax.Array], pos,
     o = o.reshape(b, 1, h * hd)
     dt = cdtype(cfg)
     y = jnp.dot(o.astype(dt), p["wo"].astype(dt))
-    return y, {"k": k_cache, "v": v_cache}
+    return y, {"k": k_stack, "v": v_stack}

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from ..distributed.sharding import constrain
 from ..kernels.flash_attention.ops import flash_attention
+from .attention import write_rows
 from .config import ModelConfig
 from .layers import (apply_rotary, cdtype, rms_norm_1d, yarn_frequencies,
                      yarn_mscale)
@@ -138,13 +139,16 @@ def mla_full(p, x: jax.Array, cfg: ModelConfig, *,
 
 
 # ---------------------------------------------------------------------------
-# Compressed cache
+# Compressed cache: ``c_kv`` (B,T,kvl) and ``k_rope`` (B,rope,T).  The rope
+# keys keep T minor: a 64-wide row is less than a TPU lane tile, and the
+# compiler would lay such a leaf out with T minor anyway — storing it so
+# lets the decode step write its column in place (``write_rows``).
 # ---------------------------------------------------------------------------
 def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int,
                    dtype=jnp.bfloat16) -> Dict[str, jax.Array]:
     return {
         "c_kv": jnp.zeros((batch, max_len, cfg.kv_lora_rank), dtype),
-        "k_rope": jnp.zeros((batch, max_len, cfg.qk_rope_head_dim), dtype),
+        "k_rope": jnp.zeros((batch, cfg.qk_rope_head_dim, max_len), dtype),
     }
 
 
@@ -153,24 +157,31 @@ def mla_cache_struct(cfg: ModelConfig, batch: int, max_len: int,
     return {
         "c_kv": jax.ShapeDtypeStruct((batch, max_len, cfg.kv_lora_rank), dtype),
         "k_rope": jax.ShapeDtypeStruct(
-            (batch, max_len, cfg.qk_rope_head_dim), dtype),
+            (batch, cfg.qk_rope_head_dim, max_len), dtype),
     }
 
 
 def mla_cache_from_prefill(cfg: ModelConfig, c_kv, k_rope, max_len: int,
                            dtype=jnp.bfloat16):
+    """Prefill's (B,S,kvl) latents and (B,S,rope) keys -> the cache,
+    padded out to ``max_len``."""
     s = c_kv.shape[1]
     pad = [(0, 0), (0, max_len - s), (0, 0)]
     return {"c_kv": jnp.pad(c_kv.astype(dtype), pad),
-            "k_rope": jnp.pad(k_rope.astype(dtype), pad)}
+            "k_rope": jnp.pad(k_rope.astype(dtype).swapaxes(1, 2),
+                              [(0, 0), (0, 0), (0, max_len - s)])}
 
 
 # ---------------------------------------------------------------------------
 # Decode: absorbed MQA-style attention against the latent cache
 # ---------------------------------------------------------------------------
 def mla_decode(p, x: jax.Array, cache: Dict[str, jax.Array], pos,
-               cfg: ModelConfig):
-    """x (B,1,D) -> (y (B,1,D), cache').  Attention runs in latent space:
+               cfg: ModelConfig, layer=0):
+    """x (B,1,D) against layer ``layer`` of the stacked latent cache
+    ``{"c_kv" (L,B,T,kvl), "k_rope" (L,B,rope,T)}``; ``pos`` (B,) int32 is
+    each sequence's absolute position.  Writes each sequence's new latent
+    and rope key at T = ``pos[b]`` of ``[layer, b]`` in place and returns
+    (y (B,1,D), the updated stack).  Attention runs in latent space:
 
     score_h(t) = q_nope_h · W_UK_h c_kv[t]  +  q_rope_h · k_rope[t]
                = (W_UK_hᵀ q_nope_h) · c_kv[t] + q_rope_h · k_rope[t]
@@ -180,21 +191,20 @@ def mla_decode(p, x: jax.Array, cache: Dict[str, jax.Array], pos,
     """
     b = x.shape[0]
     h = cfg.n_heads
-    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
     kvl = cfg.kv_lora_rank
     dt = cdtype(cfg)
-    positions = jnp.full((1,), 0, jnp.int32) + pos
+    positions = pos[:, None]
 
     q_nope, q_rope = _queries(p, x, cfg, positions)      # (B,H,1,·)
     c_new, k_rope_new = _latents(p, x, cfg, positions)   # (B,1,kvl),(B,1,1,rope)
 
-    dtype = cache["c_kv"].dtype
-    c_kv = jax.lax.dynamic_update_slice_in_dim(
-        cache["c_kv"], c_new.astype(dtype), pos, axis=1)
-    k_rope = jax.lax.dynamic_update_slice_in_dim(
-        cache["k_rope"], k_rope_new[:, 0].astype(dtype), pos, axis=1)
-    c_kv = constrain(c_kv, "batch", "kv_seq", None)
-    k_rope = constrain(k_rope, "batch", "kv_seq", None)
+    c_stack = write_rows(cache["c_kv"], c_new[:, 0], layer, pos)
+    r_stack = write_rows(cache["k_rope"], k_rope_new[:, 0, 0], layer, pos,
+                         seq_axis=-1)
+    dtype = c_stack.dtype
+    c_kv = constrain(c_stack[layer], "batch", "kv_seq", None)
+    k_rope = constrain(r_stack[layer], "batch", None, "kv_seq")
 
     # absorb W_UK into the query:  q_lat (B,H,kvl)
     wk_b = p["wk_b"].astype(jnp.float32).reshape(kvl, h, nope)
@@ -206,10 +216,10 @@ def mla_decode(p, x: jax.Array, cache: Dict[str, jax.Array], pos,
     scale = softmax_scale(cfg)
     s_lat = jnp.einsum("bhk,btk->bht", q_lat.astype(dtype), c_kv,
                        preferred_element_type=jnp.float32)
-    s_rope = jnp.einsum("bhr,btr->bht", q_rope[:, :, 0].astype(dtype),
+    s_rope = jnp.einsum("bhr,brt->bht", q_rope[:, :, 0].astype(dtype),
                         k_rope, preferred_element_type=jnp.float32)
     s = (s_lat + s_rope) * scale
-    valid = (jnp.arange(t) <= pos)[None, None, :]
+    valid = (jnp.arange(t)[None] <= pos[:, None])[:, None, :]
     s = jnp.where(valid, s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     pexp = jnp.exp(s - m)
@@ -222,4 +232,4 @@ def mla_decode(p, x: jax.Array, cache: Dict[str, jax.Array], pos,
     o = jnp.einsum("bhk,khd->bhd", o_lat, wv_b)
     o = o.reshape(b, 1, h * vd)
     y = jnp.dot(o.astype(dt), p["wo"].astype(dt))
-    return y, {"c_kv": c_kv, "k_rope": k_rope}
+    return y, {"c_kv": c_stack, "k_rope": r_stack}

@@ -42,8 +42,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from jax.custom_batching import custom_vmap
-
 from ..distributed.sharding import constrain
 from ..kernels.common import round_up
 from ..kernels.moe_gmm.ops import moe_gmm, row_tile
@@ -280,25 +278,10 @@ def apply_moe_serve(p, x: jax.Array, cfg: ModelConfig, layer=0):
     """x (B, S, D) -> (y (B, S, D), each token's routed experts (B, S,
     top_k) int32, global ids).  The expert weights ``wi`` / ``wg`` / ``wo``
     may be the whole stack over layers, ``layer`` picking this one (the
-    kernel then reads that layer's touched experts in place).  Under
-    ``jax.vmap`` (the decode engine's per-slot lanes) the tokens of every
-    lane go through ONE grouped matmul, so an expert that several slots
-    route to has its weights read once; each lane still gets its own
-    routing."""
-    def plain(p, x, layer):
-        y, expert = _moe_tokens(p, x.reshape(-1, x.shape[-1]), cfg, layer)
-        return y.reshape(x.shape), expert.reshape(x.shape[:-1] + (-1,))
-
-    fn = custom_vmap(plain)
-
-    @fn.def_vmap
-    def _lanes(axis_size, in_batched, p, x, layer):
-        p_batched, _, layer_batched = in_batched
-        if any(jax.tree_util.tree_leaves(p_batched)) or layer_batched:
-            raise NotImplementedError(
-                "the expert layer batches tokens, not weights or layers")
-        y, expert = _moe_tokens(p, x.reshape(-1, x.shape[-1]), cfg, layer)
-        return ((y.reshape(x.shape), expert.reshape(x.shape[:-1] + (-1,))),
-                (True, True))
-
-    return fn(p, x, jnp.asarray(layer, jnp.int32))
+    kernel then reads that layer's touched experts in place).  All B * S
+    tokens go through ONE grouped matmul — a decode step's B slots as a
+    prefill's S prompt tokens — so an expert that several tokens route to
+    has its weights read once; each token still gets its own routing."""
+    y, expert = _moe_tokens(p, x.reshape(-1, x.shape[-1]), cfg,
+                            jnp.asarray(layer, jnp.int32))
+    return y.reshape(x.shape), expert.reshape(x.shape[:-1] + (-1,))

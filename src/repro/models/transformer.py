@@ -12,7 +12,8 @@ Three entry points (all pure, jit/pjit-able):
 * :func:`forward`      — full-sequence hidden states (train / encoder);
 * :func:`train_loss`   — CE loss + MoE aux losses + metrics;
 * :func:`prefill` / :func:`decode_step` — serving with per-kind caches
-  (KV / MLA-latent / mamba-state / rwkv-state), carried as scan xs/ys.
+  (KV / MLA-latent / mamba-state / rwkv-state); prefill emits them as scan
+  ys, decode carries the stacks through its scan and writes in place.
 """
 
 from __future__ import annotations
@@ -105,11 +106,27 @@ def embed_inputs(params, inputs: Dict[str, jax.Array], cfg: ModelConfig
 # ---------------------------------------------------------------------------
 # One block position (shared by train / prefill / decode bodies)
 # ---------------------------------------------------------------------------
+def _layer_state(stack, layer):
+    """Layer ``layer``'s recurrent state, read from its stack."""
+    return jax.tree_util.tree_map(lambda s: s[layer], stack)
+
+
+def _write_state(stack, state, layer):
+    """A recurrent layer's whole new state, written at its index of the
+    stack (the other layers' states are not rewritten)."""
+    return jax.tree_util.tree_map(
+        lambda s, n: jax.lax.dynamic_update_index_in_dim(
+            s, n.astype(s.dtype), layer, 0), stack, state)
+
+
 def _apply_position(p, x, cfg: ModelConfig, kind: str, mlp_kind: str, *,
                     mode: str = "train", cache=None, pos=None, layer=0):
     """Returns (x, aux, new_cache_or_None).  ``aux`` is the (2,) MoE
     auxiliary losses in training; in serving (prefill / decode) a MoE
-    position's routed experts per token (B, S, top_k) int32."""
+    position's routed experts per token (B, S, top_k) int32.  In decode,
+    ``cache`` is the position's whole layer stack and ``layer`` its index
+    there (and in the MoE expert stack); the stack comes back with only
+    this layer's new state written."""
     rs = residual_scale(cfg)
     aux = jnp.zeros((2,), jnp.float32)
     new_cache = None
@@ -117,7 +134,8 @@ def _apply_position(p, x, cfg: ModelConfig, kind: str, mlp_kind: str, *,
     h = apply_norm(p["norm1"], x, cfg)
     if kind == "attn":
         if mode == "decode":
-            out, new_cache = attend_decode(p["block"], h, cache, pos, cfg)
+            out, new_cache = attend_decode(p["block"], h, cache, pos, cfg,
+                                           layer)
         elif mode == "prefill":
             out, (k, v) = attend_full(p["block"], h, cfg, return_kv=True)
             new_cache = (k, v)
@@ -125,21 +143,23 @@ def _apply_position(p, x, cfg: ModelConfig, kind: str, mlp_kind: str, *,
             out = attend_full(p["block"], h, cfg)
     elif kind == "mla":
         if mode == "decode":
-            out, new_cache = mla_decode(p["block"], h, cache, pos, cfg)
+            out, new_cache = mla_decode(p["block"], h, cache, pos, cfg, layer)
         elif mode == "prefill":
             out, new_cache = mla_full(p["block"], h, cfg, return_cache=True)
         else:
             out = mla_full(p["block"], h, cfg)
     elif kind == "mamba":
         if mode == "decode":
-            out, new_cache = mamba_decode(p["block"], h, cache, cfg)
+            out, state = mamba_decode(p["block"], h,
+                                      _layer_state(cache, layer), cfg)
+            new_cache = _write_state(cache, state, layer)
         elif mode == "prefill":
             out, new_cache = mamba_full(p["block"], h, cfg, return_state=True)
         else:
             out = mamba_full(p["block"], h, cfg)
     elif kind == "rwkv":
         if mode == "decode":
-            tlast, wkv, clast = cache
+            tlast, wkv, clast = _layer_state(cache, layer)
             out, (tlast2, wkv2) = rwkv_time_mix(
                 p["block"], h, cfg, state=(tlast, wkv), return_state=True)
             x = x + out * rs
@@ -147,7 +167,7 @@ def _apply_position(p, x, cfg: ModelConfig, kind: str, mlp_kind: str, *,
             out2, clast2 = rwkv_channel_mix(p["block"], h2, cfg,
                                             last_x=clast, return_state=True)
             x = x + out2 * rs
-            return x, aux, (tlast2, wkv2, clast2)
+            return x, aux, _write_state(cache, (tlast2, wkv2, clast2), layer)
         elif mode == "prefill":
             zs = init_rwkv_state(cfg, x.shape[0], cdtype(cfg))
             out, (tlast2, wkv2) = rwkv_time_mix(
@@ -183,27 +203,30 @@ def _apply_position(p, x, cfg: ModelConfig, kind: str, mlp_kind: str, *,
 
 def _apply_layer0(params, x, cfg: ModelConfig, *, mode="train", cache=None,
                   pos=None):
-    """deepseek's dense first layer (same block kind, dense MLP)."""
+    """deepseek's dense first layer (same block kind, dense MLP).  Its
+    cache is unstacked; decode views it as a stack of one layer, so the
+    same one-row write updates it in place."""
     p = params["layer0"]
     kind = cfg.block_pattern[0]
     rs = residual_scale(cfg)
     h = apply_norm(p["norm1"], x, cfg)
     new_cache = None
-    if kind == "mla":
-        if mode == "decode":
-            out, new_cache = mla_decode(p["block"], h, cache, pos, cfg)
-        elif mode == "prefill":
+    if mode == "decode":
+        decode = mla_decode if kind == "mla" else attend_decode
+        out, stack = decode(p["block"], h,
+                            jax.tree_util.tree_map(lambda c: c[None], cache),
+                            pos, cfg, 0)
+        new_cache = jax.tree_util.tree_map(lambda c: c[0], stack)
+    elif kind == "mla":
+        if mode == "prefill":
             out, new_cache = mla_full(p["block"], h, cfg, return_cache=True)
         else:
             out = mla_full(p["block"], h, cfg)
+    elif mode == "prefill":
+        out, (k, v) = attend_full(p["block"], h, cfg, return_kv=True)
+        new_cache = (k, v)
     else:
-        if mode == "decode":
-            out, new_cache = attend_decode(p["block"], h, cache, pos, cfg)
-        elif mode == "prefill":
-            out, (k, v) = attend_full(p["block"], h, cfg, return_kv=True)
-            new_cache = (k, v)
-        else:
-            out = attend_full(p["block"], h, cfg)
+        out = attend_full(p["block"], h, cfg)
     x = x + out * rs
     h2 = apply_norm(p["norm2"], x, cfg)
     x = x + apply_mlp(p["mlp"], h2, cfg) * rs
@@ -367,7 +390,7 @@ _CACHE_AXES = {
     "attn": {"k": ("batch", "kv_heads", "kv_seq", None),
              "v": ("batch", "kv_heads", "kv_seq", None)},
     "mla": {"c_kv": ("batch", "kv_seq", None),
-            "k_rope": ("batch", "kv_seq", None)},
+            "k_rope": ("batch", None, "kv_seq")},
     "mamba": (("batch", None, "mlp"), ("batch", "mlp", None)),
     "rwkv": (("batch", None), ("batch", "heads", None, None),
              ("batch", None)),
@@ -483,41 +506,45 @@ def _pad_prefill(cfg, kind, c, max_len, dtype):
 # ---------------------------------------------------------------------------
 def decode_step(params, cache, tokens: jax.Array, pos, cfg: ModelConfig,
                 return_experts: bool = False):
-    """One token for every sequence.  tokens (B,) int32, pos scalar int32.
+    """One token for every sequence.  tokens (B,) int32; pos int32, one
+    position for all rows (scalar) or each row's own (B,).
+
+    The cache is updated in place: the layer stacks ride the layer scan's
+    carry, and each layer writes only its B new rows (a recurrent layer its
+    own state) at its index, so a donated cache is never rebuilt or copied.
 
     Returns (logits (B, Vp) fp32, updated cache) [, each MoE layer's
     routed experts (L_moe, B, 1, top_k) int32, global ids].
     """
     if cfg.is_encoder:
         raise ValueError(f"{cfg.name} is encoder-only: no decode step")
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), tokens.shape)
     x = embed_tokens(params["embed"], tokens[:, None], cfg)
     x = constrain(x, "batch", None, None)
+    new_cache = {}
     if cfg.first_layer_dense:
-        x, c0 = _apply_layer0(params, x, cfg, mode="decode",
-                              cache=cache["layer0"], pos=pos)
-        new_layer0 = c0
+        x, new_cache["layer0"] = _apply_layer0(
+            params, x, cfg, mode="decode", cache=cache["layer0"], pos=pos)
 
     xs, experts = _serve_scan_xs(cfg, params["blocks"])
 
-    def body(x, xs):
-        (group_params, layer), group_cache = xs
-        new_caches, routed = [], []
+    def body(carry, xs):
+        x, stacks = carry
+        group_params, layer = xs
+        stacks, routed = dict(stacks), []
         for i, (kind, mlp_kind) in enumerate(
                 zip(cfg.block_pattern, cfg.mlp_pattern)):
-            x, r, c = _apply_position(
+            x, r, stacks[f"pos{i}"] = _apply_position(
                 _serve_params(group_params, experts, i), x, cfg, kind,
-                mlp_kind, mode="decode", cache=group_cache[f"pos{i}"],
+                mlp_kind, mode="decode", cache=stacks[f"pos{i}"],
                 pos=pos, layer=layer)
-            new_caches.append(c)
             if mlp_kind == "moe":
                 routed.append(r)
-        return x, (tuple(new_caches), tuple(routed))
+        return (x, stacks), tuple(routed)
 
-    scan_cache = {k: v for k, v in cache.items() if k != "layer0"}
-    x, (stacked, routed) = jax.lax.scan(body, x, (xs, scan_cache))
-    new_cache = {f"pos{i}": stacked[i] for i in range(cfg.period)}
-    if cfg.first_layer_dense:
-        new_cache["layer0"] = new_layer0
+    stacks = {k: v for k, v in cache.items() if k != "layer0"}
+    (x, stacks), routed = jax.lax.scan(body, (x, stacks), xs)
+    new_cache.update(stacks)
     x = apply_norm(params["final_norm"], x, cfg)
     logits = logits_from_hidden(params["embed"], x, cfg)[:, 0]
     if return_experts:

@@ -20,9 +20,10 @@ Engine invariants (pinned by ``tests/test_decode_serve.py``):
   ``launch_prefix(..., donate=<cache leaves>)`` — slot insertion is a
   launch-time buffer update, never a re-capture, and the graphs stay pure:
   slot state is data the launch carries, not state the capture holds.
-- **Slot insertion never perturbs other slots' outputs.**  The per-slot
-  step is an independent ``jax.vmap`` lane over (cache slot, token,
-  position); decode under staggered arrival is bit-identical to whole-batch
+- **Slot insertion never perturbs other slots' outputs.**  The step runs
+  every slot at its own position (a positions vector) and each slot
+  reads and writes only its own cache rows; decode under staggered arrival
+  is bit-identical to whole-batch
   :func:`~repro.train.serve.greedy_generate` for every cache family (plain
   KV, MLA latent, rwkv6 O(1) state).
 - **No full-vocabulary output rides the step graph.**  The decode kernel
@@ -140,38 +141,29 @@ def build_decode_kernel(config: EGPUConfig = EGPU_16T, *,
     *params) -> (next tokens (B,), *new cache leaves[, routed experts (B,
     L_moe, top_k)]).
 
-    Each slot is an independent ``jax.vmap`` lane over (cache slot, token,
-    position) — per-slot positions are what make staggered insertion
-    bit-identical to each request's own whole-batch trajectory.  The step
-    takes its greedy argmax inside, so no ``(B, vocab)`` buffer rides the
-    captured graph's outputs.  A model with MoE layers adds one output:
-    each slot's routed experts in every MoE layer.
+    The whole batch runs as one :func:`~repro.models.transformer.decode_step`
+    with each slot's own position, and every layer writes only each slot's
+    new cache row in place — per-slot positions are what make staggered
+    insertion bit-identical to each request's own whole-batch trajectory,
+    and the donated cache is never rebuilt or copied.  The step takes its
+    greedy argmax inside, so no ``(B, vocab)`` buffer rides the captured
+    graph's outputs.  A model with MoE layers adds one output: each slot's
+    routed experts in every MoE layer.
     """
     del num_slots                        # identity only: one graph per width
-    bidx = batch_axes(cfg)
-    cache_def = jax.tree_util.tree_structure(bidx)
+    cache_def = jax.tree_util.tree_structure(batch_axes(cfg))
     n_cache = cache_def.num_leaves
     moe = moe_layers(cfg) > 0
-
-    def one(params, cache_slot, tok, pos):
-        cache_b = jax.tree_util.tree_map(
-            lambda c, i: jnp.expand_dims(c, i), cache_slot, bidx)
-        logits, new_cache, *experts = decode_step(
-            params, cache_b, tok[None], pos, cfg, return_experts=moe)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        new_slot = jax.tree_util.tree_map(
-            lambda c, i: jnp.squeeze(c, axis=i), new_cache, bidx)
-        return nxt[0], new_slot, [e[:, 0, 0] for e in experts]
-
-    vstep = jax.vmap(one, in_axes=(None, bidx, 0, 0),
-                     out_axes=(0, bidx, 0))
 
     def engine_decode(tokens, positions, *state):
         cache = jax.tree_util.tree_unflatten(cache_def, state[:n_cache])
         params = jax.tree_util.tree_unflatten(
             engine_decode._params_def, state[n_cache:])
-        toks, new_cache, experts = vstep(params, cache, tokens, positions)
-        return (toks, *jax.tree_util.tree_leaves(new_cache), *experts)
+        logits, new_cache, *experts = decode_step(
+            params, cache, tokens, positions, cfg, return_experts=moe)
+        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return (toks, *jax.tree_util.tree_leaves(new_cache),
+                *(jnp.swapaxes(e[:, :, 0], 0, 1) for e in experts))
 
     return Kernel(name="engine.generate", executor=engine_decode,
                   counts=_engine_counts)
@@ -230,9 +222,11 @@ class DecodeEngine:
     resident — donated back to each launch, never round-tripped — and the
     counts model prices exactly token/position I/O as host traffic.
 
-    Donation discipline: donated inputs are consumed by XLA, so every
-    launch realizes its token output and retires (drains) before the next
-    launch donates the buffers the previous outputs alias.
+    Donation discipline: the step writes each slot's new cache row into
+    the donated cache leaves in place, so each launch's cache outputs ARE
+    its inputs' buffers.  Every launch therefore realizes its token output
+    and retires (drains) before the next launch donates the buffers the
+    previous outputs alias.
     """
 
     def __init__(self, cfg: ModelConfig, params: Any, *,
@@ -317,33 +311,34 @@ class DecodeEngine:
         kern.executor._params_def = jax.tree_util.tree_structure(self.params)
         return kern
 
+    def _prefill_kernel(self) -> Kernel:
+        kern = self._program.create_kernel(
+            "engine.prefill", cfg=self.cfg, max_len=self.max_len,
+            cache_dtype=str(self.cache_dtype))
+        kern.executor._params_def = jax.tree_util.tree_structure(self.params)
+        return kern
+
     def _cache_structs(self) -> Tuple[Any, ...]:
-        """Canonical per-leaf avals of the persistent cache: the decode
-        step's OWN output avals (its fixed point), not ``cache_struct``'s
-        advertised ones — recurrent families re-emit some leaves at the
-        activation dtype (rwkv's token-shift state), and seeding the state
-        there keeps every step on ONE captured graph."""
+        """Canonical per-leaf avals of the persistent cache: the prefill's
+        OWN cache avals, ``num_slots`` wide, not ``cache_struct``'s
+        advertised ones — recurrent families emit some state at the
+        activation dtype (rwkv's token-shift state), the decode step
+        carries every leaf at the dtype it is given, and so a state seeded
+        as prefill emits it takes each insert exactly and keeps every step
+        on ONE captured graph."""
         if self._canonical_structs is not None:
             return self._canonical_structs
-        b = self.num_slots
-        kern = self._decode_kernel()
-        leaves = [jax.ShapeDtypeStruct(s.shape, s.dtype)
-                  for s in jax.tree_util.tree_leaves(
-                      cache_struct(self.cfg, b, self.max_len,
-                                   self.cache_dtype))]
-        io = (jax.ShapeDtypeStruct((b,), jnp.int32),
-              jax.ShapeDtypeStruct((b,), jnp.int32))
         pstructs = [jax.ShapeDtypeStruct(p.shape, p.dtype)
                     for p in self._param_leaves]
-        for _ in range(3):                       # fixed point in <= 1 pass
-            outs = jax.eval_shape(kern.executor, *io, *leaves, *pstructs)
-            new = [jax.ShapeDtypeStruct(o.shape, o.dtype)
-                   for o in outs[1:1 + len(leaves)]]
-            if [(l.shape, l.dtype) for l in new] == \
-                    [(l.shape, l.dtype) for l in leaves]:
-                break
-            leaves = new
-        self._canonical_structs = tuple(leaves)
+        outs = jax.eval_shape(self._prefill_kernel().executor,
+                              jax.ShapeDtypeStruct((1, 1), jnp.int32),
+                              *pstructs)
+        n_cache = len(jax.tree_util.tree_leaves(self._bidx))
+        self._canonical_structs = tuple(
+            jax.ShapeDtypeStruct(o.shape[:i] + (self.num_slots,)
+                                 + o.shape[i + 1:], o.dtype)
+            for o, i in zip(outs[1:1 + n_cache],
+                            jax.tree_util.tree_leaves(self._bidx)))
         return self._canonical_structs
 
     def init_state(self) -> DecodeState:
@@ -399,12 +394,7 @@ class DecodeEngine:
         s = int(prompt.shape[1])
         stages = self._prefill_stages.get(s)
         if stages is None:
-            kern = self._program.create_kernel(
-                "engine.prefill", cfg=self.cfg, max_len=self.max_len,
-                cache_dtype=str(self.cache_dtype))
-            kern.executor._params_def = jax.tree_util.tree_structure(
-                self.params)
-            stages = (Stage(kern,
+            stages = (Stage(self._prefill_kernel(),
                             counts_params=self._prefill_counts_params(s)),)
             self._prefill_stages[s] = stages
         inputs = (prompt, *self._param_leaves)

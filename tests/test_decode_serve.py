@@ -32,6 +32,7 @@ from repro.models.transformer import decode_step, prefill
 from repro.obs import Tracer
 from repro.serve import DecodeEngine, EngineHTTPServer, Server
 from repro.serve.faults import InjectedFault
+from repro.serve.engine import batch_axes
 from repro.serve.server import AdmissionError
 from repro.train.serve import greedy_generate
 
@@ -63,6 +64,44 @@ def test_greedy_generate_cache_family(arch):
     again = greedy_generate(params, cfg, prompts, max_new=NEW,
                             max_len=PROMPT + NEW + 1)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(again))
+
+
+#: one reduced model per decoding kind: GQA, MLA, recurrent, and the mamba
+#: and attention hybrid
+DECODE_KINDS = FAMILIES + ["jamba-1.5-large-398b"]
+
+
+@pytest.mark.parametrize("arch", DECODE_KINDS)
+def test_positions_vector_step_equals_single_rows(arch):
+    """One decode step with each row at its own position equals, row for
+    row, a step that puts every row at that row's position as a scalar —
+    exactly (float32 model and cache): logits, and every cache leaf of
+    the row.  Both steps run the same batch, because a one-row matmul
+    sums in another order than a batched one on the CPU."""
+    cfg = ARCHS[arch].reduced()
+    assert cfg.dtype == "float32"
+    params = init_params(model_spec(cfg), jax.random.PRNGKey(0))
+    lens, max_len = [5, 12, 9], 16
+    rng = np.random.default_rng(3)
+    caches = [prefill(params, {"tokens": jnp.asarray(
+        rng.integers(0, cfg.vocab, (1, n)), jnp.int32)}, cfg, max_len,
+        cache_dtype=jnp.float32)[1] for n in lens]
+    bidx = batch_axes(cfg)
+    batched = jax.tree_util.tree_map(
+        lambda i, *cs: jnp.concatenate(cs, axis=i), bidx, *caches)
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab, len(lens)), jnp.int32)
+    logits, cache = decode_step(params, batched, tokens,
+                                jnp.asarray(lens, jnp.int32), cfg)
+    for r, n in enumerate(lens):
+        want, want_cache = decode_step(params, batched, tokens,
+                                       jnp.int32(n), cfg)
+        np.testing.assert_array_equal(np.asarray(logits[r]),
+                                      np.asarray(want[r]))
+        jax.tree_util.tree_map(
+            lambda i, got, w: np.testing.assert_array_equal(
+                np.asarray(jnp.take(got, r, axis=i)),
+                np.asarray(jnp.take(w, r, axis=i))),
+            bidx, cache, want_cache)
 
 
 # -- ISSUE 9: the continuous-batching decode engine -------------------------

@@ -25,7 +25,7 @@ from repro.models import reference_deepseek_v2 as ref
 from repro.models.layers import yarn_frequencies, yarn_mscale
 from repro.models.mla import softmax_scale
 from repro.models.moe import apply_moe_serve, route
-from repro.models.transformer import decode_step, prefill
+from repro.models.transformer import cache_struct, decode_step, prefill
 from repro.obs import MetricsRegistry
 from repro.serve import DecodeEngine, Server
 
@@ -238,20 +238,49 @@ def test_eight_shares_add_up_to_the_uncut_layer(params):
     assert int(rows_all.sum()) == 24 * 6
 
 
+def _gmm_rows(fn, *args):
+    """The row count of every grouped matmul (``moe_gmm`` call) that
+    ``fn`` traces, in trace order."""
+    rows = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.params.get("name") == "moe_gmm":
+                rows.append(eqn.invars[0].aval.shape[0])
+            for v in eqn.params.values():
+                for sub in v if isinstance(v, (list, tuple)) else [v]:
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return rows
+
+
 def test_decode_lanes_share_one_grouped_matmul(params):
-    """Under the engine's per-slot vmap the MoE layer batches every lane's
-    token into one grouped matmul, and each lane still gets its own
-    routing and the output it would get alone (to float32 rounding: the
-    batched matmuls over five rows sum in another order than five one-row
-    ones)."""
+    """A decode step hands the MoE layer every slot's token at once, at
+    each slot's own position, so the tokens of all slots go through ONE
+    grouped matmul per projection; each slot still gets its own routing
+    and the output it would get alone (to float32 rounding: the batched
+    matmuls over five rows sum in another order than five one-row ones)."""
     layer = jax.tree_util.tree_map(lambda a: a[0],
                                    params["blocks"]["pos0"]["mlp"])
-    h = jax.random.normal(jax.random.PRNGKey(4), (5, 1, 1, CFG.d_model))
-    y, experts = jax.vmap(lambda x: apply_moe_serve(layer, x, CFG))(h)
-    assert experts.shape == (5, 1, 1, CFG.top_k)
+    h = jax.random.normal(jax.random.PRNGKey(4), (5, 1, CFG.d_model))
+    y, experts = apply_moe_serve(layer, h, CFG)
+    assert experts.shape == (5, 1, CFG.top_k)
     for i in range(5):
-        yi, ei = apply_moe_serve(layer, h[i], CFG)
-        np.testing.assert_allclose(np.asarray(y[i]), np.asarray(yi),
+        yi, ei = apply_moe_serve(layer, h[i:i + 1], CFG)
+        np.testing.assert_allclose(np.asarray(y[i]), np.asarray(yi[0]),
                                    rtol=1e-4, atol=1e-5)
         np.testing.assert_array_equal(np.asarray(experts[i]),
-                                      np.asarray(ei))
+                                      np.asarray(ei[0]))
+    five = _gmm_rows(lambda x: apply_moe_serve(layer, x, CFG), h)
+    one = _gmm_rows(lambda x: apply_moe_serve(layer, x, CFG), h[:1])
+    cache = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype),
+        cache_struct(CFG, 5, 32))
+    step = _gmm_rows(lambda c, t, p: decode_step(params, c, t, p, CFG),
+                     cache, jnp.zeros((5,), jnp.int32),
+                     jnp.arange(5, dtype=jnp.int32) + 20)
+    assert len(five) == 3 and five[0] > one[0]
+    assert step == five

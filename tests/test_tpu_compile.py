@@ -11,6 +11,7 @@ The topology is described inside a fixture, never at import time: only one
 process may load the TPU library, and every test worker imports this file.
 """
 
+import math
 import os
 import re
 
@@ -287,3 +288,47 @@ def test_moe_gmm_compiles(one_chip, rows, tm):
         fn = lambda x, w, g: moe_gmm(x, w, g, tm=tm)  # noqa: E731
         compiled = _compile(fn, spec(rows, k), spec(20, k, n), gs)
         assert _kernel_names(compiled) == {"moe_gmm_pallas"}
+
+
+def _whole_leaf_moves(text: str, leaves) -> list:
+    """Each copy, reshape or transpose in compiled ``text`` whose result
+    holds as many elements as one of ``leaves``: a whole cache leaf moved
+    rather than updated in place."""
+    sizes = {math.prod(s.shape) for s in leaves}
+    moves = []
+    for m in re.finditer(r"= \w+\[([\d,]*)\]\S* (copy|reshape|transpose)\(",
+                         text):
+        dims = [int(d) for d in m.group(1).split(",") if d]
+        if math.prod(dims) in sizes:
+            moves.append(m.group(0))
+    return moves
+
+
+@pytest.mark.parametrize("case", ["qwen-chat", "deepseek-longdoc"])
+def test_engine_decode_step_writes_cache_in_place(one_chip, case):
+    """The engine's decode step at the chat cell's widths (qwen2.5-3b, 32
+    slots of 2048) and at the longdoc cell's chip share (DeepSeek-V2, 16
+    slots of 12544), its cache donated, keeps no second cache: its
+    temporaries stay under a tenth of the cache's bytes, and no copy,
+    reshape or transpose moves a whole cache leaf.  Each step writes only
+    every slot's new row in place."""
+    from repro.core import EGPU_16T, Program
+    from repro.models.transformer import cache_struct
+    from repro.serve.engine import ENGINE_REGISTRY
+    if case == "qwen-chat":
+        (cfg, params), slots, max_len = _qwen_params(one_chip), 32, 2048
+    else:
+        (cfg, params), slots, max_len = _deepseek_share(one_chip), 16, 12544
+    kern = Program.build(EGPU_16T, registry=ENGINE_REGISTRY).create_kernel(
+        "engine.generate", cfg=cfg, num_slots=slots, cache_dtype="bfloat16")
+    kern.executor._params_def = jax.tree_util.tree_structure(params)
+    cache = [jax.ShapeDtypeStruct(c.shape, c.dtype, sharding=one_chip)
+             for c in jax.tree_util.tree_leaves(
+                 cache_struct(cfg, slots, max_len, jnp.bfloat16))]
+    io = [jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)] * 2
+    donate = tuple(range(2, 2 + len(cache)))
+    compiled = jax.jit(kern.executor, donate_argnums=donate).lower(
+        *io, *cache, *jax.tree_util.tree_leaves(params)).compile()
+    cache_bytes = sum(math.prod(c.shape) * c.dtype.itemsize for c in cache)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1 * cache_bytes
+    assert _whole_leaf_moves(compiled.as_text(), cache) == []
