@@ -19,10 +19,9 @@ from ...core.program import kernel_family
 from ...core.runtime import Kernel
 from ..common import pad_dim, use_interpret
 from .mamba_scan import mamba_scan_pallas
-from .ref import counts, mamba_scan_ref, mamba_step_ref
+from .ref import counts, mamba_scan_ref
 
-__all__ = ["mamba_scan", "counts", "mamba_scan_ref", "mamba_step_ref",
-           "build_kernel"]
+__all__ = ["mamba_scan", "counts", "mamba_scan_ref", "build_kernel"]
 
 
 def _combine(p, q):
@@ -66,22 +65,17 @@ def mamba_scan(x: jax.Array, delta: jax.Array, a: jax.Array, b: jax.Array,
 
     Returns (y (B,T,Dm) including the D*x skip, final state (B,Dm,N) fp32).
     """
-    bsz, t, dm = x.shape
+    t = x.shape[1]
     if impl == "auto":
         impl = "xla" if use_interpret() else "pallas"
     if impl == "pallas" and state0 is None:
+        y, h = mamba_scan_pallas(x, delta, a, b, c)
+    else:
         xp = pad_dim(x, 1, chunk)
         dp = pad_dim(delta, 1, chunk)      # delta=0 pad: exp(0*A)=1, inc=0
         bp = pad_dim(b, 1, chunk)
         cp = pad_dim(c, 1, chunk)
         # state after padded (identity) steps equals the state at t — exact
-        y, h = mamba_scan_pallas(xp, dp, a, bp, cp, chunk=chunk)
-        y = y[:, :t]
-    else:
-        xp = pad_dim(x, 1, chunk)
-        dp = pad_dim(delta, 1, chunk)
-        bp = pad_dim(b, 1, chunk)
-        cp = pad_dim(c, 1, chunk)
         y, h = _chunked_assoc(xp, dp, a, bp, cp, state0, chunk)
         y = y[:, :t].astype(x.dtype)
     y = y + (x.astype(jnp.float32) * d[None, None].astype(jnp.float32)

@@ -46,13 +46,6 @@ def mamba_scan_ref(x, delta, a, b, c, d, state0=None):
     return y.astype(x.dtype), h
 
 
-def mamba_step_ref(x, delta, a, b, c, d, state):
-    """Single decode step: x/delta (B, Dm), b/c (B, N), state (B, Dm, N)."""
-    y, h = mamba_scan_ref(x[:, None], delta[:, None], a, b[:, None],
-                          c[:, None], d, state)
-    return y[:, 0], h
-
-
 def counts(bsz: int, t: int, dm: int, n: int, itemsize: int = 4) -> WorkCounts:
     # per step per channel: exp+mul (2N), increment (2N), readout (2N)
     ops = 6.0 * bsz * t * dm * n

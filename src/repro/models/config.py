@@ -186,9 +186,11 @@ class ModelConfig:
                           + kvl * h * (nope + vd) + h * vd * d)
         if "mamba" in per:
             di, n, dtr = self.mamba_d_inner, self.mamba_d_state, self.mamba_dt_rank
-            per["mamba"] = (d * 2 * di + di * self.mamba_d_conv
-                            + di * (dtr + 2 * n) + dtr * di + di * n + di
-                            + di * d)
+            # in_proj, conv (weights and bias), x_proj, the dt/B/C norms,
+            # dt_proj and its bias, A_log, D, out_proj
+            per["mamba"] = (d * 2 * di + di * (self.mamba_d_conv + 1)
+                            + (di + 1) * (dtr + 2 * n) + dtr * di + di
+                            + di * n + di + di * d)
         if "rwkv" in per:
             per["rwkv"] = 5 * d * d + 2 * d * 32 + (d * self.d_ff + self.d_ff * d
                                                     + d * d)

@@ -3,12 +3,14 @@
 Block structure (Mamba-1, as used by Jamba):
 
     x ->(in_proj) [xz | z] -> causal depthwise conv1d -> SiLU
-      ->(x_proj) [dt_low | B | C] ; dt = softplus(dt_proj(dt_low) + bias)
+      ->(x_proj) [dt_low | B | C] -> RMSNorm each (Jamba's dt/B/C norms)
+      ; dt = softplus(dt_proj(dt_low) + bias)
       -> selective scan (kernels/mamba_scan) -> * SiLU(z) ->(out_proj) y
 
 Decode keeps two states per layer: the conv window (B, d_conv-1, d_inner)
-and the SSM state (B, d_inner, d_state) — O(1) in context length, which is
-why jamba runs the long_500k cell.
+and the SSM state (B, d_state, d_inner), channels minor so that it is
+lane-dense — O(1) in context length, which is why jamba runs the
+long_500k cell.
 """
 
 from __future__ import annotations
@@ -19,9 +21,9 @@ import jax
 import jax.numpy as jnp
 
 from ..distributed.sharding import constrain
-from ..kernels.mamba_scan.ops import mamba_scan, mamba_step_ref
+from ..kernels.mamba_scan.ops import mamba_scan
 from .config import ModelConfig
-from .layers import cdtype
+from .layers import cdtype, rms_norm_1d
 from .params import ParamSpec, dense_spec
 
 
@@ -40,6 +42,9 @@ def mamba_spec(cfg: ModelConfig, stacked: int = 0) -> Dict[str, ParamSpec]:
         "conv_w": p((dc, di), (None, "mlp"), "normal", dc ** -0.5),
         "conv_b": p((di,), ("mlp",), "zeros"),
         "x_proj": dense_spec(di, dtr + 2 * n, ("mlp", None), stacked=stacked),
+        "dt_norm": p((dtr,), (None,), "ones"),
+        "b_norm": p((n,), (None,), "ones"),
+        "c_norm": p((n,), (None,), "ones"),
         "dt_proj": dense_spec(dtr, di, (None, "mlp"), stacked=stacked),
         "dt_bias": p((di,), ("mlp",), "constant"),     # softplus(0) ~ .69
         # A stored as -exp(a_log) < 0; init a_log = log(arange(1, N+1))
@@ -60,11 +65,15 @@ def _conv1d_causal(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
 
 
 def _ssm_inputs(p, x: jax.Array, cfg: ModelConfig):
-    """Post-conv activations -> (delta, B, C) for the scan."""
-    n, dtr = cfg.mamba_d_state, cfg.mamba_dt_rank
+    """Post-conv activations -> (delta, B, C) for the scan; dt_low, B and
+    C each pass through their RMSNorm first."""
+    n, dtr, eps = cfg.mamba_d_state, cfg.mamba_dt_rank, cfg.norm_eps
     dt = cdtype(cfg)
     proj = jnp.dot(x.astype(dt), p["x_proj"].astype(dt))
     dt_low, bmat, cmat = jnp.split(proj, [dtr, dtr + n], axis=-1)
+    dt_low = rms_norm_1d(dt_low, p["dt_norm"], eps)
+    bmat = rms_norm_1d(bmat, p["b_norm"], eps)
+    cmat = rms_norm_1d(cmat, p["c_norm"], eps)
     delta = jax.nn.softplus(
         jnp.dot(dt_low.astype(dt), p["dt_proj"].astype(dt)).astype(jnp.float32)
         + p["dt_bias"].astype(jnp.float32))
@@ -92,16 +101,26 @@ def mamba_full(p, x: jax.Array, cfg: ModelConfig, *,
     if return_state:
         conv_state = xs[:, -(dc - 1):, :] if s >= dc - 1 else jnp.pad(
             xs, ((0, 0), (dc - 1 - s, 0), (0, 0)))
-        return out, (conv_state.astype(dt), h)
+        return out, (conv_state.astype(dt), jnp.swapaxes(h, 1, 2))
     return out
+
+
+def _ssm_step(x, delta, a, b, c, d, h):
+    """One step of the recurrence for every sequence, in float32: x/delta
+    (B, Di), a (N, Di), b/c (B, N), d (Di,), h (B, N, Di) -> (y (B, Di),
+    h').  Elementwise products and sums only, so that a TPU keeps every
+    term in float32 (a dot would round its operands to bfloat16)."""
+    h = (jnp.exp(delta[:, None, :] * a[None]) * h
+         + (delta * x)[:, None, :] * b[:, :, None])
+    return jnp.sum(h * c[:, :, None], axis=1) + x * d, h
 
 
 def mamba_decode(p, x: jax.Array, state: Tuple[jax.Array, jax.Array],
                  cfg: ModelConfig):
-    """x (B, 1, D), state (conv (B, dc-1, Di), ssm (B, Di, N)) -> (y, state')."""
+    """x (B, 1, D), state (conv (B, dc-1, Di), ssm (B, N, Di)) -> (y, state')."""
     conv_state, ssm_state = state
-    dc = cfg.mamba_d_conv
     dt = cdtype(cfg)
+    f32 = jnp.float32
 
     xz = jnp.dot(x.astype(dt), p["in_proj"].astype(dt))
     xs, z = jnp.split(xz, 2, axis=-1)                  # (B, 1, Di)
@@ -110,9 +129,9 @@ def mamba_decode(p, x: jax.Array, state: Tuple[jax.Array, jax.Array],
     xc = jax.nn.silu(jnp.einsum("bkd,kd->bd", window, w)
                      + p["conv_b"].astype(dt))          # (B, Di)
     delta, bmat, cmat = _ssm_inputs(p, xc[:, None], cfg)
-    a = -jnp.exp(p["a_log"].astype(jnp.float32))
-    y, h = mamba_step_ref(xc, delta[:, 0], a, bmat[:, 0], cmat[:, 0],
-                          p["d_skip"].astype(jnp.float32), ssm_state)
+    a = -jnp.exp(p["a_log"].astype(f32)).T             # (N, Di)
+    y, h = _ssm_step(xc.astype(f32), delta[:, 0], a, bmat[:, 0], cmat[:, 0],
+                     p["d_skip"].astype(f32), ssm_state.astype(f32))
     y = y[:, None].astype(dt) * jax.nn.silu(z)
     out = jnp.dot(y, p["out_proj"].astype(dt))
     return out, (window[:, 1:], h)
@@ -121,10 +140,10 @@ def mamba_decode(p, x: jax.Array, state: Tuple[jax.Array, jax.Array],
 def init_mamba_state(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16):
     di, n, dc = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_d_conv
     return (jnp.zeros((batch, dc - 1, di), dtype),
-            jnp.zeros((batch, di, n), jnp.float32))
+            jnp.zeros((batch, n, di), jnp.float32))
 
 
 def mamba_state_struct(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16):
     di, n, dc = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_d_conv
     return (jax.ShapeDtypeStruct((batch, dc - 1, di), dtype),
-            jax.ShapeDtypeStruct((batch, di, n), jnp.float32))
+            jax.ShapeDtypeStruct((batch, n, di), jnp.float32))

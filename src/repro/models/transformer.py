@@ -391,10 +391,30 @@ _CACHE_AXES = {
              "v": ("batch", "kv_heads", "kv_seq", None)},
     "mla": {"c_kv": ("batch", "kv_seq", None),
             "k_rope": ("batch", None, "kv_seq")},
-    "mamba": (("batch", None, "mlp"), ("batch", "mlp", None)),
+    "mamba": (("batch", None, "mlp"), ("batch", None, "mlp")),
     "rwkv": (("batch", None), ("batch", "heads", None, None),
              ("batch", None)),
 }
+
+
+#: What each cache leaf holds, per block kind (mirrors _CACHE_AXES): the
+#: attention families' keys and values or latents ("kv"), mamba's conv
+#: window ("conv") and SSM state ("ssm"), rwkv's shifts and wkv state.
+_CACHE_KINDS = {
+    "attn": {"k": "kv", "v": "kv"},
+    "mla": {"c_kv": "kv", "k_rope": "kv"},
+    "mamba": ("conv", "ssm"),
+    "rwkv": ("shift", "wkv", "shift"),
+}
+
+
+def cache_kinds(cfg: ModelConfig):
+    """Pytree of kind names matching :func:`cache_struct` leaf for leaf."""
+    out = {f"pos{i}": _CACHE_KINDS[kind]
+           for i, kind in enumerate(cfg.block_pattern)}
+    if cfg.first_layer_dense:
+        out["layer0"] = _CACHE_KINDS[cfg.block_pattern[0]]
+    return out
 
 
 def cache_axes(cfg: ModelConfig):

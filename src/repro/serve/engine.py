@@ -25,7 +25,8 @@ Engine invariants (pinned by ``tests/test_decode_serve.py``):
   reads and writes only its own cache rows; decode under staggered arrival
   is bit-identical to whole-batch
   :func:`~repro.train.serve.greedy_generate` for every cache family (plain
-  KV, MLA latent, rwkv6 O(1) state).
+  KV, MLA latent, rwkv6 O(1) state, and jamba's mamba conv and SSM state
+  beside its KV rows).
 - **No full-vocabulary output rides the step graph.**  The decode kernel
   takes the greedy argmax inside the step;
   :meth:`DecodeEngine.decode_graph`'s out avals carry tokens + cache (and
@@ -51,8 +52,8 @@ from ..core.runtime import CommandGraph, Kernel
 from ..core.scheduler import optimal_ndrange
 from ..models.config import ModelConfig
 from ..models.moe import serve_row_tile
-from ..models.transformer import (cache_axes, cache_struct, decode_step,
-                                  moe_layers, prefill)
+from ..models.transformer import (cache_axes, cache_kinds, cache_struct,
+                                  decode_step, moe_layers, prefill)
 from ..obs import Tracer
 from ..obs.profiler import span
 from .batching import MicroBatch
@@ -279,6 +280,11 @@ class DecodeEngine:
         self._slot_write_bytes = float(sum(
             (_nbytes(x) / max_len if "kv_seq" in ax else _nbytes(x))
             for x, ax in zip(struct_leaves, axes_leaves)))
+        #: what each cache leaf holds ("kv", "conv", "ssm", ...), in leaf
+        #: order
+        self._kinds = tuple(jax.tree_util.tree_leaves(cache_kinds(cfg)))
+        #: bytes that inserts wrote into the slots, by kind
+        self.state_written_bytes: Dict[str, int] = {}
         self._decode_stage: Optional[Tuple[Stage, ...]] = None
         self._prefill_stages: Dict[int, Tuple[Stage, ...]] = {}
         self._canonical_structs: Optional[Tuple[Any, ...]] = None
@@ -340,6 +346,14 @@ class DecodeEngine:
             for o, i in zip(outs[1:1 + n_cache],
                             jax.tree_util.tree_leaves(self._bidx)))
         return self._canonical_structs
+
+    def slot_state_bytes(self) -> Dict[str, int]:
+        """Bytes of the decode state the ``num_slots`` slots hold, by kind:
+        a hybrid's KV rows beside its mamba conv windows and SSM states."""
+        out: Dict[str, int] = {}
+        for s, kind in zip(self._cache_structs(), self._kinds):
+            out[kind] = out.get(kind, 0) + s.size * s.dtype.itemsize
+        return out
 
     def init_state(self) -> DecodeState:
         """An all-free decode state (zero cache, batch ``num_slots``)."""
@@ -502,7 +516,10 @@ class DecodeEngine:
         if state.occupied[slot]:
             raise ValueError(f"slot {slot} is occupied (rid="
                              f"{state.rids[slot]}); release it first")
-        with span("engine.insert", slot=slot):
+        written = {k: v // self.num_slots
+                   for k, v in self.slot_state_bytes().items()}
+        with span("engine.insert", slot=slot,
+                  state_bytes=sum(written.values())):
             state.tokens = state.tokens.at[slot].set(prefix.token[0])
             state.positions = state.positions.at[slot].set(prefix.pos)
             state.cache = jax.tree_util.tree_map(
@@ -512,6 +529,9 @@ class DecodeEngine:
         state.occupied[slot] = True
         state.rids[slot] = prefix.rid
         self.n_inserts += 1
+        for kind, n in written.items():
+            self.state_written_bytes[kind] = (
+                self.state_written_bytes.get(kind, 0) + n)
         return state
 
     def release(self, state: DecodeState, slot: int) -> DecodeState:
@@ -600,6 +620,14 @@ class DecodeEngine:
                        "decode-engine slot width").set(self.num_slots)
         registry.gauge("repro_engine_occupancy",
                        "mean occupied-slot fraction").set(self.occupancy)
+        held = registry.gauge("repro_engine_slot_state_bytes",
+                              "decode state the slots hold, by kind")
+        for kind, n in self.slot_state_bytes().items():
+            held.set(n, kind=kind)
+        wrote = registry.counter("repro_engine_state_written_bytes_total",
+                                 "slot state written by inserts, by kind")
+        for kind, n in self.state_written_bytes.items():
+            wrote.set_total(n, kind=kind)
         if self._moe:
             moe = registry.counter(
                 "repro_moe_events_total",

@@ -1,11 +1,12 @@
-"""Greedy decode across the three cache families (repro.train.serve).
+"""Greedy decode across the four cache families (repro.train.serve).
 
 The old ``examples/serve_lm.py`` was the only executable coverage of
 ``greedy_generate`` and the per-family decode caches; when that example was
 repurposed for the ``repro.serve`` engine (ISSUE 2), this test inherited
 the coverage: a GQA transformer (plain KV cache), the MLA+MoE family
-(compressed latent cache) and the attention-free rwkv6 (O(1) state) all
-decode through one serving API.
+(compressed latent cache), the attention-free rwkv6 (O(1) state) and the
+jamba hybrid (mamba conv and SSM state beside a KV cache) all decode
+through one serving API.
 
 ISSUE 9 layers the continuous-batching :class:`DecodeEngine` on top and
 pins its invariants here: slot-based decode is bit-identical to whole-batch
@@ -40,7 +41,8 @@ BATCH, PROMPT, NEW = 2, 12, 4
 
 FAMILIES = ["qwen2.5-3b",       # GQA: plain KV cache
             "deepseek-v2-236b",  # MLA latent cache
-            "rwkv6-3b"]          # O(1) recurrent state
+            "rwkv6-3b",          # O(1) recurrent state
+            "jamba-1.5-large-398b"]  # mamba state beside a KV cache
 
 
 def _setup(arch, batch, prompt_len, seed=1):
@@ -66,12 +68,7 @@ def test_greedy_generate_cache_family(arch):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(again))
 
 
-#: one reduced model per decoding kind: GQA, MLA, recurrent, and the mamba
-#: and attention hybrid
-DECODE_KINDS = FAMILIES + ["jamba-1.5-large-398b"]
-
-
-@pytest.mark.parametrize("arch", DECODE_KINDS)
+@pytest.mark.parametrize("arch", FAMILIES)
 def test_positions_vector_step_equals_single_rows(arch):
     """One decode step with each row at its own position equals, row for
     row, a step that puts every row at that row's position as a scalar —

@@ -117,6 +117,16 @@ def test_prefill_carries_its_request_and_its_wait_for_a_slot(served):
         assert 0 <= s.stats["slot"] < SLOTS
 
 
+def test_insert_carries_the_state_bytes_it_writes(served):
+    """Each insert writes one slot's whole decode state, every kind."""
+    prog, eng, _ = served
+    one_slot = sum(eng.slot_state_bytes().values()) // SLOTS
+    inserts = _named(prog, "engine.insert")
+    assert inserts and one_slot > 0
+    assert all(s.stats["state_bytes"] == one_slot for s in inserts)
+    assert sum(eng.state_written_bytes.values()) == one_slot * len(inserts)
+
+
 def test_launch_names_its_graph_and_marks_the_compile(served):
     prog, _, _ = served
     launches = _named(prog, "graph.launch")

@@ -131,6 +131,22 @@ def test_rwkv6_scan_compiles(one_chip):
     assert _mosaic_kernels(_compile(rwkv6_scan_pallas, x, x, x, x, u)) >= 1
 
 
+def test_mamba_scan_compiles(one_chip):
+    """The selective scan of one AI21-Jamba2-3B layer at the longest
+    served prompt: 32768 steps over 5120 channels, 16 state entries; its
+    Mosaic kernel is named ``mamba_scan_pallas`` (the benchmark finds it
+    so)."""
+    from repro.kernels.mamba_scan.mamba_scan import mamba_scan_pallas
+    x = jax.ShapeDtypeStruct((1, 32768, 5120), jnp.bfloat16,
+                             sharding=one_chip)
+    delta = jax.ShapeDtypeStruct((1, 32768, 5120), jnp.float32,
+                                 sharding=one_chip)
+    a = jax.ShapeDtypeStruct((5120, 16), jnp.float32, sharding=one_chip)
+    bc = jax.ShapeDtypeStruct((1, 32768, 16), jnp.float32, sharding=one_chip)
+    compiled = _compile(mamba_scan_pallas, x, delta, a, bc, bc)
+    assert _kernel_names(compiled) == {"mamba_scan_pallas"}
+
+
 def test_flash_attention_compiles(one_chip):
     """qwen2.5-3b widths: 16 query heads, 2 kv heads of 128, S=512."""
     from repro.kernels.flash_attention.ops import flash_attention
@@ -272,6 +288,71 @@ def test_deepseek_engine_decode_step_compiles(one_chip):
     assert _kernel_names(compiled) == {"moe_gmm_pallas"}
 
 
+def _jamba2_3b(one_chip):
+    """AI21-Jamba2-3B at its published widths, uncut: 28 layers of 2560,
+    attention at index 7 of each period of 14 (20 query heads, one
+    key-value head, no rope), Mamba-1 elsewhere (d_inner 5120, d_state
+    16, dt_rank 160), dense MLPs of 8192, a tied head over 65536."""
+    from repro.models import model_spec
+    from repro.models.config import ModelConfig
+    from repro.models.params import abstract_params
+    cfg = ModelConfig(
+        name="jamba2-3b", n_layers=28, d_model=2560, n_heads=20,
+        n_kv_heads=1, head_dim=128, d_ff=8192, vocab=65536,
+        block_pattern=("mamba",) * 7 + ("attn",) + ("mamba",) * 6,
+        mlp_pattern=("dense",) * 14, rotary_pct=0.0, mamba_d_state=16,
+        mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=160, norm_eps=1e-6,
+        tie_embeddings=True, dtype="bfloat16")
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        abstract_params(model_spec(cfg), jnp.bfloat16))
+    return cfg, params
+
+
+def test_jamba_prefill_step_compiles(one_chip):
+    """A 32768-token prefill of AI21-Jamba2-3B (the DecodeEngine's prefill
+    kernel, cache out to 33280) fits one chip; its Mamba layers scan
+    through the Pallas kernel and its attention layers through flash."""
+    from repro.core import EGPU_16T, Program
+    from repro.serve.engine import ENGINE_REGISTRY
+    cfg, params = _jamba2_3b(one_chip)
+    kern = Program.build(EGPU_16T, registry=ENGINE_REGISTRY).create_kernel(
+        "engine.prefill", cfg=cfg, max_len=33280, cache_dtype="bfloat16")
+    kern.executor._params_def = jax.tree_util.tree_structure(params)
+    tokens = jax.ShapeDtypeStruct((1, 32768), jnp.int32, sharding=one_chip)
+    compiled = _compile(kern.executor, tokens,
+                        *jax.tree_util.tree_leaves(params))
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < V5E_HBM_BYTES
+    assert _kernel_names(compiled) == {"flash_attention_pallas",
+                                       "mamba_scan_pallas"}
+
+
+def test_jamba_engine_decode_step_compiles(one_chip):
+    """The DecodeEngine's 16-slot step over 33280 positions, its KV rows,
+    conv windows and SSM states donated, fits one chip; the decode runs
+    no Pallas kernel."""
+    from repro.core import EGPU_16T, Program
+    from repro.models.transformer import cache_struct
+    from repro.serve.engine import ENGINE_REGISTRY
+    cfg, params = _jamba2_3b(one_chip)
+    kern = Program.build(EGPU_16T, registry=ENGINE_REGISTRY).create_kernel(
+        "engine.generate", cfg=cfg, num_slots=16, cache_dtype="bfloat16")
+    kern.executor._params_def = jax.tree_util.tree_structure(params)
+    cache = [jax.ShapeDtypeStruct(c.shape, c.dtype, sharding=one_chip)
+             for c in jax.tree_util.tree_leaves(
+                 cache_struct(cfg, 16, 33280, jnp.bfloat16))]
+    io = [jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)] * 2
+    donate = tuple(range(2, 2 + len(cache)))
+    compiled = jax.jit(kern.executor, donate_argnums=donate).lower(
+        *io, *cache, *jax.tree_util.tree_leaves(params)).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < V5E_HBM_BYTES)
+    assert _kernel_names(compiled) == set()
+
+
 @pytest.mark.parametrize("rows,tm", [(79104, 256), (400, 16)],
                          ids=["prefill", "decode"])
 def test_moe_gmm_compiles(one_chip, rows, tm):
@@ -304,21 +385,26 @@ def _whole_leaf_moves(text: str, leaves) -> list:
     return moves
 
 
-@pytest.mark.parametrize("case", ["qwen-chat", "deepseek-longdoc"])
+@pytest.mark.parametrize("case", ["qwen-chat", "deepseek-longdoc",
+                                  "jamba-longctx"])
 def test_engine_decode_step_writes_cache_in_place(one_chip, case):
     """The engine's decode step at the chat cell's widths (qwen2.5-3b, 32
-    slots of 2048) and at the longdoc cell's chip share (DeepSeek-V2, 16
-    slots of 12544), its cache donated, keeps no second cache: its
-    temporaries stay under a tenth of the cache's bytes, and no copy,
-    reshape or transpose moves a whole cache leaf.  Each step writes only
-    every slot's new row in place."""
+    slots of 2048), at the longdoc cell's chip share (DeepSeek-V2, 16
+    slots of 12544) and at the longctx cell's (AI21-Jamba2-3B, 16 slots of
+    33280: KV rows, conv windows and SSM states), its cache donated, keeps
+    no second cache: its temporaries stay under a tenth of the slot
+    state's bytes, and no copy, reshape or transpose moves a whole cache
+    leaf.  Each step writes only every slot's new row (a Mamba layer its
+    own state) in place."""
     from repro.core import EGPU_16T, Program
     from repro.models.transformer import cache_struct
     from repro.serve.engine import ENGINE_REGISTRY
     if case == "qwen-chat":
         (cfg, params), slots, max_len = _qwen_params(one_chip), 32, 2048
-    else:
+    elif case == "deepseek-longdoc":
         (cfg, params), slots, max_len = _deepseek_share(one_chip), 16, 12544
+    else:
+        (cfg, params), slots, max_len = _jamba2_3b(one_chip), 16, 33280
     kern = Program.build(EGPU_16T, registry=ENGINE_REGISTRY).create_kernel(
         "engine.generate", cfg=cfg, num_slots=slots, cache_dtype="bfloat16")
     kern.executor._params_def = jax.tree_util.tree_structure(params)
